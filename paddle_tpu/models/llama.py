@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from .. import nn
 from ..nn import functional as F
 from ..framework.tensor import Tensor
-from ..ops.pallas.flash_attention import sdpa
+from ..ops.pallas.flash_attention import _xla_sdpa, sdpa
 
 
 @dataclass
@@ -130,7 +130,8 @@ class LlamaAttention(nn.Layer):
         q, k = rope_op(q, k, cos, sin)
         # causal always: attn_mask (e.g. padding) composes with, never
         # replaces, the causal structure of the LM
-        out = flash_attention(q, k, v, attn_mask, is_causal=True)
+        out = flash_attention(q, k, v, attn_mask, is_causal=True,
+                              kernel=self.config.use_flash_attention)
         out = out.reshape([b, s, self.num_heads * self.head_dim])
         return self.o_proj(out)
 
@@ -168,14 +169,22 @@ class LlamaDecoderLayer(nn.Layer):
         return residual + h
 
 
+def _built(layer, config: LlamaConfig):
+    """Cast a sublayer to the config dtype as soon as it is built: the
+    float32 initialisers then never hold more than one sublayer, where
+    casting the finished model held all of it twice over (11 GB before
+    the first bf16 byte at Llama-3-8B widths x 8 layers)."""
+    return layer.bfloat16() if config.dtype == "bfloat16" else layer
+
+
 class LlamaModel(nn.Layer):
     def __init__(self, config: LlamaConfig):
         super().__init__()
         self.config = config
-        self.embed_tokens = nn.Embedding(config.vocab_size,
-                                         config.hidden_size)
+        self.embed_tokens = _built(
+            nn.Embedding(config.vocab_size, config.hidden_size), config)
         self.layers = nn.LayerList(
-            [LlamaDecoderLayer(config)
+            [_built(LlamaDecoderLayer(config), config)
              for _ in range(config.num_hidden_layers)])
         self.norm = LlamaRMSNorm(config)
 
@@ -204,8 +213,9 @@ class LlamaForCausalLM(nn.Layer):
         if config.tie_word_embeddings:
             self.lm_head = None
         else:
-            self.lm_head = nn.Linear(config.hidden_size, config.vocab_size,
-                                     bias_attr=False)
+            self.lm_head = _built(
+                nn.Linear(config.hidden_size, config.vocab_size,
+                          bias_attr=False), config)
 
         if config.dtype == "bfloat16":
             self.bfloat16()
@@ -279,5 +289,10 @@ def rope_op(q, k, cos, sin):
 
 
 @_op(name="flash_attention")
-def flash_attention(q, k, v, attn_mask=None, is_causal=False):
+def flash_attention(q, k, v, attn_mask=None, is_causal=False, kernel=True):
+    """``kernel=False`` (``LlamaConfig.use_flash_attention``) takes the
+    plain XLA formulation on every backend — the reference the Pallas
+    kernels are compared against."""
+    if not kernel:
+        return _xla_sdpa(q, k, v, attn_mask=attn_mask, is_causal=is_causal)
     return sdpa(q, k, v, attn_mask=attn_mask, is_causal=is_causal)

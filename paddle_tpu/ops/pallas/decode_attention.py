@@ -128,37 +128,14 @@ def _pallas_decode(q, kcache, vcache, pos, block_t):
     return out.reshape(b, nh, d)
 
 
-_PROBE_OK = None
-
-
-def _probe():
-    global _PROBE_OK
-    if _PROBE_OK is None:
-        from .flash_attention import run_probe
-
-        def smoke():
-            z = jnp.zeros((1, 4, 64), jnp.bfloat16)
-            c = jnp.zeros((1, 2, 256, 64), jnp.bfloat16)
-            p = jnp.zeros((1,), jnp.int32)
-            jax.jit(lambda q, k, v, s: _pallas_decode(
-                q, k, v, s, 256))(z, c, c, p).block_until_ready()
-
-        _PROBE_OK = run_probe(smoke)
-    return _PROBE_OK
-
-
 def decode_attention(q, kcache, vcache, pos):
     """One-token cache attention: q [B, nh, D], caches [B, kvh, T, D]
     (kv-head-major serving layout),
     pos [B] (index of the CURRENT token; entries t <= pos attend).
-    Returns [B, nh, D].  Pallas path when shapes/backend allow, XLA
-    einsum fallback otherwise (identical numerics).
-
-    Caveat: when this is traced inside an outer jit, only trace-time
-    failures fall back here — a Mosaic compile error at the outer jit's
-    compile would surface to the caller.  The probe compiles the real
-    streamed kernel and VMEM use is O(block_t) regardless of cache
-    length, which removes the known shape-dependent failure modes."""
+    Returns [B, nh, D].  Pallas path when PALLAS_DECODE is on and
+    shapes/backend allow, XLA einsum otherwise (identical numerics).
+    The route is chosen on the input; a kernel that fails to trace or
+    compile raises, it is never swapped for the XLA result."""
     b, nh, d = q.shape
     kvh, t = kcache.shape[1], kcache.shape[2]
     block_t = 256 if t % 256 == 0 else (128 if t % 128 == 0 else None)
@@ -168,15 +145,10 @@ def decode_attention(q, kcache, vcache, pos):
         and d in (64, 128, 256)
         and nh % kvh == 0
         and q.dtype == kcache.dtype == vcache.dtype
-        and (jax.default_backend() not in ("cpu",) or _INTERPRET)
-        and (_INTERPRET or _probe()))
+        and (jax.default_backend() not in ("cpu",) or _INTERPRET))
     if use_pallas:
-        try:
-            return _pallas_decode(q, kcache, vcache, pos, block_t)
-        except Exception:
-            from .flash_attention import _warn_fallback_once
-            _warn_fallback_once()   # advisor r2: silent kernel loss is
-    return _xla_decode(q, kcache, vcache, pos)   # a perf-bug magnet
+        return _pallas_decode(q, kcache, vcache, pos, block_t)
+    return _xla_decode(q, kcache, vcache, pos)
 
 
 def _xla_decode(q, kcache, vcache, pos):

@@ -25,7 +25,7 @@ Grouped-query attention runs in-kernel: the K/V BlockSpec index map
 sends q-head h to kv-head h // group, so K/V are never materialized at
 q-head width.  dK/dV are emitted per q-head and group-summed outside.
 
-The XLA fallback (`_xla_sdpa`) keeps full semantics (arbitrary masks,
+The XLA path (`_xla_sdpa`) keeps full semantics (arbitrary masks,
 dropout) and is numerically the flash reference: fp32 softmax, input
 dtype matmuls.
 """
@@ -116,112 +116,9 @@ def _xla_sdpa(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
     return jnp.swapaxes(out, 1, 2)
 
 
-_PALLAS_OK = None   # lazily probed once per process
-_INTERPRET = False  # tests: run the kernels anywhere via interpret mode
-
-
-def run_probe(fn):
-    """Compile+run `fn` once in a FRESH THREAD and report success.  jax
-    trace state is thread-local, so the probe stays eager (and
-    catchable) even when reached while tracing a caller's jit.  Shared
-    by every pallas kernel family's availability gate."""
-    import threading
-
-    box = {}
-
-    def run():
-        try:
-            fn()
-            box["ok"] = True
-        except Exception:
-            box["ok"] = False
-
-    t = threading.Thread(target=run)
-    t.start()
-    t.join()
-    return box.get("ok", False)   # thread died on BaseException -> no
-
-
-def _probe_pallas():
-    """Compile+run a tiny fwd AND grad once. The bwd kernels are traced
-    outside any caller's try (when the cotangent is pulled back at
-    jit-compile time), so a bwd lowering failure would otherwise crash
-    training instead of falling back to the XLA path."""
-    global _PALLAS_OK
-    if _PALLAS_OK is None:
-        def smoke():
-            # ragged seq (tail-masked) + GQA (2 q heads per kv head) +
-            # causal: exercises every generalized code path
-            q = jnp.zeros((1, 320, 2, 64), jnp.bfloat16)
-            z = jnp.zeros((1, 320, 1, 64), jnp.bfloat16)
-            # grad wrt q, k AND v so none of the three bwd kernels is
-            # dead code the jaxpr DCE could skip lowering for
-            jax.jit(jax.grad(
-                lambda q, k, v: jnp.sum(_pallas_sdpa(q, k, v, True)
-                                        .astype(jnp.float32)),
-                argnums=(0, 1, 2)))(q, z, z)[0].block_until_ready()
-            # the no-grad path uses the separate need_lse=False forward
-            # variant; compile that too
-            jax.jit(lambda q: _pallas_sdpa(q, z, z, True))(
-                q).block_until_ready()
-
-        _PALLAS_OK = run_probe(smoke)
-    return _PALLAS_OK
-
-
-_MASKED_STREAM_OK: dict = {}
-
-
-def _probe_masked_stream(hd=64, nvec=2):
-    """Compile+run the STREAMED masked/biased kernels (fwd and grad)
-    once per (head_dim, mask-vec arity) at the PRODUCTION block
-    configuration, so the long-seq masked dispatch can trust them
-    (their Mosaic compile happens at the caller's jit compile, where
-    failure is uncatchable).
-
-    Probe shapes derive from the call site (r4 advisor: a S=256/nvec=2
-    smoke test left S>4k nvec=4 hd=128 failures to surface at the
-    caller): S=512 selects the same 512-wide blocks _block_sizes picks
-    for every long padded sequence, and hd/nvec come in from the
-    dispatch."""
-    key = (int(hd), int(nvec))
-    if key not in _MASKED_STREAM_OK:
-        from . import flash_mask as FM
-
-        def smoke():
-            global _FORCE_STREAM
-            saved = _FORCE_STREAM
-            _FORCE_STREAM = True
-            try:
-                s = 512          # -> 512-blocks, the long-seq config
-                q = jnp.zeros((1, s, 2, hd), jnp.bfloat16)
-                kv = jnp.zeros((1, s, 1, hd), jnp.bfloat16)
-                vec = jnp.zeros((1, 1, nvec, s), jnp.int32)
-                bias = jnp.zeros((1, 1, s, s), jnp.float32)
-                sc = 0.125
-
-                def loss_m(q, k, v):
-                    return jnp.sum(FM.flash_mha_masked(
-                        jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
-                        jnp.swapaxes(v, 1, 2), vec, True, sc)
-                        .astype(jnp.float32))
-
-                jax.jit(jax.grad(loss_m, argnums=(0, 1, 2)))(
-                    q, kv, kv)[0].block_until_ready()
-
-                def loss_b(q, k, v, bias):
-                    return jnp.sum(FM.flash_mha_biased(
-                        jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
-                        jnp.swapaxes(v, 1, 2), bias, False, sc)
-                        .astype(jnp.float32))
-
-                jax.jit(jax.grad(loss_b, argnums=(0, 1, 2, 3)))(
-                    q, kv, kv, bias)[0].block_until_ready()
-            finally:
-                _FORCE_STREAM = saved
-
-        _MASKED_STREAM_OK[key] = run_probe(smoke)
-    return _MASKED_STREAM_OK[key]
+# tests: run the kernels anywhere via interpret mode.  Off in every
+# program a user starts (chip_smoke.py asserts it).
+_INTERPRET = False
 
 
 def _pad_len(s, mult=128):
@@ -238,14 +135,6 @@ def _pad_seq(x, target):
     return jnp.pad(x, ((0, 0), (0, target - s), (0, 0), (0, 0)))
 
 
-# below this max-seq, plain unmasked sdpa routes to XLA's fused
-# attention instead of the flash kernel.  Default OFF: the isolated
-# S=512 microbench favors XLA 2.4x, but the end-to-end MoE-step A/B
-# (same session, route toggled) measured the XLA path 13 ms SLOWER in
-# the full scanned program — only an in-context A/B decides this knob.
-_SHORT_SEQ_XLA = 0
-
-
 def sdpa(q, k, v, attn_mask=None, dropout_p=0.0, is_causal=False,
          training=True, flashmask=None):
     """Paddle-layout scaled-dot-product attention: [B, S, H, D] in/out.
@@ -260,7 +149,9 @@ def sdpa(q, k, v, attn_mask=None, dropout_p=0.0, is_causal=False,
         kernel (streamed blockwise, no softmax residuals).
     Sequence lengths are arbitrary (>= 128): inputs are padded to block
     multiples and the tails masked in-kernel.  Anything else (dropout,
-    arbitrary bool masks, tiny shapes) falls back to the XLA path."""
+    arbitrary bool masks, fewer than 128 positions, the CPU backend)
+    routes to the XLA path: a choice made on the input, never a rescue
+    from a kernel that failed."""
     shapes_ok = (
         dropout_p == 0.0
         and q.dtype == k.dtype == v.dtype   # kernels matmul in input dtype
@@ -283,47 +174,21 @@ def sdpa(q, k, v, attn_mask=None, dropout_p=0.0, is_causal=False,
                 and am.shape[-2:] == (q.shape[1], k.shape[1])):
             bias = am
 
-    # short-sequence route: below ~1024 the flash grid is too small to
-    # pipeline and XLA's fused attention wins (measured on v5e, hd=128:
-    # S=512 f+b 0.87 ms vs 2.14 ms pallas; pallas wins 2-5x from 1024 up)
-    if (shapes_ok and attn_mask is None and mask_vecs is None
-            and max(q.shape[1], k.shape[1]) < _SHORT_SEQ_XLA
-            and q.shape[2] % k.shape[2] == 0):
-        try:
-            return jax.nn.dot_product_attention(q, k, v,
-                                                is_causal=is_causal)
-        except Exception:
-            pass
-
-    long_seq = max(q.shape[1], k.shape[1]) > _STREAM_SEQ
     if shapes_ok and (attn_mask is None or mask_vecs is not None
-                      or bias is not None) and _probe_pallas():
-        masked = mask_vecs is not None or bias is not None
-        # past _STREAM_SEQ the masked kernels switch to their streamed
-        # variants (inner-grid K/V iteration, VMEM independent of S);
-        # gate them behind their own compile probe so a Mosaic failure
-        # at the CALLER's jit-compile can't crash training
-        stream_ok = (not (masked and long_seq)) or _probe_masked_stream(
-            hd=q.shape[-1],
-            nvec=(mask_vecs.shape[2] if mask_vecs is not None else 2))
-        if stream_ok:
-            try:
-                if mask_vecs is not None:
-                    return _pallas_sdpa_masked(q, k, v, mask_vecs,
-                                               is_causal)
-                if bias is not None:
-                    return _pallas_sdpa_biased(q, k, v, bias, is_causal)
-                return _pallas_sdpa(q, k, v, is_causal)
-            except Exception:
-                _warn_fallback_once()
-    if shapes_ok and long_seq and (mask_vecs is not None
-                                   or bias is not None):
-        # masked long-seq with the kernels unavailable: the chunked-XLA
-        # online-softmax path keeps O(S) forward memory at any length
-        return _xla_sdpa_streamed(q, k, v, is_causal, bias=bias,
-                                  mask_vecs=mask_vecs)
+                      or bias is not None):
+        # No probe and no fallback.  What routes a call here is visible
+        # in its input (backend, dtypes, head dim, lengths, mask form);
+        # a kernel that then fails to trace or compile raises with the
+        # compiler's message — the XLA path would hide a chip that is
+        # running without its kernels.  Past _STREAM_SEQ the kernels
+        # switch to their streamed variants on their own.
+        if mask_vecs is not None:
+            return _pallas_sdpa_masked(q, k, v, mask_vecs, is_causal)
+        if bias is not None:
+            return _pallas_sdpa_biased(q, k, v, bias, is_causal)
+        return _pallas_sdpa(q, k, v, is_causal)
     if attn_mask is None and flashmask is not None:
-        # keep flashmask semantics on the fallback path (dense, O(S^2)).
+        # keep flashmask semantics on the XLA path (dense, O(S^2)).
         # Additive -1e9 (not bool -inf) keeps fully-masked rows finite;
         # zeroing them afterwards matches the kernel's convention.
         from .flash_mask import dense_mask_from_intervals
@@ -342,8 +207,9 @@ def sdpa(q, k, v, attn_mask=None, dropout_p=0.0, is_causal=False,
 def _xla_sdpa_streamed(q, k, v, is_causal, bias=None, mask_vecs=None,
                        chunk=512):
     """O(S)-memory masked attention in plain XLA: lax.scan over key
-    chunks with the online-softmax recurrence.  The long-sequence
-    masked fallback when the streamed Pallas kernels are unavailable.
+    chunks with the online-softmax recurrence.  No dispatch reaches
+    it: it is the reference the streamed masked kernels are tested
+    against at lengths where the dense [Sq, Sk] reference is too big.
     Supports float bias [B|1, H|1, Sq, Sk] and flashmask interval vecs
     [B|1, H|1, 2|4, Sk]; per-chunk slices keep every transient at
     [B, H, Sq, chunk].  The step is jax.checkpoint-ed: without it the
@@ -416,23 +282,6 @@ def _xla_sdpa_streamed(q, k, v, is_causal, bias=None, mask_vecs=None,
     out = jnp.where(row_ok[..., None],
                     acc / jnp.where(row_ok, l, 1.0)[..., None], 0.0)
     return jnp.swapaxes(out, 1, 2).astype(q.dtype)
-
-
-_WARNED_FALLBACK = False
-
-
-def _warn_fallback_once():
-    """A pallas trace/compile failure silently degrading to the XLA path
-    is a perf bug magnet (advisor r2): surface it once."""
-    global _WARNED_FALLBACK
-    if not _WARNED_FALLBACK:
-        _WARNED_FALLBACK = True
-        import logging
-        import traceback
-        logging.getLogger("paddle_tpu").warning(
-            "pallas flash-attention raised at trace time; falling back "
-            "to the XLA path for this and similar calls:\n%s",
-            traceback.format_exc())
 
 
 def _pallas_sdpa(q, k, v, causal):

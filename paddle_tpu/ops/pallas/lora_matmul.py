@@ -89,27 +89,6 @@ def _pallas_gather_matmul(x, a, b, scale, idx):
         )(idx.astype(jnp.int32), x, a, b, svec)
 
 
-_PROBE_OK = None
-
-
-def _probe():
-    global _PROBE_OK
-    if _PROBE_OK is None:
-        from .flash_attention import run_probe
-
-        def smoke():
-            x = jnp.zeros((4, 256), jnp.bfloat16)
-            a = jnp.zeros((3, 8, 256), jnp.bfloat16)
-            b = jnp.zeros((3, 8, 256), jnp.bfloat16)
-            sc = jnp.zeros((3,), jnp.float32)
-            idx = jnp.zeros((4,), jnp.int32)
-            jax.jit(_pallas_gather_matmul)(
-                x, a, b, sc, idx).block_until_ready()
-
-        _PROBE_OK = run_probe(smoke)
-    return _PROBE_OK
-
-
 def lora_gather_matmul(x, a, b, scale, idx):
     """Per-row adapter delta: ``x [S, K]`` against banks ``a [N, r, K]``
     / ``b [N, r, M]`` with per-bank-row ``scale [N]`` (alpha / r) and
@@ -126,14 +105,9 @@ def lora_gather_matmul(x, a, b, scale, idx):
                          f"bank A {a.shape[2]}")
     use_pallas = (
         x.shape[0] <= _GATHER_MAX_ROWS
-        and (_INTERPRET or (jax.default_backend() not in ("cpu",)
-                            and _probe())))
-    if use_pallas:
-        try:
-            return _pallas_gather_matmul(x, a, b, scale, idx)
-        except Exception:
-            from .flash_attention import _warn_fallback_once
-            _warn_fallback_once()
+        and (_INTERPRET or jax.default_backend() not in ("cpu",)))
+    if use_pallas:      # a kernel failure raises: no XLA rescue
+        return _pallas_gather_matmul(x, a, b, scale, idx)
     return _xla_gather_matmul(x, a, b, scale, idx)
 
 

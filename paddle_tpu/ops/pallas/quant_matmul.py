@@ -194,28 +194,6 @@ def _xla_fallback(x, w: QuantizedWeight):
     return (out * w.scale.astype(jnp.float32)).astype(x.dtype)
 
 
-_PROBE_OK = None
-
-
-def _probe():
-    global _PROBE_OK
-    if _PROBE_OK is None:
-        from .flash_attention import run_probe
-
-        def smoke():
-            x = jnp.zeros((8, 256), jnp.bfloat16)
-            q8 = jnp.zeros((256, 256), jnp.int8)
-            s = jnp.zeros((256,), jnp.float32)
-            jax.jit(lambda a, b, c: _pallas_int8(a, b, c, 128))(
-                x, q8, s).block_until_ready()
-            p4 = jnp.zeros((128, 256), jnp.int8)
-            jax.jit(lambda a, b, c: _pallas_int4(a, b, c, 256, 128))(
-                x, p4, s).block_until_ready()
-
-        _PROBE_OK = run_probe(smoke)
-    return _PROBE_OK
-
-
 def weight_only_matmul(x, w: QuantizedWeight):
     """x [..., K] @ dequant(w) -> [..., N] — Pallas GEMV kernel at
     decode shapes on TPU, XLA dequant-matmul otherwise."""
@@ -232,16 +210,11 @@ def weight_only_matmul(x, w: QuantizedWeight):
         (bn > 0)
         and m <= _GEMV_MAX_ROWS
         and (w.kind == "int8" or k % 2 == 0)
-        and (_INTERPRET or (jax.default_backend() not in ("cpu",)
-                            and _probe())))
-    if use_pallas:
-        try:
-            if w.kind == "int4":
-                out = _pallas_int4(x2, w.q, w.scale, k, bn)
-            else:
-                out = _pallas_int8(x2, w.q, w.scale, bn)
-            return out.reshape(*lead, n)
-        except Exception:
-            from .flash_attention import _warn_fallback_once
-            _warn_fallback_once()
+        and (_INTERPRET or jax.default_backend() not in ("cpu",)))
+    if use_pallas:      # a kernel failure raises: no XLA rescue
+        if w.kind == "int4":
+            out = _pallas_int4(x2, w.q, w.scale, k, bn)
+        else:
+            out = _pallas_int8(x2, w.q, w.scale, bn)
+        return out.reshape(*lead, n)
     return _xla_fallback(x2, w).reshape(*lead, n)

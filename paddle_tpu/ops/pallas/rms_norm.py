@@ -20,15 +20,33 @@ def _rms_kernel(x_ref, w_ref, o_ref, *, eps):
     o_ref[...] = (y * w_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
 
 
+# Scoped VMEM one kernel instance may use is 16 MB on the chip.  Each
+# element of a (block, d) tile costs the double-buffered input and output
+# blocks (4 x itemsize) plus one f32 working copy: the compiler counted
+# 24.21 MB for 512 rows x 4096 in bf16, 11.5 bytes per element against the
+# 12 this predicts.  Budget 14 MB: the weight row and compiler scratch
+# take the rest.
+_VMEM_BUDGET = 14 * 1024 * 1024
+
+
+def _row_block(n, d, itemsize):
+    """Largest power-of-two row block <= 512 that divides ``n`` and keeps
+    a (block, d) tile inside the VMEM budget, so the block shrinks as the
+    width grows (bf16: 512 rows up to 2048 wide, 256 at 4096, 128 at
+    8192) where it used to be 512 whatever the width."""
+    cap = _VMEM_BUDGET // ((4 * itemsize + 4) * d)
+    block = 512
+    while block > 1 and (block > cap or n % block):
+        block //= 2
+    return block
+
+
 @functools.partial(jax.jit, static_argnames=("eps",))
 def _pallas_rms(x2d, w, eps):
     from jax.experimental import pallas as pl
 
     n, d = x2d.shape
-    block = 512 if n % 512 == 0 else (256 if n % 256 == 0 else 8)
-    while n % block:
-        block //= 2
-    block = max(block, 1)
+    block = _row_block(n, d, x2d.dtype.itemsize)
     with jax.enable_x64(False):   # see flash_attention._flash_fwd
         return pl.pallas_call(
             functools.partial(_rms_kernel, eps=eps),

@@ -1,0 +1,138 @@
+"""The main path's kernels compile for a TPU v5e at Llama-3-8B shapes.
+
+No chip is needed: the TPU compiler is installed and compiles for a
+topology that is described, not attached.  What it refuses here (a tile
+it cannot lay out, more scoped VMEM than a kernel may use) it refuses on
+the chip, where the same failure costs chip time — `rms_norm` at hidden
+4096 was refused for exactly that and no interpret-mode test saw it.
+
+Everything chip-shaped happens inside fixtures and tests of THIS file:
+only one process may hold the TPU library, pytest-xdist imports every
+test file in every worker, and a second file could land on a worker
+that cannot load it.  Nothing runs; a compile that passes is not a chip
+run.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from paddle_tpu.ops.pallas import decode_attention as DA
+from paddle_tpu.ops.pallas import flash_attention as FA
+from paddle_tpu.ops.pallas import flash_mask as FM
+from paddle_tpu.ops.pallas import paged_attention as PA
+from paddle_tpu.ops.pallas import rms_norm as RN
+
+# Llama-3-8B attention: 32 query heads on 8 KV heads, head dim 128, bf16
+NH, KVH, HD = 32, 8, 128
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")   # no /tmp/tpu_logs
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def sds(topo):
+    """ShapeDtypeStruct factory pinned to one described chip."""
+    from jax.sharding import SingleDeviceSharding
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def make(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return make
+
+
+@pytest.fixture(autouse=True)
+def no_compile_cache():
+    """A compile for a described device is written to the persistent
+    cache but can never be read back without a chip; keep these compiles
+    out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def compile_kernels(fn, *args) -> int:
+    """Compile ``fn`` for the described chip; the number of Pallas
+    kernels in the compiled program."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return text.count('custom_call_target="tpu_custom_call"')
+
+
+def test_paged_attention(sds):
+    # the serving decode step: 8 slots, page 16, 128 pages per sequence
+    slots, ps, width = 8, 16, 128
+    pool = sds((slots * width + 1, KVH, ps, HD))
+    n = compile_kernels(PA.paged_attention, sds((slots, NH, HD)), pool,
+                        pool, sds((slots, width), jnp.int32),
+                        sds((slots,), jnp.int32))
+    assert n == 1
+
+
+def test_pallas_decode(sds):
+    cache = sds((8, KVH, 2048, HD))
+    n = compile_kernels(
+        lambda q, k, v, pos: DA._pallas_decode(q, k, v, pos, 256),
+        sds((8, NH, HD)), cache, cache, sds((8,), jnp.int32))
+    assert n == 1
+
+
+def _qkv(sds, s, batch=1):
+    return (sds((batch, s, NH, HD)), sds((batch, s, KVH, HD)),
+            sds((batch, s, KVH, HD)))
+
+
+def test_flash_forward(sds):
+    n = compile_kernels(lambda q, k, v: FA._pallas_sdpa(q, k, v, True),
+                        *_qkv(sds, 2048))
+    assert n == 1
+
+
+def test_flash_grad(sds):
+    def loss(q, k, v):
+        return jnp.sum(FA._pallas_sdpa(q, k, v, True).astype(jnp.float32))
+
+    n = compile_kernels(jax.grad(loss, argnums=(0, 1, 2)),
+                        *_qkv(sds, 2048))
+    assert n == 3       # forward with lse, dq, dk/dv
+
+
+def test_flash_masked_from_key_padding(sds):
+    # the serving prefill: causal + a key-padding mask over the bucket
+    s = 1024
+
+    def prefill_attention(q, k, v, mask):
+        vecs = FM.padding_mask_to_intervals(mask[:, :, 0, :], s)
+        return FA._pallas_sdpa_masked(q, k, v, vecs, True)
+
+    n = compile_kernels(prefill_attention, *_qkv(sds, s),
+                        sds((1, 1, 1, s), jnp.bool_))
+    assert n == 1
+
+
+@pytest.mark.parametrize("rows,width", [(4096, 4096), (16384, 2048)])
+def test_rms_norm(sds, rows, width):
+    # 512 rows at width 4096 asked for 24.21 MB of the 16 MB scoped VMEM
+    n = compile_kernels(lambda x, w: RN._pallas_rms(x, w, eps=1e-6),
+                        sds((rows, width)), sds((width,)))
+    assert n == 1
+
+
+def test_rms_row_block_shrinks_with_width():
+    assert RN._row_block(16384, 2048, 2) == 512     # as before the fix
+    assert RN._row_block(4096, 4096, 2) == 256      # was 512: refused
+    assert RN._row_block(4096, 4096, 4) == 128
+    assert RN._row_block(24, 4096, 2) == 8          # divides the rows
+    assert RN._row_block(7, 4096, 2) == 1

@@ -1,0 +1,128 @@
+"""chip_smoke.py's own logic, at a toy width on the CPU.
+
+The script refuses to run without a TPU, so its phases are functions
+with the sizes as arguments and the tests call those: the HTTP driving,
+the teacher-forced logits check, the census, the re-lowering that lists
+each program's kernels, and the trainer loop all run here, so that a
+change to the runner's signatures or the server's metrics breaks a test
+and not the next chip call.
+"""
+import json
+import os
+import sys
+
+import jax
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+import chip_smoke as C  # noqa: E402
+
+from paddle_tpu.models.bert import BertConfig  # noqa: E402
+from paddle_tpu.models.llama import llama_tiny  # noqa: E402
+from paddle_tpu.utils import compile_cache  # noqa: E402
+
+LENGTHS = (200, 20, 24, 40, 48)
+
+
+def tiny_llama():
+    cfg = llama_tiny(num_attention_heads=8, num_key_value_heads=4,
+                     max_position_embeddings=512)
+    cfg.dtype = "bfloat16"
+    return cfg
+
+
+@pytest.fixture(scope="module")
+def served():
+    cfg = tiny_llama()
+    model = C.build_llama(cfg, C.SEED)
+    prompts = C.make_prompts(cfg.vocab_size, LENGTHS, C.SEED)
+    out = C.drive_server(model, prompts, (6, 8), max_model_len=256)
+    return model, prompts, out
+
+
+def test_serve_phase_answers_three_groups(served):
+    _, _, out = served
+    assert [len(t) for t in out["served"]] == [6, 8, 8, 8, 8, 6]
+    assert out["hit_pages"] == 12           # 200 // 16 pages of the repeat
+    assert out["census"]["leak"] == 0 and out["census"]["live"] == 0
+    assert out["decode_traces"] == 1
+    assert {"decode_step", "prefill[208]",
+            "prefill_cached[16]"} <= set(out["compile_s"])
+
+
+def test_served_tokens_pass_the_reference(served):
+    model, prompts, out = served
+    check = C.check_served(model, prompts, out["served"])
+    assert check["worst_gap"] <= C.LOGIT_TOL
+    assert check["long"]["positions"] == 12
+    assert check["short"]["positions"] == 32
+    assert model.config.use_flash_attention     # the switch is put back
+
+
+def test_a_wrong_token_fails_the_reference(served):
+    """The check has teeth: tokens served for ANOTHER prompt are far
+    outside the tolerance."""
+    model, prompts, out = served
+    swapped = list(out["served"])
+    swapped[1], swapped[2] = swapped[2], swapped[1]
+    with pytest.raises(RuntimeError, match="leave the reference"):
+        C.check_served(model, prompts, swapped)
+
+
+def test_runner_kernels_relowers_every_program(served):
+    _, _, out = served
+    kernels = C.runner_kernels(out["runner"])
+    assert set(kernels) == {"decode_step", "prefill[32]", "prefill[48]",
+                            "prefill[208]", "prefill_cached[16]"}
+    assert all(v == {} for v in kernels.values())   # the CPU has none
+
+
+def test_train_phase_loss_falls():
+    cfg = BertConfig(vocab_size=512, hidden_size=128, num_hidden_layers=2,
+                     num_attention_heads=4, intermediate_size=256)
+    out = C.train_phase(cfg, jax.devices()[:1], seed=C.SEED, batch=2,
+                        seq=64, autocast=False)
+    assert out["losses"][-1] < out["losses"][0]
+    assert np.all(np.isfinite(out["losses"]))
+    json.dumps(out)                         # every line it prints is JSON
+
+
+def test_interpret_switches_are_checked(monkeypatch):
+    from paddle_tpu.ops.pallas import paged_attention
+    C.interpret_is_off()
+    monkeypatch.setattr(paged_attention, "_INTERPRET", True)
+    with pytest.raises(RuntimeError, match="_INTERPRET is on"):
+        C.interpret_is_off()
+
+
+@pytest.fixture
+def cache_config():
+    was = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_no_tpu_no_result(capsys, monkeypatch, cache_config):
+    """On the CPU the script exits non-zero and prints no result line."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    with pytest.raises(SystemExit) as e:
+        C.main([])
+    assert e.value.code not in (0, None)
+    assert '"ok"' not in capsys.readouterr().out
+
+
+def test_compile_cache_placed_from_outside(monkeypatch, cache_config):
+    was = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    assert compile_cache.enable_compile_cache() == "/somewhere/else"
+    assert jax.config.jax_compilation_cache_dir == was  # nothing set
+
+
+def test_compile_cache_defaults_to_the_checkout(monkeypatch, cache_config):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    path = compile_cache.enable_compile_cache()
+    assert path == os.path.join(root, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == path

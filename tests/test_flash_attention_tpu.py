@@ -12,8 +12,15 @@ import jax.numpy as jnp
 
 from paddle_tpu.ops.pallas import flash_attention as F
 
-tpu_only = pytest.mark.skipif(
-    jax.default_backend() in ("cpu",), reason="needs TPU for pallas")
+@pytest.fixture
+def _needs_tpu():
+    """Decided when a test runs, never while the file is imported:
+    collecting on a chip host must not open the device in every worker."""
+    if jax.default_backend() in ("cpu",):
+        pytest.skip("needs TPU for pallas")
+
+
+tpu_only = pytest.mark.usefixtures("_needs_tpu")
 
 
 @tpu_only

@@ -101,9 +101,9 @@ def test_paged_generate_page_boundary_crossing():
     assert np.array_equal(d, p)
 
 
-@pytest.mark.skipif(jax.default_backend() in ("cpu",),
-                    reason="needs TPU for the pallas kernel")
 def test_paged_kernel_tpu_parity():
+    if jax.default_backend() in ("cpu",):   # asked in the body, not at
+        pytest.skip("needs TPU for the pallas kernel")      # import
     rng = np.random.RandomState(0)
     B, nh, kvh, D, ps, P, M = 4, 16, 4, 128, 128, 19, 5
     q = jnp.asarray(rng.randn(B, nh, D), jnp.bfloat16)
