@@ -1,7 +1,7 @@
 """The quickest proof that the system still starts on the chip.
 
     python chip_smoke.py               # one TPU chip: a server, then a trainer
-    python chip_smoke.py --four-chips  # one host, four chips: sharded paths only
+    python chip_smoke.py --four-chips  # four chips: the sharded paths only
 
 One process, which touches JAX itself and starts no child.  Any phase that
 raises, any device that is not a TPU, any check that fails ends the run
@@ -98,24 +98,40 @@ def device_bytes(devices) -> list:
             for s in stats]
 
 
+def shard_bytes(tree, devices) -> list:
+    """Bytes of ``tree``'s leaves on each of ``devices``, from their
+    addressable shards; raises if a leaf misses one of the devices.  Code
+    that has only ever seen one chip puts everything on the first."""
+    import jax
+    held = {d: 0 for d in devices}
+    for leaf in jax.tree.leaves(tree):
+        on = {s.device for s in leaf.addressable_shards}
+        if on != set(devices):
+            raise RuntimeError(
+                f"a {leaf.shape} leaf sits on {sorted(map(str, on))}, "
+                f"not on all of {[str(d) for d in devices]}")
+        for s in leaf.addressable_shards:
+            held[s.device] += s.data.nbytes
+    return [held[d] for d in devices]
+
+
 def kernels_in(jitted, args) -> dict:
     """Pallas kernels in the program ``jitted`` traces for ``args``, by
     name and count, read from the lowered module: no compile, no run."""
     import jax
 
     def aval(a):
-        if not hasattr(a, "shape"):
+        if not isinstance(a, jax.Array):
             return a
+        # only a placed array says where it lives; a fresh jnp.zeros sits
+        # on the first device by default and would contradict a mesh
         return jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=getattr(a, "sharding", None))
+            a.shape, a.dtype, sharding=a.sharding if a.committed else None)
 
     text = jitted.lower(*jax.tree.map(aval, args)).as_text()
     found: dict = {}
     for name in re.findall(r'kernel_name\s*=\s*"([^"]+)"', text):
         found[name] = found.get(name, 0) + 1
-    # a kernel lowered without a name still shows as a custom call
-    if not found and "tpu_custom_call" in text:
-        found["tpu_custom_call"] = text.count("tpu_custom_call")
     return found
 
 
@@ -193,6 +209,11 @@ def drive_server(model, prompts, new_tokens, *, mesh=None,
                 toks.extend(ev["choices"][0]["token_ids"])
             return toks
 
+        def ledger():       # process-wide: read it as a difference
+            jits = client.request("GET", "/debug/resources")["compiles"]
+            return {k: v["seconds"] for k, v in jits["jits"].items()}
+
+        ledger_before = ledger()
         t0 = time.perf_counter()
         served = [client.completion_tokens(long_p, max_tokens=n_long)]
         t1 = time.perf_counter()
@@ -206,7 +227,9 @@ def drive_server(model, prompts, new_tokens, *, mesh=None,
         served.append(client.completion_tokens(long_p, max_tokens=n_long))
         t3 = time.perf_counter()
         hit_pages = hits() - hits_before
-        compiles = client.request("GET", "/debug/resources")["compiles"]
+        compile_s = {k: round(v - ledger_before.get(k, 0.0), 2)
+                     for k, v in ledger().items()
+                     if v > ledger_before.get(k, 0.0)}
     finally:
         server.stop()
     engine = server.worker.engine
@@ -220,8 +243,7 @@ def drive_server(model, prompts, new_tokens, *, mesh=None,
     if census["leak"] != 0:
         raise RuntimeError(f"page census leaks: {census}")
     return {"served": served, "hit_pages": hit_pages, "census": census,
-            "compile_s": {k: round(v["seconds"], 2)
-                          for k, v in compiles["jits"].items()},
+            "compile_s": compile_s,
             "group_s": [round(t1 - t0, 2), round(t2 - t1, 2),
                         round(t3 - t2, 2)],
             "decode_traces": engine.runner.decode_traces,
@@ -264,10 +286,13 @@ def reference_gaps(model, sequences) -> dict:
     top = jnp.max(picked, axis=-1)
     at = jnp.arange(len(toks))
     gaps = np.asarray(top - picked[at, jnp.asarray(toks)])
-    # the control: each position judged on its NEIGHBOUR's token.  A
-    # reference that cannot tell these from the right ones (constant or
-    # collapsed logits) would pass anything
-    wrong = np.asarray(top - picked[at, jnp.asarray(np.roll(toks, 1))])
+    # the control: each position judged on a token that was NOT served
+    # (half the vocabulary away; a neighbour's token will not do, greedy
+    # decoding of random weights repeats itself).  A reference that cannot
+    # tell these from the right ones (constant or collapsed logits) would
+    # pass anything
+    other = (np.asarray(toks) + logits.shape[-1] // 2) % logits.shape[-1]
+    wrong = np.asarray(top - picked[at, jnp.asarray(other)])
     return {"worst_gap": float(gaps.max()), "positions": len(toks),
             "argmax_agree": int((gaps == 0.0).sum()),
             "control_gap": float(np.median(wrong)),
@@ -289,32 +314,49 @@ def check_served(model, prompts, served) -> dict:
     control = min(long_ref["control_gap"], short_ref["control_gap"])
     if not control > 4 * LOGIT_TOL:
         raise RuntimeError(
-            f"the reference cannot tell a wrong token: the neighbour's "
-            f"token sits {control} below the maximum (long {long_ref}, "
+            f"the reference cannot tell a wrong token: an unserved token "
+            f"sits only {control} below the maximum (long {long_ref}, "
             f"short {short_ref})")
     return {"worst_gap": worst, "tol": LOGIT_TOL, "long": long_ref,
             "short": short_ref}
 
 
-def serve_phase(cfg, devices, *, seed, lengths=(1536, 136, 144, 248, 256),
-                new_tokens=(16, 32), max_model_len=2048) -> dict:
-    """The one-chip server phase; sizes are arguments so the tests can
-    run the same code at a toy width on the CPU."""
+def serve_phase(cfg, devices, *, seed, meshes=(None,),
+                lengths=(1536, 136, 144, 248, 256), new_tokens=(16, 32),
+                max_model_len=2048) -> dict:
+    """One model behind one server per entry of ``meshes`` (``None`` is
+    one chip, ``"tp=4"`` four), each driven over HTTP and held to the one
+    eager reference.  Sizes are arguments so the tests can run the same
+    code at a toy width on the CPU."""
+    from paddle_tpu.serving.parallel.mesh import parse_mesh
+
     t0 = time.perf_counter()
     model = build_llama(cfg, seed)
-    t_build = time.perf_counter() - t0
+    out = {"phase": "serve", "layers": cfg.num_hidden_layers,
+           "hidden": cfg.hidden_size,
+           "build_s": round(time.perf_counter() - t0, 2)}
     prompts = make_prompts(cfg.vocab_size, lengths, seed)
-    out = drive_server(model, prompts, new_tokens,
-                       max_model_len=max_model_len)
-    check = check_served(model, prompts, out["served"])
-    return {"phase": "serve", "layers": cfg.num_hidden_layers,
-            "hidden": cfg.hidden_size, "build_s": round(t_build, 2),
-            "seconds": round(time.perf_counter() - t0, 2),
-            "group_s": out["group_s"], "compile_s": out["compile_s"],
-            "decode_traces": out["decode_traces"],
-            "prefix_hit_pages": out["hit_pages"], "census": out["census"],
-            "logits": check, "kernels": runner_kernels(out["runner"]),
-            "device_bytes": device_bytes(devices)}
+    for mesh in meshes:
+        tp = parse_mesh(mesh)
+        run = drive_server(model, prompts, new_tokens, mesh=mesh,
+                           max_model_len=max_model_len)
+        runner = run.pop("runner")
+        held = shard_bytes(runner.state, devices[:tp])
+        whole = sum(v.nbytes for v in runner.state.values())
+        if tp > 1 and (len(set(held)) != 1 or held[0] >= whole):
+            raise RuntimeError(
+                f"tp={tp} weights are not sharded evenly: {held} of {whole}")
+        out[f"tp{tp}"] = {
+            "logits": check_served(model, prompts, run.pop("served")),
+            "weight_bytes": held,
+            "pool_bytes": shard_bytes((runner.kpool, runner.vpool),
+                                      devices[:tp]),
+            "kernels": runner_kernels(runner),
+            "device_bytes": device_bytes(devices), **run}
+        del runner, run
+        gc.collect()            # the engine holds reference cycles
+    out["seconds"] = round(time.perf_counter() - t0, 2)
+    return out
 
 
 # ------------------------------------------------------------------ train
@@ -358,10 +400,10 @@ def train_phase(cfg, devices, *, seed, batch=32, seq=384, max_steps=10,
     else:
         raise RuntimeError(f"loss never fell below the first: {losses}")
 
-    for name, p in model.named_parameters():
-        if p._data.devices() != {devices[0]}:
-            raise RuntimeError(f"{name} is on {p._data.devices()}")
     params = {k: p._data for k, p in model.named_parameters()}
+    for name, p in params.items():
+        if p.devices() != {devices[0]}:
+            raise RuntimeError(f"{name} is on {p.devices()}")
     bufs = {"buffers." + k: b._data for k, b in model.named_buffers()}
     kernels = kernels_in(step._compiled, (
         params, bufs, o.opt_state(), jax.random.key(0), ids._data,
@@ -373,6 +415,71 @@ def train_phase(cfg, devices, *, seed, batch=32, seq=384, max_steps=10,
             "step_s": step_s, "losses": losses, "kernels": kernels,
             "params_on": str(devices[0]),
             "device_bytes": device_bytes(devices)}
+
+
+# ------------------------------------------------------------- four chips
+# The two trainer layouts start from one seed and one batch, so their
+# losses are one function evaluated under two shardings.  They differ by
+# where bf16 partial sums are rounded (row-parallel o/down products are
+# reduced over 4 shards or over 2): ~2**-8 relative on activations, which
+# a mean over 8192 tokens of a loss near ln(128256) + 0.5 = 12.3 carries
+# at the 1e-3 level.  A shard dropped or counted twice changes the logit
+# variance and moves the loss by tenths.
+LOSS_TOL = 0.02
+
+
+def hybrid_phase(cfg, devices, *, seed, layouts=((1, 1, 4), (1, 2, 2)),
+                 batch=4, seq=2048, steps=3, dtype="bfloat16",
+                 tol=LOSS_TOL) -> dict:
+    """``llama_hybrid`` for a few steps under each (pp, dp, tp) layout,
+    from one seed and one batch; the first-step losses must agree."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from paddle_tpu.models import llama_hybrid as H
+
+    t0 = time.perf_counter()
+    ids_np = np.random.RandomState(seed).randint(
+        0, cfg.vocab_size, (batch, seq + 1)).astype(np.int64)
+    out = {"phase": "hybrid_train", "layers": cfg.num_hidden_layers,
+           "hidden": cfg.hidden_size, "batch": batch, "seq": seq,
+           "tol": tol}
+    first = {}
+    for pp, dp, tp in layouts:
+        name = f"pp{pp}_dp{dp}_tp{tp}"
+        mesh = H.build_mesh(len(devices), pp=pp, dp=dp, tp=tp,
+                            devices=devices)
+        params, opt = H.setup(cfg, mesh, seed=seed, dtype=jnp.dtype(dtype))
+        placed = {"param_bytes": shard_bytes(params, devices),
+                  "adam_bytes": shard_bytes((opt.m, opt.v), devices)}
+        step = H.build_train_step(cfg, mesh, n_micro=1, remat=True,
+                                  sp=False)
+        ids = jax.device_put(ids_np, NamedSharding(mesh, P("dp", None)))
+        kernels = kernels_in(step, (params, opt, ids))
+        losses, step_s = [], []
+        for _ in range(steps):
+            t = time.perf_counter()
+            loss, params, opt = step(params, opt, ids)
+            losses.append(float(loss))          # host fetch ends the step
+            step_s.append(round(time.perf_counter() - t, 3))
+        if not np.all(np.isfinite(losses)):
+            raise RuntimeError(f"{name}: loss is not finite: {losses}")
+        n_params = sum(x.size for x in jax.tree.leaves(params))
+        out[name] = {"params": int(n_params), "losses": losses,
+                     "step_s": step_s, "kernels": kernels, **placed,
+                     "param_bytes_after": shard_bytes(params, devices),
+                     "device_bytes": device_bytes(devices)}
+        first[name] = losses[0]
+        del params, opt, step, ids
+        gc.collect()
+    spread = max(first.values()) - min(first.values())
+    out["first_loss_spread"] = spread
+    if not spread <= tol:
+        raise RuntimeError(
+            f"layouts disagree on the first-step loss: {first} "
+            f"(spread {spread} > {tol})")
+    out["seconds"] = round(time.perf_counter() - t0, 2)
+    return out
 
 
 # ------------------------------------------------------------------- main
@@ -396,11 +503,19 @@ def main(argv=None) -> int:
     cfg = llama3_8b()
     cfg.num_hidden_layers = 8           # depth is the only cut
     if args.four_chips:
-        raise SystemExit("chip_smoke: --four-chips is not built yet")
-    say(**serve_phase(cfg, devices, seed=SEED))
-    gc.collect()        # the engine holds reference cycles, and 6 GB
-    say(**train_phase(BertConfig(), devices, seed=SEED))
-    interpret_is_off()
+        # the trainer goes first: it frees what it placed, while a model
+        # that has run an eager forward stays on its device (the eager
+        # segment cache keeps the layer alive), and 5.6 GB left on chip 0
+        # would not leave room for the dp=2 x tp=2 step's 10.3 GB
+        trainer_cfg = llama3_8b()
+        trainer_cfg.num_hidden_layers = 2
+        say(**hybrid_phase(trainer_cfg, devices[:4], seed=SEED))
+        gc.collect()
+        say(**serve_phase(cfg, devices[:4], seed=SEED,
+                          meshes=("tp=4", None)))
+    else:
+        say(**serve_phase(cfg, devices, seed=SEED))
+        say(**train_phase(BertConfig(), devices, seed=SEED))
 
     d = devices[0]
     print(json.dumps({"ok": True, "device": {

@@ -19,9 +19,6 @@ import jax as _jax
 # so TPU matmuls stay on the MXU.
 _jax.config.update("jax_enable_x64", True)
 
-from . import jax_compat as _jax_compat
-_jax_compat.install()
-
 # -- core types ------------------------------------------------------------
 from .framework import dtype as _dtype_mod
 from .framework.dtype import (  # noqa: F401

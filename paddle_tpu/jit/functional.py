@@ -70,10 +70,9 @@ class TrainStep:
     def multi_step(self, n):
         """Compile an n-step training scan: ONE device dispatch runs n
         optimizer steps on the same batch argument (pass fresh batches
-        per call for real epochs).  This amortizes per-dispatch latency
-        — essential on tunneled/remote device transports where each
-        dispatch costs tens of ms — mirroring how the reference's
-        Executor replays a whole program per run call.
+        per call for real epochs).  This amortizes per-dispatch host
+        latency, mirroring how the reference's Executor replays a whole
+        program per run call.
 
             many = paddle.jit.train_step(model, opt, loss_fn).multi_step(10)
             loss = many(x, y)     # 10 steps, one dispatch

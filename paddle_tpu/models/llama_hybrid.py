@@ -41,7 +41,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .llama import LlamaConfig, _rope_tables, apply_rotary_pos_emb
 from ..distributed.pipeline_schedules import pipeline_1f1b
-from ..ops.pallas.flash_attention import sdpa
+from ..ops.pallas.flash_attention import kernel_route, sdpa
 
 
 # ----------------------------------------------------------------- mesh
@@ -127,7 +127,31 @@ def _rms(x, w, eps):
     return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype) * w
 
 
-def _decoder_layer(lp, x, cos, sin, config: LlamaConfig):
+def _attention(q, k, v, mesh):
+    """Causal attention over [mb, S, heads, D], one call per shard.
+
+    The Pallas kernel is one program per device and the partitioner
+    cannot split it ("Mosaic kernels cannot be automatically partitioned.
+    Please wrap the call in a shard_map"), so on a mesh the call is
+    mapped: batch over dp, heads over tp.  Attention needs no collective
+    — every head sees its whole sequence — and q heads stay beside their
+    kv heads because tp divides both counts.  Every mesh axis has to be
+    manual around a Mosaic call: with pp > 1 the layer already runs
+    inside the pipeline's pp-manual region and dp, tp complete the set;
+    with pp == 1 nothing is manual yet and all three are named."""
+    if mesh is None or mesh.size == 1 or not kernel_route(q, k, v):
+        return sdpa(q, k, v, is_causal=True)    # XLA: GSPMD partitions it
+    nested = mesh.shape["pp"] > 1       # then the context mesh is used
+    axes = {"dp", "tp"} if nested else set(mesh.axis_names)
+    spec = P("dp", None, "tp", None)
+    return jax.shard_map(
+        lambda q, k, v: sdpa(q, k, v, is_causal=True),
+        mesh=None if nested else mesh, in_specs=(spec, spec, spec),
+        out_specs=spec, axis_names=frozenset(axes),
+        check_vma=False)(q, k, v)
+
+
+def _decoder_layer(lp, x, cos, sin, config: LlamaConfig, mesh=None):
     """One decoder layer, functional. x: [mb, S, H]."""
     nh, kvh, hd = (config.num_attention_heads, config.num_key_value_heads,
                    config.head_dim)
@@ -138,7 +162,7 @@ def _decoder_layer(lp, x, cos, sin, config: LlamaConfig):
     k = (h @ lp["k"]).reshape(b, sq, kvh, hd)
     v = (h @ lp["v"]).reshape(b, sq, kvh, hd)
     q, k = apply_rotary_pos_emb(q, k, cos, sin)
-    a = sdpa(q, k, v, is_causal=True)
+    a = _attention(q, k, v, mesh)
     from jax.ad_checkpoint import checkpoint_name as _ckpt_name
     a = _ckpt_name(a, "attn_out")
     x = r + (a.reshape(b, sq, nh * hd) @ lp["o"])
@@ -157,12 +181,13 @@ import os as _os
 UNROLL_STAGE = _os.environ.get("PADDLE_TPU_UNROLL_STAGE", "0") == "1"
 
 
-def _stage_fn(stage_params, x, cos, sin, config, remat=True):
+def _stage_fn(stage_params, x, cos, sin, config, remat=True, mesh=None):
     """Apply this stage's layers_per_stage layers (leaves [lps, ...]).
     remat: True = full per-layer checkpoint; "attn" = checkpoint but keep
     the flash-attention outputs resident (skips the most expensive
     recompute for ~1 GB at 1B/2k/8 scale); False = no remat."""
-    body = functools.partial(_decoder_layer, cos=cos, sin=sin, config=config)
+    body = functools.partial(_decoder_layer, cos=cos, sin=sin, config=config,
+                             mesh=mesh)
     if remat == "attn":
         body = jax.checkpoint(
             body, policy=jax.checkpoint_policies.save_only_these_names(
@@ -192,7 +217,8 @@ def pipelined_trunk(stacked, mbs, cos, sin, config, mesh, remat=True):
     if n_pp == 1:
         squeeze = jax.tree_util.tree_map(lambda a: a[0], stacked)
         return jax.vmap(
-            lambda mb: _stage_fn(squeeze, mb, cos, sin, config, remat))(mbs)
+            lambda mb: _stage_fn(squeeze, mb, cos, sin, config, remat,
+                                 mesh))(mbs)
 
     def per_device(stk, mbs):
         lp = jax.tree_util.tree_map(lambda a: a[0], stk)  # my stage
@@ -205,7 +231,7 @@ def pipelined_trunk(stacked, mbs, cos, sin, config, mesh, remat=True):
             state, outs = carry
             inj = mbs[jnp.minimum(t, m - 1)]
             state = jnp.where(stage == 0, inj, state)
-            state = _stage_fn(lp, state, cos, sin, config, remat)
+            state = _stage_fn(lp, state, cos, sin, config, remat, mesh)
             oi = t - (n_pp - 1)
             ok = jnp.logical_and(stage == n_pp - 1,
                                  jnp.logical_and(oi >= 0, oi < m))
@@ -313,7 +339,7 @@ def grad_1f1b(params, ids, config: LlamaConfig, mesh: Mesh, n_micro,
         return jnp.take(fp["embed"], aux_j[:, :-1], axis=0)
 
     def stage_fn(cp, x):
-        return _stage_fn(cp, x, cos, sin, config, remat)
+        return _stage_fn(cp, x, cos, sin, config, remat, mesh)
 
     def last_fn(lp, y, aux_j):
         h = _rms(y, lp["norm"], config.rms_norm_eps)
@@ -346,9 +372,16 @@ def _f32_zeros_like(params):
 
 
 def init_adamw(params):
-    z = _f32_zeros_like(params)
-    return AdamWState(jnp.zeros((), jnp.int32), z,
-                      jax.tree_util.tree_map(jnp.copy, z))
+    """Zero AdamW state placed like the (concrete) params: each zero
+    buffer is made shard by shard where its param lives.  Plain
+    ``jnp.zeros`` lands whole on ``jax.devices()[0]`` and the first step
+    then replicates it — 12 GB of state on every chip for a 1.5 B model
+    whose shards are 0.4 B."""
+    def zeros(p):
+        return jnp.zeros(p.shape, jnp.float32, device=p.sharding)
+    return AdamWState(jnp.zeros((), jnp.int32),
+                      jax.tree_util.tree_map(zeros, params),
+                      jax.tree_util.tree_map(zeros, params))
 
 
 def build_train_step(config: LlamaConfig, mesh: Mesh, lr=3e-4, wd=0.01,

@@ -135,6 +135,20 @@ def _pad_seq(x, target):
     return jnp.pad(x, ((0, 0), (0, target - s), (0, 0), (0, 0)))
 
 
+def kernel_route(q, k, v, dropout_p=0.0) -> bool:
+    """Whether :func:`sdpa` sends these operands to the Pallas kernels
+    (given a mask form they can express): decided on what is visible in
+    the input.  Callers that must treat a kernel call differently from an
+    XLA one (a Mosaic call cannot be partitioned automatically) ask here
+    instead of repeating the rule."""
+    return (
+        dropout_p == 0.0
+        and q.dtype == k.dtype == v.dtype   # kernels matmul in input dtype
+        and q.shape[-1] in (64, 128, 256)
+        and q.shape[1] >= 128 and k.shape[1] >= 128
+        and jax.default_backend() not in ("cpu",))
+
+
 def sdpa(q, k, v, attn_mask=None, dropout_p=0.0, is_causal=False,
          training=True, flashmask=None):
     """Paddle-layout scaled-dot-product attention: [B, S, H, D] in/out.
@@ -152,12 +166,7 @@ def sdpa(q, k, v, attn_mask=None, dropout_p=0.0, is_causal=False,
     arbitrary bool masks, fewer than 128 positions, the CPU backend)
     routes to the XLA path: a choice made on the input, never a rescue
     from a kernel that failed."""
-    shapes_ok = (
-        dropout_p == 0.0
-        and q.dtype == k.dtype == v.dtype   # kernels matmul in input dtype
-        and q.shape[-1] in (64, 128, 256)
-        and q.shape[1] >= 128 and k.shape[1] >= 128
-        and jax.default_backend() not in ("cpu",))
+    shapes_ok = kernel_route(q, k, v, dropout_p)
 
     mask_vecs = flashmask
     bias = None

@@ -1357,6 +1357,8 @@ def _main(argv=None):
 
     import paddle_tpu as paddle
     from ..models.llama import LlamaForCausalLM, llama_tiny
+    from ..utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     paddle.seed(0)
     cfg = llama_tiny(num_hidden_layers=args.layers,
                      hidden_size=args.hidden,

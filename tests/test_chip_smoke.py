@@ -79,6 +79,44 @@ def test_runner_kernels_relowers_every_program(served):
     assert all(v == {} for v in kernels.values())   # the CPU has none
 
 
+def test_serve_phase_on_a_virtual_mesh():
+    """What --four-chips runs, at toy width on the virtual CPU devices:
+    tp=2 beside tp=1 from one model, shards counted per device."""
+    devices = jax.devices()[:2]
+    out = C.serve_phase(tiny_llama(), devices, seed=C.SEED,
+                        meshes=("tp=2", None), lengths=LENGTHS,
+                        new_tokens=(6, 8), max_model_len=256)
+    two, one = out["tp2"]["weight_bytes"], out["tp1"]["weight_bytes"]
+    assert len(two) == 2 and two[0] == two[1] < one[0]
+    assert out["tp2"]["pool_bytes"][0] * 2 == out["tp1"]["pool_bytes"][0]
+    for run in (out["tp2"], out["tp1"]):
+        assert run["logits"]["worst_gap"] <= C.LOGIT_TOL
+        assert run["census"]["leak"] == 0 and run["decode_traces"] == 1
+    json.dumps(out)
+
+
+def test_hybrid_phase_layouts_agree():
+    cfg = tiny_llama()
+    out = C.hybrid_phase(cfg, jax.devices()[:4], seed=C.SEED, batch=4,
+                         seq=128)
+    a, b = out["pp1_dp1_tp4"], out["pp1_dp2_tp2"]
+    assert out["first_loss_spread"] <= C.LOSS_TOL
+    assert a["params"] == b["params"]
+    # tp=4 holds a quarter of every sharded leaf, dp=2 x tp=2 a half, and
+    # the Adam state sits where the params do (f32 m and v: 4x the bytes)
+    assert len(set(a["param_bytes"])) == 1 and len(set(b["param_bytes"])) == 1
+    assert a["param_bytes"][0] < b["param_bytes"][0]
+    assert a["adam_bytes"][0] == 4 * a["param_bytes"][0]
+    json.dumps(out)
+
+
+def test_shard_bytes_refuses_a_leaf_left_on_one_device():
+    import jax.numpy as jnp
+    devices = jax.devices()[:2]
+    with pytest.raises(RuntimeError, match="not on all of"):
+        C.shard_bytes({"w": jnp.zeros((4, 4))}, devices)
+
+
 def test_train_phase_loss_falls():
     cfg = BertConfig(vocab_size=512, hidden_size=128, num_hidden_layers=2,
                      num_attention_heads=4, intermediate_size=256)

@@ -37,9 +37,10 @@ def run(tag):
           flush=True)
 
 
-def unrolled_stage(stage_params, x, cos, sin, config, remat=True):
+def unrolled_stage(stage_params, x, cos, sin, config, remat=True,
+                   mesh=None):
     body = functools.partial(H._decoder_layer, cos=cos, sin=sin,
-                             config=config)
+                             config=config, mesh=mesh)
     if remat == "attn":
         body = jax.checkpoint(
             body, policy=jax.checkpoint_policies.save_only_these_names(

@@ -1092,6 +1092,8 @@ def bench_args(**overrides) -> argparse.Namespace:
 
 def main(argv=None):
     args = _build_parser().parse_args(argv)
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.http:
         res = run_http_bench(args)
     elif args.overload_baseline:
