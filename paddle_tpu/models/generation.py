@@ -200,28 +200,32 @@ def _prefill_layer(w, x, cos, sin, mask, cfg: LlamaConfig, lora=(),
     b, s, _ = x.shape
     nh, kvh, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    cfg.head_dim)
-    h = _rms(x, w["ln1"], cfg.rms_norm_eps)
-    qp, kp, vp = _qkv_proj(w, h, nh, kvh, hd, lora, aidx, li)
-    q = qp.reshape(b, s, nh, hd)
-    k = kp.reshape(b, s, kvh, hd)
-    v = vp.reshape(b, s, kvh, hd)
-    cos_c = cos[None, :, None, :].astype(q.dtype)
-    sin_c = sin[None, :, None, :].astype(q.dtype)
-    q = q * cos_c + _rotate_half(q) * sin_c
-    k = k * cos_c + _rotate_half(k) * sin_c
+    with jax.named_scope("attn.qkv"):
+        h = _rms(x, w["ln1"], cfg.rms_norm_eps)
+        qp, kp, vp = _qkv_proj(w, h, nh, kvh, hd, lora, aidx, li)
+        q = qp.reshape(b, s, nh, hd)
+        k = kp.reshape(b, s, kvh, hd)
+        v = vp.reshape(b, s, kvh, hd)
+        cos_c = cos[None, :, None, :].astype(q.dtype)
+        sin_c = sin[None, :, None, :].astype(q.dtype)
+        q = q * cos_c + _rotate_half(q) * sin_c
+        k = k * cos_c + _rotate_half(k) * sin_c
 
-    # flash path: causal + key-padding mask, GQA in-kernel, O(S) memory
-    # (the naive [B,H,S,S] fp32 logits OOM long-prompt prefill)
-    from ..ops.pallas.flash_attention import sdpa
-    attn = sdpa(q, k, v, attn_mask=mask[:, None, None, :],
-                is_causal=True).reshape(b, s, nh * hd)
-    o = _mm(attn, w["o"])
-    if lora:
-        from ..ops.pallas.lora_matmul import lora_delta
-        o = o + lora_delta(lora, "o", li, attn, aidx)
-    x = x + o
-    h = _rms(x, w["ln2"], cfg.rms_norm_eps)
-    return (x + _ffn(w, h, lora, aidx, li), k, v)
+    with jax.named_scope("attn.prefill"):
+        # flash path: causal + key-padding mask, GQA in-kernel, O(S) memory
+        # (the naive [B,H,S,S] fp32 logits OOM long-prompt prefill)
+        from ..ops.pallas.flash_attention import sdpa
+        attn = sdpa(q, k, v, attn_mask=mask[:, None, None, :],
+                    is_causal=True).reshape(b, s, nh * hd)
+    with jax.named_scope("attn.out"):
+        o = _mm(attn, w["o"])
+        if lora:
+            from ..ops.pallas.lora_matmul import lora_delta
+            o = o + lora_delta(lora, "o", li, attn, aidx)
+        x = x + o
+    with jax.named_scope("mlp"):
+        h = _rms(x, w["ln2"], cfg.rms_norm_eps)
+        return (x + _ffn(w, h, lora, aidx, li), k, v)
 
 
 # ------------------------------------------------------------ decode step
@@ -271,41 +275,46 @@ def _decode_layer_paged(w, x, kpool, vpool, table, cos1, sin1, pos,
     nh, kvh, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    cfg.head_dim)
     ps = kpool.shape[2]
-    h = _rms(x[:, None], w["ln1"], cfg.rms_norm_eps)[:, 0]
-    qp, kp, vp = _qkv_proj(w, h, nh, kvh, hd, lora, aidx, li)
-    q = qp.reshape(b, nh, hd)
-    k = kp.reshape(b, kvh, hd)
-    v = vp.reshape(b, kvh, hd)
-    cos_c = cos1[:, None, :].astype(q.dtype)
-    sin_c = sin1[:, None, :].astype(q.dtype)
-    q = q * cos_c + _rotate_half(q) * sin_c
-    k = k * cos_c + _rotate_half(k) * sin_c
+    with jax.named_scope("attn.qkv"):
+        h = _rms(x[:, None], w["ln1"], cfg.rms_norm_eps)[:, 0]
+        qp, kp, vp = _qkv_proj(w, h, nh, kvh, hd, lora, aidx, li)
+        q = qp.reshape(b, nh, hd)
+        k = kp.reshape(b, kvh, hd)
+        v = vp.reshape(b, kvh, hd)
+        cos_c = cos1[:, None, :].astype(q.dtype)
+        sin_c = sin1[:, None, :].astype(q.dtype)
+        q = q * cos_c + _rotate_half(q) * sin_c
+        k = k * cos_c + _rotate_half(k) * sin_c
 
-    page = jnp.take_along_axis(table, (pos // ps)[:, None], axis=1)[:, 0]
-    off = pos % ps
-    heads = jnp.arange(kvh)
-    kpool = kpool.at[page[:, None], heads[None, :], off[:, None]].set(k)
-    vpool = vpool.at[page[:, None], heads[None, :], off[:, None]].set(v)
+    with jax.named_scope("kv.write"):
+        page = jnp.take_along_axis(table, (pos // ps)[:, None], axis=1)[:, 0]
+        off = pos % ps
+        heads = jnp.arange(kvh)
+        kpool = kpool.at[page[:, None], heads[None, :], off[:, None]].set(k)
+        vpool = vpool.at[page[:, None], heads[None, :], off[:, None]].set(v)
 
-    from ..ops.pallas.paged_attention import select_paged_attention
-    attn = select_paged_attention()(
-        q, kpool, vpool, table, pos + 1).reshape(b, nh * hd)
-    o = _mm(attn, w["o"])
-    if lora:
-        from ..ops.pallas.lora_matmul import lora_delta
-        o = o + lora_delta(lora, "o", li, attn, aidx)
-    x = x + o
-    h = _rms(x[:, None], w["ln2"], cfg.rms_norm_eps)[:, 0]
-    g = _mm(h, w["gate"])
-    u = _mm(h, w["up"])
-    if lora:
-        g = g + lora_delta(lora, "gate", li, h, aidx)
-        u = u + lora_delta(lora, "up", li, h, aidx)
-    act = jax.nn.silu(g) * u
-    d = _mm(act, w["down"])
-    if lora:
-        d = d + lora_delta(lora, "down", li, act, aidx)
-    return (x + d, kpool, vpool)
+    with jax.named_scope("attn.decode"):
+        from ..ops.pallas.paged_attention import select_paged_attention
+        attn = select_paged_attention()(
+            q, kpool, vpool, table, pos + 1).reshape(b, nh * hd)
+    with jax.named_scope("attn.out"):
+        o = _mm(attn, w["o"])
+        if lora:
+            from ..ops.pallas.lora_matmul import lora_delta
+            o = o + lora_delta(lora, "o", li, attn, aidx)
+        x = x + o
+    with jax.named_scope("mlp"):
+        h = _rms(x[:, None], w["ln2"], cfg.rms_norm_eps)[:, 0]
+        g = _mm(h, w["gate"])
+        u = _mm(h, w["up"])
+        if lora:
+            g = g + lora_delta(lora, "gate", li, h, aidx)
+            u = u + lora_delta(lora, "up", li, h, aidx)
+        act = jax.nn.silu(g) * u
+        d = _mm(act, w["down"])
+        if lora:
+            d = d + lora_delta(lora, "down", li, act, aidx)
+        return (x + d, kpool, vpool)
 
 
 # --------------------------------------------------------------- sampling

@@ -19,9 +19,17 @@ metrics registry cannot:
     of what the engine was doing when it wedged (reference analog:
     CommTaskManager's hang dumps).
 
-Both are pure stdlib, lock-bounded, and cheap enough to stay on in
-production: recording a span is two ``perf_counter`` calls and one
-deque append.
+Both are lock-bounded and cheap enough to stay on in production:
+recording a span is two ``perf_counter`` calls and one deque append.
+
+:meth:`Tracer.phase` is the one span source of the serving loop: the
+interval it takes itself lands in the ring, is handed back to the
+caller (``ph.seconds``, which the engine adds to its ``timings``), and
+is open as a ``jax.profiler.TraceAnnotation`` of the same name for as
+long, so any ``jax.profiler`` capture of a live server shows the
+``engine.*`` rows on the host thread above the device's rows, on the
+device trace's clock.  With no capture running the annotation is a
+flag test.
 """
 from __future__ import annotations
 
@@ -33,6 +41,8 @@ import threading
 import time
 from collections import deque
 from typing import NamedTuple
+
+from jax.profiler import TraceAnnotation
 
 from ..sanitizer import make_lock
 
@@ -101,10 +111,12 @@ class Span:
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "start",
                  "end_time", "attributes", "events", "pid", "tid",
-                 "thread_name", "_tracer", "_token", "_ended")
+                 "thread_name", "_tracer", "_token", "_ended",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
-                 parent_id: str | None, attributes: dict | None):
+                 parent_id: str | None, attributes: dict | None,
+                 annotation=None):
         self._tracer = tracer
         self.name = name
         self.trace_id = trace_id
@@ -120,6 +132,8 @@ class Span:
         self.thread_name = t.name
         self._token = None
         self._ended = False
+        # what Tracer.phase hands over: open for as long as the span
+        self._annotation = annotation
 
     @property
     def context(self) -> SpanContext:
@@ -128,6 +142,11 @@ class Span:
     @property
     def duration(self) -> float | None:
         return None if self.end_time is None else self.end_time - self.start
+
+    @property
+    def seconds(self) -> float:
+        """The interval as a number to add up: 0.0 while still open."""
+        return self.duration or 0.0
 
     def set_attribute(self, key: str, value) -> "Span":
         self.attributes[key] = value
@@ -149,6 +168,11 @@ class Span:
         self._tracer._commit(self)
 
     def __enter__(self) -> "Span":
+        if self._annotation is not None:
+            # a phase: the profiler's row opens first and closes last,
+            # so the ring's interval lies inside it
+            self._annotation.__enter__()
+            self.start = time.perf_counter()
         self._token = _CURRENT.set(self)
         return self
 
@@ -159,6 +183,9 @@ class Span:
         if exc is not None:
             self.attributes.setdefault("error", repr(exc))
         self.end()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
 
     def to_dict(self) -> dict:
         return {"name": self.name, "trace_id": self.trace_id,
@@ -201,7 +228,8 @@ class Tracer:
 
     # ------------------------------------------------------------- spans
     def start_span(self, name: str, parent=_INHERIT,
-                   attributes: dict | None = None) -> Span:
+                   attributes: dict | None = None, *,
+                   _annotation=None) -> Span:
         """Open a span.  ``parent`` may be a :class:`Span`, a
         :class:`SpanContext`, ``None`` (force a new root trace), or
         omitted (inherit the context-local current span)."""
@@ -213,7 +241,18 @@ class Tracer:
             trace_id, parent_id = parent.trace_id, parent.span_id
         else:
             trace_id, parent_id = _new_trace_id(), None
-        return Span(self, name, trace_id, parent_id, attributes)
+        return Span(self, name, trace_id, parent_id, attributes,
+                    _annotation)
+
+    def phase(self, name: str, parent=_INHERIT, **attributes) -> Span:
+        """A span to use as ``with tracer.phase(name) as ph:`` around
+        one piece of host work.  For as long as the block runs the span
+        is the context-local parent and a ``jax.profiler.
+        TraceAnnotation(name)`` is open; on exit ONE span with the
+        ``perf_counter`` interval taken here is committed to the ring,
+        and ``ph.seconds`` hands the same interval to the caller."""
+        return self.start_span(name, parent=parent, attributes=attributes,
+                               _annotation=TraceAnnotation(name))
 
     def record_span(self, name: str, start: float, end: float, *,
                     parent=None, attributes: dict | None = None) -> Span:

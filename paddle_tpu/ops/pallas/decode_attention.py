@@ -124,6 +124,7 @@ def _pallas_decode(q, kcache, vcache, pos, block_t):
                 pltpu.VMEM((rep, NUM_LANES), jnp.float32),
             ],
             interpret=_INTERPRET,
+            name="decode_attention",
         )(qg, kcache, vcache, pos_b)
     return out.reshape(b, nh, d)
 

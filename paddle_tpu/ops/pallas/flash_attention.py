@@ -468,6 +468,7 @@ def _flash_fwd_x32(q, k, v, causal, sm_scale, block_q, block_k, sq_real,
         out_specs=out_specs if need_lse else out_specs[0],
         out_shape=out_shape if need_lse else out_shape[0],
         interpret=_INTERPRET,
+        name="flash_fwd",
     )(q, k, v)
     return res if need_lse else (res, None)
 
@@ -614,6 +615,7 @@ def _flash_fwd_stream(q, k, v, causal, sm_scale, block_q, block_k,
                         pltpu.VMEM((block_q, NUM_LANES), jnp.float32),
                         pltpu.VMEM((block_q, NUM_LANES), jnp.float32)],
         interpret=_INTERPRET,
+        name="flash_fwd_stream",
     )(q, k, v)
     return res if need_lse else (res, None)
 
@@ -749,6 +751,7 @@ def _flash_bwd_stream(q, k, v, out, lse, g, causal, sm_scale, block_q,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
                         pltpu.VMEM((block_q, NUM_LANES), jnp.float32)],
         interpret=_INTERPRET,
+        name="flash_bwd_dq_stream",
     )(q, k, v, g, out, lse)
 
     blk_k4 = pl.BlockSpec((None, None, block_k, d),
@@ -772,6 +775,7 @@ def _flash_bwd_stream(q, k, v, out, lse, g, causal, sm_scale, block_q,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=_INTERPRET,
+        name="flash_bwd_dkv_stream",
     )(q, k, v, g, out, lse)
     if grp > 1:
         dk = dk.reshape(b, hk, grp, sk, d).sum(axis=2)
@@ -900,6 +904,7 @@ def _flash_bwd_x32(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
         out_specs=blk_q(),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=_INTERPRET,
+        name="flash_bwd_dq",
     )(q, k, v, g, out, lse)
 
     blk_k = lambda: pl.BlockSpec((None, None, block_k, d),
@@ -918,6 +923,7 @@ def _flash_bwd_x32(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
         out_shape=[jax.ShapeDtypeStruct((b, h, sk, d), k.dtype),
                    jax.ShapeDtypeStruct((b, h, sk, d), v.dtype)],
         interpret=_INTERPRET,
+        name="flash_bwd_dkv",
     )(q, k, v, g, out, lse)
     if grp > 1:
         dk = dk.reshape(b, hk, grp, sk, d).sum(axis=2)

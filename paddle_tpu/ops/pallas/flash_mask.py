@@ -237,6 +237,7 @@ def _masked_fwd(q, k, v, mask_vecs, bias, causal, sm_scale, block_q,
             out_specs=out_specs if need_lse else out_specs[0],
             out_shape=out_shape if need_lse else out_shape[0],
             interpret=interpret,
+            name="flash_mask_fwd",
         )(*args)
     return res if need_lse else (res, None)
 
@@ -606,6 +607,7 @@ def _masked_fwd_stream(q, k, v, mask_vecs, bias, causal, sm_scale,
                             pltpu.VMEM((block_q, NUM_LANES), jnp.float32),
                             pltpu.VMEM((block_q, NUM_LANES), jnp.float32)],
             interpret=interpret,
+            name="flash_mask_fwd_stream",
         )(*args)
     return res if need_lse else (res, None)
 
@@ -651,6 +653,7 @@ def _masked_bwd_stream(q, k, v, out, lse, g, mask_vecs, bias, causal,
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
                             pltpu.VMEM((block_q, NUM_LANES), jnp.float32)],
             interpret=interpret,
+            name="flash_mask_bwd_dq_stream",
         )(q, k, v, g, out, lse_b, *mb_args)
 
         blk_k4 = pl.BlockSpec((None, None, block_k, d),
@@ -680,6 +683,7 @@ def _masked_bwd_stream(q, k, v, out, lse, g, mask_vecs, bias, causal,
             scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                             pltpu.VMEM((block_k, d), jnp.float32)],
             interpret=interpret,
+            name="flash_mask_bwd_dkv_stream",
         )(q, k, v, g, out, lse_b, *mb_args)
         if grp > 1:
             dk = dk.reshape(b, hk, grp, sk, d).sum(axis=2)
@@ -740,6 +744,7 @@ def _masked_bwd_stream(q, k, v, out, lse, g, mask_vecs, bias, causal,
                 scratch_shapes=[pltpu.VMEM((block_q, block_k),
                                            jnp.float32)],
                 interpret=interpret,
+                name="flash_mask_bwd_dbias_stream",
             )(*d_args).astype(bias.dtype)
     return dq, dk, dv, dbias
 
@@ -936,6 +941,7 @@ def _masked_bwd(q, k, v, out, lse, g, mask_vecs, bias, causal, sm_scale,
             out_specs=blk_q,
             out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
             interpret=interpret,
+            name="flash_mask_bwd_dq",
         )(q, k, v, g, lse_b, delta,
           *(tail_args + ([bias] if has_bias else [])))
 
@@ -965,6 +971,7 @@ def _masked_bwd(q, k, v, out, lse, g, mask_vecs, bias, causal, sm_scale,
             out_shape=[jax.ShapeDtypeStruct((b, h, sk, d), k.dtype),
                        jax.ShapeDtypeStruct((b, h, sk, d), v.dtype)],
             interpret=interpret,
+            name="flash_mask_bwd_dkv",
         )(q, k, v, g, lse_b, delta,
           *(tail_args + ([bias] if has_bias else [])))
         if grp > 1:
@@ -986,6 +993,7 @@ def _masked_bwd(q, k, v, out, lse, g, mask_vecs, bias, causal, sm_scale,
                 out_shape=jax.ShapeDtypeStruct((b, h, sq, sk),
                                                jnp.float32),
                 interpret=interpret,
+                name="flash_mask_bwd_dbias",
             )(q, k, v, g, lse_b, delta, *(tail_args + [bias]))
             # reduce over broadcast dims back to the bias shape
             red = []

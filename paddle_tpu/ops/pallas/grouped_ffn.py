@@ -120,6 +120,7 @@ def _call_fwd(x_buf, w1, b1, w2, b2, emap, gated):
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((r, dout), x_buf.dtype),
             interpret=_INTERPRET,
+            name="grouped_ffn_fwd",
         )(emap.astype(jnp.int32), x_buf, w1, b1, w2, b2)
 
 
@@ -163,6 +164,7 @@ def _call_bwd(x_buf, dy, w1, b1, w2, emap, gated):
                 jax.ShapeDtypeStruct((e, dout), f32),
             ],
             interpret=_INTERPRET,
+            name="grouped_ffn_bwd",
         )(emap.astype(jnp.int32), x_buf, dy, w1, b1, w2)
 
 

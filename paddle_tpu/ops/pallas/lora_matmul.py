@@ -86,6 +86,7 @@ def _pallas_gather_matmul(x, a, b, scale, idx):
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((s, m), x.dtype),
             interpret=_INTERPRET,
+            name="lora_matmul",
         )(idx.astype(jnp.int32), x, a, b, svec)
 
 

@@ -161,6 +161,7 @@ def paged_attention(q, kpool, vpool, table, lens):
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, kvh, rep, d), q.dtype),
             interpret=_INTERPRET,
+            name="paged_attention",
         )(table.astype(jnp.int32), lens.astype(jnp.int32), qg, kpool,
           vpool)
     return out.reshape(b, nh, d)

@@ -157,6 +157,7 @@ def _pallas_int8(x, q, scale, bn):
             out_specs=pl.BlockSpec((m, bn), lambda i: (0, i)),
             out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
             interpret=_INTERPRET,
+            name="quant_matmul_int8",
         )(x, q, s2)
 
 
@@ -180,6 +181,7 @@ def _pallas_int4(x, packed, scale, k, bn):
             out_specs=pl.BlockSpec((m, bn), lambda i: (0, i)),
             out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
             interpret=_INTERPRET,
+            name="quant_matmul_int4",
         )(xe, xo, packed, s2)
 
 

@@ -55,6 +55,7 @@ def _pallas_rms(x2d, w, eps):
                       pl.BlockSpec((1, d), lambda i: (0, 0))],
             out_specs=pl.BlockSpec((block, d), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((n, d), x2d.dtype),
+            name="rms_norm",
         )(x2d, w.reshape(1, d))
 
 

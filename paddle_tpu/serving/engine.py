@@ -51,6 +51,7 @@ next step), so ``sync_interval`` only pays off on greedy traffic.
 """
 from __future__ import annotations
 
+import contextlib
 import time
 
 import jax
@@ -95,9 +96,10 @@ _M_HOST_SYNCS = _obs.counter(
     ("kind",))
 _M_PHASE_SECONDS = _obs.counter(
     "serving_step_phase_seconds_total",
-    "engine wall seconds by phase: 'prefill' jit calls (incl. CoW "
-    "copies), 'decode' step dispatch, 'host_sync' blocking ring "
-    "fetches — the resource tracker's tokens/s and MFU denominator",
+    "engine wall seconds by phase: 'schedule' passes, 'prefill' jit "
+    "calls (incl. CoW copies), 'decode' step dispatch, 'host_sync' "
+    "blocking ring fetches, 'emit' row walks and callbacks — the "
+    "resource tracker's tokens/s and MFU denominator",
     ("phase",))
 _M_CHUNKS = _obs.counter(
     "serving_prefill_chunks_total",
@@ -354,9 +356,12 @@ class Engine:
         self.quarantines = 0        # requests failed in place
         self.replayed_requests = 0  # in-flight requests re-prefilled
         # per-phase wall seconds (mirror of serving_step_phase_seconds_
-        # total; resource_snapshot() reports them per engine)
-        self.timings = {"prefill_s": 0.0, "decode_s": 0.0,
-                        "host_sync_s": 0.0}
+        # total; resource_snapshot() reports them per engine), each the
+        # summed intervals of one span name (see _phase); queue_wait_s
+        # is the requests' summed wait for admission, not engine time
+        self.timings = {"schedule_s": 0.0, "prefill_s": 0.0,
+                        "decode_s": 0.0, "host_sync_s": 0.0,
+                        "emit_s": 0.0, "queue_wait_s": 0.0}
         # monotonically increasing iteration counter.  The serving
         # watchdog reads it lock-free (comparing against active_count)
         # to detect a wedged decode loop — never reset.
@@ -369,10 +374,7 @@ class Engine:
         # ``progress`` reads; costs nothing when no profiler runs.
         self.current_phase = "idle"
         self.slo = slo              # optional slo.SLOTracker
-        # open "engine.decode_segment" span covering the device steps
-        # since the last host sync (None between segments)
-        self._seg_span = None
-        self._seg_steps = 0
+        self._emitted = 0           # tokens handed to requests, ever
         self._rngs: dict[int, np.random.Generator] = {}
         self._ttft, self._tpot, self._e2e = _serving_hists()
         self._pages_hist = _obs.histogram(
@@ -545,29 +547,39 @@ class Engine:
         """One engine iteration: evict/admit (scheduler pass), prefill
         admissions, then one lockstep decode step over the active slots.
         Returns whether any work happened."""
-        now = self._clock()
-        admitted = self.scheduler.schedule(now)
-        # chunk states registered by THIS step's admissions already ran
-        # their first chunk inside _prefill — snapshot the in-flight set
-        # first so each prefill advances exactly one chunk per step
-        inflight = list(self._chunking)
-        for slot, req in admitted:
-            self._prefill(slot, req)
-        advanced = 0
-        for slot in inflight:
-            if slot in self._chunking:      # evicted states drop out
-                self._advance_chunk(slot)
-                advanced += 1
-        active = [i for i, r in enumerate(self.scheduler.slots)
-                  if r is not None and r.state == RequestState.DECODE]
-        if active:
-            self._decode(active)
-        else:
-            # gap witness: nothing was decoding, so this step's prefill
-            # work starved no resident — the stall meter restarts
-            self._prefill_since_decode = 0
-        self.current_phase = "idle"
-        self.progress += 1          # watchdog heartbeat
+        with _obs.tracer().phase("engine.step", parent=None,
+                                 step=self.progress) as st:
+            now = self._clock()
+            evicted = self.scheduler.evictions
+            with self._phase("engine.schedule", "schedule") as ph:
+                admitted = self.scheduler.schedule(now)
+                ph.set_attribute("admitted", len(admitted))
+                ph.set_attribute("evicted",
+                                 self.scheduler.evictions - evicted)
+            # chunk states registered by THIS step's admissions already
+            # ran their first chunk inside _prefill — snapshot the
+            # in-flight set first so each prefill advances exactly one
+            # chunk per step
+            inflight = list(self._chunking)
+            for slot, req in admitted:
+                self._prefill(slot, req)
+            advanced = 0
+            for slot in inflight:
+                if slot in self._chunking:  # evicted states drop out
+                    self._advance_chunk(slot)
+                    advanced += 1
+            active = [i for i, r in enumerate(self.scheduler.slots)
+                      if r is not None and r.state == RequestState.DECODE]
+            st.set_attribute("active", len(active))
+            if active:
+                self._decode(active)
+            else:
+                # gap witness: nothing was decoding, so this step's
+                # prefill work starved no resident — the stall meter
+                # restarts
+                self._prefill_since_decode = 0
+            self.current_phase = "idle"
+            self.progress += 1      # watchdog heartbeat
         return bool(admitted) or bool(active) or bool(advanced)
 
     def run_until_complete(self, max_steps: int | None = None):
@@ -598,8 +610,9 @@ class Engine:
         if req.admitted_at is not None:
             # ledger: queue-wait seconds — every wait (first admission
             # and each preemption re-queue) sums into the same field
-            req.queue_seconds += max(
-                0.0, req.admitted_at - req._queued_since)
+            waited = max(0.0, req.admitted_at - req._queued_since)
+            req.queue_seconds += waited
+            self.timings["queue_wait_s"] += waited
             req._queued_since = req.admitted_at
             if req.timeline is not None:
                 # a re-queue wait after preemption charges to the
@@ -616,98 +629,93 @@ class Engine:
             self._resume(slot, req)
             return
         self.current_phase = "prefill"
-        t0 = time.perf_counter()
-        ps = self.page_size
-        plen = req.prompt.size
-        meta = self.blocks.seq_meta(req.id)
-        cached = int(meta["cached_len"])
-        row = self.blocks.table_row(req.id, self.table_width)
-        if self.prefill_chunk and plen - cached > self.prefill_chunk:
+        failed = None
+        with self._phase("engine.prefill", "prefill", parent=req.root_span,
+                         req=req.id, slot=slot) as ph:
+            ps = self.page_size
+            plen = req.prompt.size
+            meta = self.blocks.seq_meta(req.id)
+            cached = int(meta["cached_len"])
+            row = self.blocks.table_row(req.id, self.table_width)
             # chunked admission: CoW once up front, then one chunk per
             # engine step so decoding slots keep stepping in between
+            chunked = bool(self.prefill_chunk
+                           and plen - cached > self.prefill_chunk)
+            ph.set_attribute("cached_tokens", cached)
+            ph.set_attribute("cow", meta["cow_src"] is not None)
+            ph.set_attribute("kind", "chunked_admit" if chunked else
+                             "cached_suffix" if cached else "full")
             try:
                 if meta["cow_src"] is not None:
+                    # copy-on-write: duplicate the matching tail page
+                    # into this request's own tail before any writes
+                    # land there
                     self.runner.copy_page(int(meta["cow_src"]),
                                           int(row[cached // ps]))
+                if not chunked:
+                    logits, bucket = self._dispatch_prefill(
+                        req.prompt[cached:], cached, row, req._adapter_row)
+                    ph.set_attribute("bucket", bucket)
+                req.num_cached_tokens = cached
+                req.prefill_cached_tokens += cached
+                req.prefill_computed_tokens += plen - cached
+                if not chunked:
+                    self._note_gap(plen - cached)
+                    tok = self._fetch_first_token(slot, req, logits)
+                    now = self._clock()
+                    self._ttft.observe(now - req.arrival_time)
             except Exception as e:
-                self._note_phase("prefill", time.perf_counter() - t0)
-                self._quarantine(slot, req, e, self._clock())
-                return
-            req.num_cached_tokens = cached
-            req.prefill_cached_tokens += cached
-            req.prefill_computed_tokens += plen - cached
-            self._note_phase("prefill", time.perf_counter() - t0)
+                failed = e
+        if failed is not None:
+            # a failed prefill kills ONE request, never the process:
+            # pages release, the slot parks, the batch keeps running
+            self._quarantine(slot, req, failed, self._clock())
+            return
+        if chunked:
             self._begin_chunks(slot, req, req.prompt, cached, row)
             return
-        try:
-            if meta["cow_src"] is not None:
-                # copy-on-write: duplicate the matching tail page into
-                # this request's own tail before any writes land there
-                self.runner.copy_page(int(meta["cow_src"]),
-                                      int(row[cached // ps]))
+        if req.timeline is not None:
+            req.timeline.note_prefill(now, cached=cached,
+                                      computed=plen - cached, slot=slot)
+        _obs.flight("engine", "prefill", req=req.id, slot=slot,
+                    bucket=bucket, cached=cached)
+        self._enter_decode(slot, req, row, plen, tok, now)
+        if self._proposer is not None:
+            # seed the drafter with the prompt; emitted tokens extend
+            # the history through _emit
+            self._proposer.register(req.id, req.prompt)
+        self._emit(slot, req, tok, now)
+
+    def _dispatch_prefill(self, tokens, cached: int, row,
+                          adapter_row: int):
+        """Hand ``tokens`` (what follows the ``cached`` resident tokens
+        of a sequence), padded to their page-multiple bucket, to the
+        runner's prefill program.  Returns (logits handle, bucket)."""
+        n = len(tokens)
+        bucket = -(-n // self.page_size) * self.page_size
+        ids = np.zeros((1, bucket), np.int32)
+        ids[0, :n] = tokens
+        with _obs.tracer().phase("engine.prefill.dispatch"):
             if cached == 0:
-                bucket = -(-plen // ps) * ps
-                ids = np.zeros((1, bucket), np.int32)
-                ids[0, :plen] = req.prompt
-                logits = self.runner.prefill(
-                    ids, plen, row, adapter_row=req._adapter_row)
+                logits = self.runner.prefill(ids, n, row,
+                                             adapter_row=adapter_row)
             else:
-                suffix = plen - cached
-                bucket = -(-suffix // ps) * ps
-                ids = np.zeros((1, bucket), np.int32)
-                ids[0, :suffix] = req.prompt[cached:]
                 logits = self.runner.prefill_cached(
-                    ids, suffix, cached, row,
-                    adapter_row=req._adapter_row)
-            req.num_cached_tokens = cached
-            req.prefill_cached_tokens += cached
-            req.prefill_computed_tokens += plen - cached
-            self._note_gap(plen - cached)
-            _M_HOST_SYNCS.labels("prefill").inc()
+                    ids, n, cached, row, adapter_row=adapter_row)
+        return logits, bucket
+
+    def _fetch_first_token(self, slot: int, req: Request, logits) -> int:
+        """The blocking half of an admission's prefill: fetch the
+        last-position logits and pick the request's first token."""
+        _M_HOST_SYNCS.labels("prefill").inc()
+        with _obs.tracer().phase("engine.prefill.fetch"):
             logits_row = np.asarray(logits)[0]
             if (self.faults is not None
                     and self.faults.check("nan_logits", req=req.id,
                                           slot=slot,
                                           phase="prefill") is not None):
                 logits_row = np.full_like(logits_row, np.nan)
-            tok = self._pick_token(req, logits_row)
-        except Exception as e:
-            # a failed prefill kills ONE request, never the process:
-            # pages release, the slot parks, the batch keeps running
-            self._note_phase("prefill", time.perf_counter() - t0)
-            self._quarantine(slot, req, e, self._clock())
-            return
-        now = self._clock()
-        self._ttft.observe(now - req.arrival_time)
-        self._note_phase("prefill", time.perf_counter() - t0)
-        if req.timeline is not None:
-            req.timeline.note_prefill(now, cached=cached,
-                                      computed=plen - cached, slot=slot)
-        _obs.tracer().record_span(
-            "engine.prefill", t0, time.perf_counter(),
-            parent=req.root_span,
-            attributes={"req": req.id, "slot": slot, "bucket": bucket,
-                        "cached_tokens": cached,
-                        "kind": "cached_suffix" if cached else "full",
-                        "cow": meta["cow_src"] is not None})
-        if req.root_span is not None:
-            req.decode_span = _obs.tracer().start_span(
-                "engine.decode", parent=req.root_span,
-                attributes={"req": req.id, "slot": slot})
-        _obs.flight("engine", "prefill", req=req.id, slot=slot,
-                    bucket=bucket, cached=cached)
-        self.table[slot] = row
-        self._pos[slot] = plen
-        self._tok[slot] = tok
-        self._active[slot] = 1
-        self._aidx[slot] = req._adapter_row
-        self._push_slot(slot)
-        req.state = RequestState.DECODE
-        if self._proposer is not None:
-            # seed the drafter with the prompt; emitted tokens extend
-            # the history through _emit
-            self._proposer.register(req.id, req.prompt)
-        self._emit(slot, req, tok, now)
+            return self._pick_token(req, logits_row)
 
     # --------------------------------------------------- chunked prefill
     def _note_gap(self, tokens: int):
@@ -730,7 +738,7 @@ class Engine:
         self._chunking[slot] = {
             "req": req, "ids": np.asarray(ids_all, np.int32).reshape(-1),
             "done": int(done), "row": row, "resume_tok": resume_tok,
-            "chunks": 0, "t0": time.perf_counter()}
+            "chunks": 0}
         self._advance_chunk(slot)
 
     def _advance_chunk(self, slot: int):
@@ -753,75 +761,59 @@ class Engine:
         done = st["done"]
         this = min(self.prefill_chunk, n - done)
         last = done + this >= n
-        ps = self.page_size
         self.current_phase = "prefill_chunk"
-        t0 = time.perf_counter()
-        try:
-            bucket = -(-this // ps) * ps
-            ids = np.zeros((1, bucket), np.int32)
-            ids[0, :this] = ids_all[done:done + this]
-            if done == 0:
-                logits = self.runner.prefill(
-                    ids, this, st["row"],
-                    adapter_row=getattr(req, "_adapter_row", 0))
-            else:
-                logits = self.runner.prefill_cached(
-                    ids, this, done, st["row"],
-                    adapter_row=getattr(req, "_adapter_row", 0))
-            st["chunks"] += 1
-            self.prefill_chunks += 1
-            req.prefill_chunks += 1
-            _M_CHUNKS.inc()
-            self._note_gap(this)
-            if not last:
-                st["done"] = done + this
-                self._note_phase("prefill", time.perf_counter() - t0)
-                if req.timeline is not None:
-                    req.timeline.note(
-                        "prefill_compute", self._clock(), event="chunk",
-                        slot=slot, done=done + this, total=n,
-                        then="chunk_gap")
-                _obs.flight("engine", "prefill_chunk", req=req.id,
-                            slot=slot, done=done + this, total=n)
-                return
-            if st["resume_tok"] is None:
-                # admission: the first output token samples from the
-                # final chunk's last-position logits
-                _M_HOST_SYNCS.labels("prefill").inc()
-                logits_row = np.asarray(logits)[0]
-                if (self.faults is not None
-                        and self.faults.check(
-                            "nan_logits", req=req.id, slot=slot,
-                            phase="prefill") is not None):
-                    logits_row = np.full_like(logits_row, np.nan)
-                tok = self._pick_token(req, logits_row)
-            else:
-                # resume: the last emitted token re-enters as the next
-                # decode input; the replay logits are discarded
-                tok = int(st["resume_tok"])
-        except Exception as e:
-            self._note_phase("prefill", time.perf_counter() - t0)
+        failed = None
+        with self._phase("engine.prefill", "prefill", parent=req.root_span,
+                         req=req.id, slot=slot, kind="chunk") as ph:
+            try:
+                logits, _ = self._dispatch_prefill(
+                    ids_all[done:done + this], done, st["row"],
+                    getattr(req, "_adapter_row", 0))
+                st["chunks"] += 1
+                self.prefill_chunks += 1
+                req.prefill_chunks += 1
+                _M_CHUNKS.inc()
+                self._note_gap(this)
+                if not last:
+                    st["done"] = done + this
+                elif st["resume_tok"] is None:
+                    # admission: the first output token samples from the
+                    # final chunk's last-position logits
+                    tok = self._fetch_first_token(slot, req, logits)
+                else:
+                    # resume: the last emitted token re-enters as the
+                    # next decode input; the replay logits are discarded
+                    tok = int(st["resume_tok"])
+            except Exception as e:
+                failed = e
+            if failed is None and last:
+                self._chunking.pop(slot, None)
+                # the full chunked prefix is device-resident now —
+                # register it in the prefix-cache chain (deferred at
+                # allocate_seq)
+                self.blocks.publish_seq(req.id, ids_all)
+                now = self._clock()
+                ph.set_attribute("kind", "chunked")
+                ph.set_attribute("chunks", st["chunks"])
+                ph.set_attribute("cached_tokens", req.num_cached_tokens)
+                ph.set_attribute("resume", st["resume_tok"] is not None)
+        if failed is not None:
             self._chunking.pop(slot, None)
-            self._quarantine(slot, req, e, self._clock())
+            self._quarantine(slot, req, failed, self._clock())
             return
-        self._chunking.pop(slot, None)
-        # the full chunked prefix is device-resident now — register it
-        # in the prefix-cache chain (deferred at allocate_seq)
-        self.blocks.publish_seq(req.id, ids_all)
-        now = self._clock()
-        self._note_phase("prefill", time.perf_counter() - t0)
+        if not last:
+            if req.timeline is not None:
+                req.timeline.note(
+                    "prefill_compute", self._clock(), event="chunk",
+                    slot=slot, done=done + this, total=n,
+                    then="chunk_gap")
+            _obs.flight("engine", "prefill_chunk", req=req.id,
+                        slot=slot, done=done + this, total=n)
+            return
         if req.timeline is not None:
             req.timeline.note("prefill_compute", now, event="chunk",
                               slot=slot, done=n, total=n,
                               chunks=st["chunks"], then="decode")
-        _obs.tracer().record_span(
-            "engine.prefill", st["t0"], time.perf_counter(),
-            parent=req.root_span,
-            attributes={"req": req.id, "slot": slot,
-                        "chunks": st["chunks"],
-                        "cached_tokens": req.num_cached_tokens,
-                        "kind": "chunked",
-                        "resume": st["resume_tok"] is not None})
         _obs.flight("engine", "prefill", req=req.id, slot=slot,
                     chunks=st["chunks"], cached=req.num_cached_tokens)
         self._enter_decode(slot, req, st["row"], n, tok, now)
@@ -861,7 +853,13 @@ class Engine:
         req = self.scheduler.slots[slot]
         if req is None or req.state != RequestState.DECODE:
             return False
-        t0 = time.perf_counter()
+        with _obs.tracer().phase("engine.preempt_spill",
+                                 parent=req.root_span, req=req.id,
+                                 slot=slot) as ph:
+            return self._spill(slot, req, ph)
+
+    def _spill(self, slot: int, req: Request, ph) -> bool:
+        """The body of :meth:`_preempt`, inside its span ``ph``."""
         if req.timeline is not None:
             # decoding ends here; the spill loop below (and, if the
             # preemption lands, the re-queue wait and the restore)
@@ -884,6 +882,7 @@ class Engine:
                 _obs.flight("engine", "spill_abort", req=req.id,
                             slot=slot, page=page,
                             parked_dropped=len(parked))
+                ph.set_attribute("aborted", True)
                 return False
             arrays = self.runner.read_page(page)
             self.blocks.host_put(digest, *arrays)
@@ -896,6 +895,7 @@ class Engine:
             if self.usage is not None:
                 self.usage.on_host_park(req, digest)
             parked.append(digest)
+        ph.set_attribute("pages", len(parked))
         self.blocks.release_preempted(req.id, tokens)
         self._park(slot)
         self.preemptions += 1
@@ -919,11 +919,6 @@ class Engine:
             req.queue_span = _obs.tracer().start_span(
                 "scheduler.queue_wait", parent=req.root_span,
                 attributes={"resume": True})
-        _obs.tracer().record_span(
-            "engine.preempt_spill", t0, time.perf_counter(),
-            parent=req.root_span,
-            attributes={"req": req.id, "slot": slot,
-                        "pages": len(parked)})
         _obs.flight("engine", "preempt_spill", req=req.id, slot=slot,
                     pages=len(parked))
         return True
@@ -937,57 +932,82 @@ class Engine:
         token as the next input, token-for-token identical to an
         uninterrupted greedy run (parity asserted in tests)."""
         self.current_phase = "prefill"
-        t0 = time.perf_counter()
-        ps = self.page_size
-        if self.usage is not None:
-            # this request is no longer waiting on its parked pages —
-            # per-request host-tier accrual stops here (the tenant keeps
-            # paying until the digests fall out of the host LRU)
-            self.usage.on_host_release(req)
-        tokens = req.resume_tokens()
-        ids_all = tokens[:-1]
-        n = int(ids_all.size)
-        meta = self.blocks.seq_meta(req.id)
-        # ledger: the uncapped match length is what allocate_seq added
-        # to the global cached_tokens counter for this resume
-        req.prefill_cached_tokens += int(meta["cached_len"])
-        cached = min(int(meta["cached_len"]), n)
-        row = self.blocks.table_row(req.id, self.table_width)
-        restored = 0
-        try:
-            if meta["cow_src"] is not None:
-                # tail CoW page from the admission match: duplicate it
-                # before any writes land (same rule as fresh admission)
-                self.runner.copy_page(int(meta["cow_src"]),
-                                      int(row[cached // ps]))
-            else:
-                # host-tier unpark: extend coverage page by page past
-                # the cache match while parked complete chunks exist
-                while cached % ps == 0 and cached + ps <= n:
-                    c = cached // ps
-                    entry = self.blocks.host_get(
-                        self.blocks.spill_digest(tokens, c))
-                    if entry is None:
-                        break
-                    self.runner.write_page(int(row[c]), *entry)
-                    self.blocks.note_restored()
-                    req.restored_pages += 1
-                    req.restore_bytes += sum(a.nbytes for a in entry)
-                    restored += 1
-                    cached += ps
-        except Exception as e:
-            self._note_phase("prefill", time.perf_counter() - t0)
-            self._quarantine(slot, req, e, self._clock())
+        failed = None
+        with self._phase("engine.resume", "prefill", parent=req.root_span,
+                         req=req.id, slot=slot) as ph:
+            ps = self.page_size
+            if self.usage is not None:
+                # this request is no longer waiting on its parked pages
+                # — per-request host-tier accrual stops here (the tenant
+                # keeps paying until the digests fall out of the host
+                # LRU)
+                self.usage.on_host_release(req)
+            tokens = req.resume_tokens()
+            ids_all = tokens[:-1]
+            n = int(ids_all.size)
+            meta = self.blocks.seq_meta(req.id)
+            # ledger: the uncapped match length is what allocate_seq
+            # added to the global cached_tokens counter for this resume
+            req.prefill_cached_tokens += int(meta["cached_len"])
+            cached = min(int(meta["cached_len"]), n)
+            row = self.blocks.table_row(req.id, self.table_width)
+            restored = 0
+            chunked = False
+            tok = int(tokens[-1])
+            try:
+                if meta["cow_src"] is not None:
+                    # tail CoW page from the admission match: duplicate
+                    # it before any writes land (same rule as fresh
+                    # admission)
+                    self.runner.copy_page(int(meta["cow_src"]),
+                                          int(row[cached // ps]))
+                else:
+                    # host-tier unpark: extend coverage page by page
+                    # past the cache match while parked complete chunks
+                    # exist
+                    while cached % ps == 0 and cached + ps <= n:
+                        c = cached // ps
+                        entry = self.blocks.host_get(
+                            self.blocks.spill_digest(tokens, c))
+                        if entry is None:
+                            break
+                        self.runner.write_page(int(row[c]), *entry)
+                        self.blocks.note_restored()
+                        req.restored_pages += 1
+                        req.restore_bytes += sum(a.nbytes for a in entry)
+                        restored += 1
+                        cached += ps
+                suffix = n - cached
+                # ledger: the re-prefilled remainder runs on device
+                # (chunked or single-shot alike)
+                req.prefill_computed_tokens += suffix
+                # a long replay suffix chunks exactly like a long prompt
+                # — resumes must not reintroduce the TPOT stall either
+                chunked = bool(self.prefill_chunk
+                               and suffix > self.prefill_chunk)
+                if suffix > 0 and not chunked:
+                    self._dispatch_prefill(ids_all[cached:], cached, row,
+                                           req._adapter_row)
+                    self._note_gap(suffix)
+                # the resume logits are discarded (the last token is
+                # already known) — no host sync happens here
+            except Exception as e:
+                failed = e
+            if failed is None and not chunked:
+                # allocate_seq defers on plen while the chunk test above
+                # uses the replay suffix, so a resume can be deferred
+                # yet single-shot — publish here too (no-op when
+                # registration wasn't deferred)
+                self.blocks.publish_seq(req.id, ids_all)
+                now = self._clock()
+            ph.set_attribute("tokens", n)
+            ph.set_attribute("cached_tokens", cached)
+            ph.set_attribute("restored_pages", restored)
+            ph.set_attribute("chunked", chunked)
+        if failed is not None:
+            self._quarantine(slot, req, failed, self._clock())
             return
-        suffix = n - cached
-        tok = int(tokens[-1])
-        # ledger: the re-prefilled remainder runs on device (chunked or
-        # single-shot alike)
-        req.prefill_computed_tokens += suffix
-        if self.prefill_chunk and suffix > self.prefill_chunk:
-            # a long replay suffix chunks exactly like a long prompt —
-            # resumes must not reintroduce the TPOT stall either
-            self._note_phase("prefill", time.perf_counter() - t0)
+        if chunked:
             if req.timeline is not None:
                 # restore work so far charges to preempted; the chunked
                 # re-prefill accounts like any chunked admission
@@ -1001,31 +1021,6 @@ class Engine:
             self._begin_chunks(slot, req, ids_all, cached, row,
                                resume_tok=tok)
             return
-        try:
-            if suffix > 0:
-                bucket = -(-suffix // ps) * ps
-                ids = np.zeros((1, bucket), np.int32)
-                ids[0, :suffix] = ids_all[cached:]
-                if cached == 0:
-                    self.runner.prefill(ids, suffix, row,
-                                        adapter_row=req._adapter_row)
-                else:
-                    self.runner.prefill_cached(
-                        ids, suffix, cached, row,
-                        adapter_row=req._adapter_row)
-                self._note_gap(suffix)
-            # the resume logits are discarded (the last token is
-            # already known) — no host sync happens here
-        except Exception as e:
-            self._note_phase("prefill", time.perf_counter() - t0)
-            self._quarantine(slot, req, e, self._clock())
-            return
-        # allocate_seq defers on plen while the chunk test above uses
-        # the replay suffix, so a resume can be deferred yet single-shot
-        # — publish here too (no-op when registration wasn't deferred)
-        self.blocks.publish_seq(req.id, ids_all)
-        now = self._clock()
-        self._note_phase("prefill", time.perf_counter() - t0)
         if req.timeline is not None:
             req.timeline.note("preempted", now, event="resume",
                               slot=slot, restored=restored,
@@ -1033,12 +1028,6 @@ class Engine:
         self._enter_decode(slot, req, row, n, tok, now)
         if self._proposer is not None:
             self._proposer.register(req.id, tokens)
-        _obs.tracer().record_span(
-            "engine.resume", t0, time.perf_counter(),
-            parent=req.root_span,
-            attributes={"req": req.id, "slot": slot, "tokens": n,
-                        "cached_tokens": cached,
-                        "restored_pages": restored})
         _obs.flight("engine", "resume", req=req.id, slot=slot,
                     tokens=n, cached=cached, restored=restored)
 
@@ -1056,22 +1045,14 @@ class Engine:
                 raise InjectedFault(
                     f"injected poisoned decode step "
                     f"(step {self.decode_steps})")
-        if self._seg_span is None:
-            # one span per host-sync interval, NOT per device step —
-            # segments are the engine's visible unit of decode work
-            self._seg_span = _obs.tracer().start_span(
-                "engine.decode_segment", parent=None,
-                attributes={"slots": len(active)})
-            self._seg_steps = 0
-        self._seg_steps += 1
         reqs = [(s, self.scheduler.slots[s]) for s in active]
         drafts = self._propose(reqs)
         if drafts:
             self._decode_spec(reqs, drafts)
             return
-        step_t0 = time.perf_counter()
-        logits = self.runner.decode_step()
-        self._note_phase("decode", time.perf_counter() - step_t0)
+        with self._phase("engine.decode.dispatch", "decode",
+                         slots=len(active)):
+            logits = self.runner.decode_step()
         self.decode_steps += 1
         self._prefill_since_decode = 0      # gap witness: decode ran
         _M_STEPS.inc()
@@ -1123,9 +1104,9 @@ class Engine:
         for slot, ds in drafts.items():
             draft_arr[slot, :len(ds)] = ds
             dlen[slot] = len(ds)
-        step_t0 = time.perf_counter()
-        self.runner.verify_step(draft_arr, dlen)
-        self._note_phase("decode", time.perf_counter() - step_t0)
+        with self._phase("engine.decode.dispatch", "decode",
+                         slots=len(reqs), verify=True):
+            self.runner.verify_step(draft_arr, dlen)
         self.decode_steps += 1
         self._prefill_since_decode = 0      # gap witness: decode ran
         _M_STEPS.inc()
@@ -1146,11 +1127,18 @@ class Engine:
         """Drain the device token ring: ONE [sync_interval, slots] int32
         transfer covers every decode step since the previous sync."""
         self.current_phase = "host_sync"
-        sync_t0 = time.perf_counter()
-        ring = self.runner.fetch_ring()
-        sync_s = time.perf_counter() - sync_t0
+        with self._phase("engine.host_sync", "host_sync") as ph:
+            ring = self.runner.fetch_ring()
+        with self._phase("engine.emit", "emit",
+                         rows=len(self._pending)) as em:
+            self._drain(ring, ph.seconds, em)
+
+    def _drain(self, ring, sync_s: float, em):
+        """What the host does with a fetched ring, inside the span
+        ``em``: re-derive acceptance, walk the rows, hand every token to
+        its request (``on_token`` callbacks and finishes included)."""
+        emitted = self._emitted
         self.host_syncs += 1
-        self._note_phase("host_sync", sync_s)
         _M_HOST_SYNCS.labels("ring").inc()
         poll = int(FLAGS.get("FLAGS_resource_memory_poll_steps") or 0)
         if poll > 0 and self.host_syncs % poll == 0:
@@ -1173,23 +1161,18 @@ class Engine:
                         break
                     a += 1
                 accepted[slot] = (len(drafts.get(slot, ())), a)
-        if self._seg_span is not None:
-            # the ring fetch above blocked on the device — the segment
-            # span ends here, covering dispatch through host sync
-            self._seg_span.set_attribute("steps", self._seg_steps)
-            if accepted:
-                self._seg_span.set_attribute(
-                    "spec_proposed", sum(p for p, _ in accepted.values()))
-                self._seg_span.set_attribute(
-                    "spec_accepted", sum(a for _, a in accepted.values()))
-            self._seg_span.end()
-            self._seg_span = None
-        _obs.flight("engine", "host_sync", rows=len(self._pending),
-                    steps=self._seg_steps, sync_s=round(sync_s, 6))
-        sample_t0 = None
+        n_rows = len(self._pending)
+        # one decode step per pending row since the last sync
+        em.set_attribute("steps", n_rows)
+        if accepted:
+            em.set_attribute("spec_proposed",
+                             sum(p for p, _ in accepted.values()))
+            em.set_attribute("spec_accepted",
+                             sum(a for _, a in accepted.values()))
+        _obs.flight("engine", "host_sync", rows=n_rows, steps=n_rows,
+                    sync_s=round(sync_s, 6))
         logits_np = None
         now = self._clock()
-        n_rows = len(self._pending)
         if self.requestlog is not None:
             # one timeline charge per live request per sync: decode
             # dispatch up to the blocking ring fetch, then the sync
@@ -1205,55 +1188,62 @@ class Engine:
                     seen.add(_req.id)
                     _req.timeline.note_sync(now, sync_s)
         corrections = []
-        for row_i, (ridx, entries, drafts) in enumerate(self._pending):
-            for slot, req in entries:
-                if req.is_finished() or req.state != RequestState.DECODE:
-                    continue        # evicted/finished: overrun discarded
-                if drafts is not None:
-                    self._accept(slot, req, ring[ridx, slot],
-                                 *accepted[slot], now)
-                    continue
-                tok = raw = int(ring[ridx, slot, 0]) if wide \
-                    else int(ring[ridx, slot])
-                if req.gen.do_sample:
-                    # sampling rows only exist under eff-interval 1, so
-                    # the step's logits handle is always the right row
-                    if logits_np is None:
-                        sample_t0 = time.perf_counter()
-                        logits_np = np.asarray(self._last_logits)
-                        self.logit_fetches += 1
-                        _M_HOST_SYNCS.labels("logits").inc()
-                    row_logits = logits_np[slot]
-                    if (self.faults is not None
-                            and self.faults.check(
-                                "nan_logits", req=req.id, slot=slot,
-                                phase="decode") is not None):
-                        row_logits = np.full_like(row_logits, np.nan)
-                    try:
-                        tok = self._pick_token(req, row_logits)
-                    except NonFiniteLogitsError as e:
-                        # fail ONLY the offending request — the other
-                        # slots in this sync keep their tokens
-                        self._quarantine(slot, req, e, now)
+        # host-side sampling for this sync, open from the logits fetch
+        # to the end of the walk: the fetch + each per-request pick
+        # (argmax/top-k/top-p)
+        with contextlib.ExitStack() as sampling:
+            sample = None
+            for row_i, (ridx, entries, drafts) in enumerate(self._pending):
+                for slot, req in entries:
+                    if (req.is_finished()
+                            or req.state != RequestState.DECODE):
+                        continue    # evicted/finished: overrun discarded
+                    if drafts is not None:
+                        self._accept(slot, req, ring[ridx, slot],
+                                     *accepted[slot], now)
                         continue
-                    if tok != raw:
-                        corrections.append((slot, tok))
-                prev = req.last_token_at
-                if prev is not None:
-                    # batched sync: spread the interval over the tokens
-                    # it covers so TPOT keeps per-token semantics
-                    self._tpot.observe((now - prev) / (n_rows - row_i))
-                self._tok[slot] = tok
-                self._emit(slot, req, tok, now)
-        self._pending.clear()
-        if sample_t0 is not None:
-            # host-side sampling for this sync: logits fetch + per-
-            # request pick (argmax/top-k/top-p) + any device feedback
-            _obs.tracer().record_span(
-                "engine.sample", sample_t0, time.perf_counter(),
-                attributes={"corrections": len(corrections)})
+                    tok = raw = int(ring[ridx, slot, 0]) if wide \
+                        else int(ring[ridx, slot])
+                    if req.gen.do_sample:
+                        # sampling rows only exist under eff-interval 1,
+                        # so the step's logits handle is always the
+                        # right row
+                        if logits_np is None:
+                            sample = sampling.enter_context(
+                                _obs.tracer().phase("engine.sample"))
+                            logits_np = np.asarray(self._last_logits)
+                            self.logit_fetches += 1
+                            _M_HOST_SYNCS.labels("logits").inc()
+                        row_logits = logits_np[slot]
+                        if (self.faults is not None
+                                and self.faults.check(
+                                    "nan_logits", req=req.id, slot=slot,
+                                    phase="decode") is not None):
+                            row_logits = np.full_like(row_logits, np.nan)
+                        try:
+                            tok = self._pick_token(req, row_logits)
+                        except NonFiniteLogitsError as e:
+                            # fail ONLY the offending request — the
+                            # other slots in this sync keep their tokens
+                            self._quarantine(slot, req, e, now)
+                            continue
+                        if tok != raw:
+                            corrections.append((slot, tok))
+                    prev = req.last_token_at
+                    if prev is not None:
+                        # batched sync: spread the interval over the
+                        # tokens it covers so TPOT keeps per-token
+                        # semantics
+                        self._tpot.observe(
+                            (now - prev) / (n_rows - row_i))
+                    self._tok[slot] = tok
+                    self._emit(slot, req, tok, now)
+            self._pending.clear()
+            if sample is not None:
+                sample.set_attribute("corrections", len(corrections))
         if corrections:
             self.runner.correct_tokens(corrections)
+        em.set_attribute("tokens", self._emitted - emitted)
 
     def _accept(self, slot: int, req: Request, row, proposed: int,
                 a: int, now: float):
@@ -1284,6 +1274,18 @@ class Engine:
             if req.is_finished():
                 break
 
+    @contextlib.contextmanager
+    def _phase(self, name: str, charge: str, **kw):
+        """``Tracer.phase(name, **kw)`` whose interval is also charged
+        to ``timings[charge + "_s"]``: the ring, the profiler's trace
+        and the counters read one and the same interval."""
+        ph = _obs.tracer().phase(name, **kw)
+        try:
+            with ph:
+                yield ph
+        finally:
+            self._note_phase(charge, ph.seconds)
+
     def _note_phase(self, phase: str, seconds: float):
         """Charge engine wall time to a phase: the per-engine mirror,
         the serving_step_phase_seconds_total counter, and the process
@@ -1298,6 +1300,7 @@ class Engine:
         if req.timeline is not None and req.first_token_at is None:
             req.timeline.mark("first_token", now)   # the TTFT moment
         req._emit(tok, now)
+        self._emitted += 1
         _M_TOKENS.inc()
         resource_tracker().note_tokens(1)
         if charge:
@@ -1453,13 +1456,15 @@ class Engine:
         Requests that cannot be replayed are quarantined.  Typically
         called by the :class:`~.supervisor.EngineSupervisor`, not
         user code."""
+        with _obs.tracer().phase("engine.recover", parent=None) as ph:
+            out = self._rebuild()
+            for key, value in out.items():
+                ph.set_attribute(key, value)
+        return out
+
+    def _rebuild(self) -> dict:
+        """The body of :meth:`recover`, inside its span."""
         now = self._clock()
-        t0 = time.perf_counter()
-        if self._seg_span is not None:
-            self._seg_span.set_attribute("aborted", True)
-            self._seg_span.end()
-            self._seg_span = None
-        self._seg_steps = 0
         # drop un-synced device state: the ring rows and logits handle
         # belong to the dead runner (the pos mirrors they would have
         # advanced are recomputed from request state below)
@@ -1491,10 +1496,6 @@ class Engine:
         self.recoveries += 1
         _obs.flight("engine", "recover", replayed=replayed,
                     flushed_cached_pages=flushed)
-        _obs.tracer().record_span(
-            "engine.recover", t0, time.perf_counter(),
-            attributes={"replayed": replayed,
-                        "flushed_cached_pages": flushed})
         return {"replayed": replayed, "flushed_cached_pages": flushed}
 
     def _replay(self, slot: int, req: Request):
@@ -1505,57 +1506,43 @@ class Engine:
         next step's input — decode then continues token-for-token as if
         the fault never happened (greedy parity is asserted in tests)."""
         self.current_phase = "prefill"
-        t0 = time.perf_counter()
-        tokens = [int(t) for t in req.prompt] + list(req.output_tokens)
-        ids_all = tokens[:-1]
-        n = len(ids_all)
-        plan = self.blocks.replay_plan(req.id, ids_all)
-        cached = int(plan["cached_len"])
-        # ledger: recovery replays re-run committed tokens; the cache
-        # match mirrors replay_plan's global cached_tokens bump
-        req.replays += 1
-        req.prefill_cached_tokens += cached
-        req.prefill_computed_tokens += n - cached
-        row = self.blocks.table_row(req.id, self.table_width)
-        ps = self.page_size
-        arow = getattr(req, "_adapter_row", 0)
-        if cached == 0:
-            bucket = -(-n // ps) * ps
-            ids = np.zeros((1, bucket), np.int32)
-            ids[0, :n] = ids_all
-            self.runner.prefill(ids, n, row, adapter_row=arow)
-        else:
-            suffix = n - cached
-            bucket = -(-suffix // ps) * ps
-            ids = np.zeros((1, bucket), np.int32)
-            ids[0, :suffix] = ids_all[cached:]
-            self.runner.prefill_cached(ids, suffix, cached, row,
-                                       adapter_row=arow)
-        # the replay's logits are discarded (the last token is already
-        # known), so no host sync happens here
-        drift = self.blocks.committed_tokens(req.id) - len(tokens)
-        if drift > 0:
-            # a fault between a speculative dispatch and its sync left
-            # uncommitted draft positions charged — roll them back
-            self.blocks.rollback(req.id, drift)
-        self.table[slot] = row
-        self._pos[slot] = n
-        self._tok[slot] = tokens[-1]
-        self._active[slot] = 1
-        self._aidx[slot] = getattr(req, "_adapter_row", 0)
-        self._push_slot(slot)
-        self._note_phase("prefill", time.perf_counter() - t0)
+        with self._phase("engine.replay", "prefill", parent=req.root_span,
+                         req=req.id, slot=slot) as ph:
+            tokens = [int(t) for t in req.prompt] + list(req.output_tokens)
+            ids_all = tokens[:-1]
+            n = len(ids_all)
+            plan = self.blocks.replay_plan(req.id, ids_all)
+            cached = int(plan["cached_len"])
+            ph.set_attribute("tokens", n)
+            ph.set_attribute("cached_tokens", cached)
+            # ledger: recovery replays re-run committed tokens; the
+            # cache match mirrors replay_plan's global cached_tokens bump
+            req.replays += 1
+            req.prefill_cached_tokens += cached
+            req.prefill_computed_tokens += n - cached
+            row = self.blocks.table_row(req.id, self.table_width)
+            arow = getattr(req, "_adapter_row", 0)
+            self._dispatch_prefill(ids_all[cached:], cached, row, arow)
+            # the replay's logits are discarded (the last token is
+            # already known), so no host sync happens here
+            drift = self.blocks.committed_tokens(req.id) - len(tokens)
+            if drift > 0:
+                # a fault between a speculative dispatch and its sync
+                # left uncommitted draft positions charged — roll them
+                # back
+                self.blocks.rollback(req.id, drift)
+            self.table[slot] = row
+            self._pos[slot] = n
+            self._tok[slot] = tokens[-1]
+            self._active[slot] = 1
+            self._aidx[slot] = arow
+            self._push_slot(slot)
         if req.timeline is not None:
             # everything since the last charge — the poisoned step, the
             # runner rebuild's share, and this replay — was recovery
             req.timeline.note("recovery", self._clock(), event="replay",
                               slot=slot, tokens=n, cached=cached,
                               then="decode")
-        _obs.tracer().record_span(
-            "engine.replay", t0, time.perf_counter(),
-            parent=req.root_span,
-            attributes={"req": req.id, "slot": slot, "tokens": n,
-                        "cached_tokens": cached})
         _obs.flight("engine", "replay", req=req.id, slot=slot,
                     tokens=n, cached=cached)
 

@@ -87,30 +87,35 @@ def decode_layer_paged_tp(w, x, kpool, vpool, table, cos1, sin1, pos,
     b = x.shape[0]
     hd = cfg.head_dim
     ps = kpool.shape[2]
-    h = _rms(x[:, None], w["ln1"], cfg.rms_norm_eps)[:, 0]
-    qp, kp, vp, nh_l, kvh_l = _local_qkv(w, h, hd, lora, aidx, li)
-    q = qp.reshape(b, nh_l, hd)
-    k = kp.reshape(b, kvh_l, hd)
-    v = vp.reshape(b, kvh_l, hd)
-    cos_c = cos1[:, None, :].astype(q.dtype)
-    sin_c = sin1[:, None, :].astype(q.dtype)
-    q = q * cos_c + _rotate_half(q) * sin_c
-    k = k * cos_c + _rotate_half(k) * sin_c
+    with jax.named_scope("attn.qkv"):
+        h = _rms(x[:, None], w["ln1"], cfg.rms_norm_eps)[:, 0]
+        qp, kp, vp, nh_l, kvh_l = _local_qkv(w, h, hd, lora, aidx, li)
+        q = qp.reshape(b, nh_l, hd)
+        k = kp.reshape(b, kvh_l, hd)
+        v = vp.reshape(b, kvh_l, hd)
+        cos_c = cos1[:, None, :].astype(q.dtype)
+        sin_c = sin1[:, None, :].astype(q.dtype)
+        q = q * cos_c + _rotate_half(q) * sin_c
+        k = k * cos_c + _rotate_half(k) * sin_c
 
-    page = jnp.take_along_axis(table, (pos // ps)[:, None], axis=1)[:, 0]
-    off = pos % ps
-    heads = jnp.arange(kvh_l)
-    kpool = kpool.at[page[:, None], heads[None, :], off[:, None]].set(k)
-    vpool = vpool.at[page[:, None], heads[None, :], off[:, None]].set(v)
+    with jax.named_scope("kv.write"):
+        page = jnp.take_along_axis(table, (pos // ps)[:, None], axis=1)[:, 0]
+        off = pos % ps
+        heads = jnp.arange(kvh_l)
+        kpool = kpool.at[page[:, None], heads[None, :], off[:, None]].set(k)
+        vpool = vpool.at[page[:, None], heads[None, :], off[:, None]].set(v)
 
-    attn = select_paged_attention(tp_axis=axis)(
-        q, kpool, vpool, table, pos + 1).reshape(b, nh_l * hd)
-    part = _mm(attn, w["o"])
-    if lora:          # o's A is row-sharded: partial delta, same psum
-        part = part + lora_delta(lora, "o", li, attn, aidx)
-    x = x + jax.lax.psum(part, axis)
-    h = _rms(x[:, None], w["ln2"], cfg.rms_norm_eps)[:, 0]
-    return x + _ffn_tp(w, h, axis, lora, aidx, li), kpool, vpool
+    with jax.named_scope("attn.decode"):
+        attn = select_paged_attention(tp_axis=axis)(
+            q, kpool, vpool, table, pos + 1).reshape(b, nh_l * hd)
+    with jax.named_scope("attn.out"):
+        part = _mm(attn, w["o"])
+        if lora:          # o's A is row-sharded: partial delta, same psum
+            part = part + lora_delta(lora, "o", li, attn, aidx)
+        x = x + jax.lax.psum(part, axis)
+    with jax.named_scope("mlp"):
+        h = _rms(x[:, None], w["ln2"], cfg.rms_norm_eps)[:, 0]
+        return x + _ffn_tp(w, h, axis, lora, aidx, li), kpool, vpool
 
 
 def prefill_layer_tp(w, x, cos, sin, mask, cfg, axis, lora=(),
@@ -119,25 +124,29 @@ def prefill_layer_tp(w, x, cos, sin, mask, cfg, axis, lora=(),
     (out replicated, k/v caches [B, S, kvH/tp, D] local)."""
     b, s, _ = x.shape
     hd = cfg.head_dim
-    h = _rms(x, w["ln1"], cfg.rms_norm_eps)
-    qp, kp, vp, nh_l, kvh_l = _local_qkv(w, h, hd, lora, aidx, li)
-    q = qp.reshape(b, s, nh_l, hd)
-    k = kp.reshape(b, s, kvh_l, hd)
-    v = vp.reshape(b, s, kvh_l, hd)
-    cos_c = cos[None, :, None, :].astype(q.dtype)
-    sin_c = sin[None, :, None, :].astype(q.dtype)
-    q = q * cos_c + _rotate_half(q) * sin_c
-    k = k * cos_c + _rotate_half(k) * sin_c
+    with jax.named_scope("attn.qkv"):
+        h = _rms(x, w["ln1"], cfg.rms_norm_eps)
+        qp, kp, vp, nh_l, kvh_l = _local_qkv(w, h, hd, lora, aidx, li)
+        q = qp.reshape(b, s, nh_l, hd)
+        k = kp.reshape(b, s, kvh_l, hd)
+        v = vp.reshape(b, s, kvh_l, hd)
+        cos_c = cos[None, :, None, :].astype(q.dtype)
+        sin_c = sin[None, :, None, :].astype(q.dtype)
+        q = q * cos_c + _rotate_half(q) * sin_c
+        k = k * cos_c + _rotate_half(k) * sin_c
 
-    from ...ops.pallas.flash_attention import sdpa
-    attn = sdpa(q, k, v, attn_mask=mask[:, None, None, :],
-                is_causal=True).reshape(b, s, nh_l * hd)
-    part = _mm(attn, w["o"])
-    if lora:
-        part = part + lora_delta(lora, "o", li, attn, aidx)
-    x = x + jax.lax.psum(part, axis)
-    h = _rms(x, w["ln2"], cfg.rms_norm_eps)
-    return x + _ffn_tp(w, h, axis, lora, aidx, li), k, v
+    with jax.named_scope("attn.prefill"):
+        from ...ops.pallas.flash_attention import sdpa
+        attn = sdpa(q, k, v, attn_mask=mask[:, None, None, :],
+                    is_causal=True).reshape(b, s, nh_l * hd)
+    with jax.named_scope("attn.out"):
+        part = _mm(attn, w["o"])
+        if lora:
+            part = part + lora_delta(lora, "o", li, attn, aidx)
+        x = x + jax.lax.psum(part, axis)
+    with jax.named_scope("mlp"):
+        h = _rms(x, w["ln2"], cfg.rms_norm_eps)
+        return x + _ffn_tp(w, h, axis, lora, aidx, li), k, v
 
 
 def prefill_layer_cached_tp(w, x, kpool, vpool, row, cos_s, sin_s, mask,
@@ -149,29 +158,33 @@ def prefill_layer_cached_tp(w, x, kpool, vpool, row, cos_s, sin_s, mask,
     the o/down all-reduces; returns (out, k_suffix, v_suffix local)."""
     b, s, _ = x.shape
     hd = cfg.head_dim
-    h = _rms(x, w["ln1"], cfg.rms_norm_eps)
-    qp, kp, vp, nh_l, kvh_l = _local_qkv(w, h, hd, lora, aidx, li)
-    q = qp.reshape(b, s, nh_l, hd)
-    k = kp.reshape(b, s, kvh_l, hd)
-    v = vp.reshape(b, s, kvh_l, hd)
-    cos_c = cos_s[None, :, None, :].astype(q.dtype)
-    sin_c = sin_s[None, :, None, :].astype(q.dtype)
-    q = q * cos_c + _rotate_half(q) * sin_c
-    k = k * cos_c + _rotate_half(k) * sin_c
+    with jax.named_scope("attn.qkv"):
+        h = _rms(x, w["ln1"], cfg.rms_norm_eps)
+        qp, kp, vp, nh_l, kvh_l = _local_qkv(w, h, hd, lora, aidx, li)
+        q = qp.reshape(b, s, nh_l, hd)
+        k = kp.reshape(b, s, kvh_l, hd)
+        v = vp.reshape(b, s, kvh_l, hd)
+        cos_c = cos_s[None, :, None, :].astype(q.dtype)
+        sin_c = sin_s[None, :, None, :].astype(q.dtype)
+        q = q * cos_c + _rotate_half(q) * sin_c
+        k = k * cos_c + _rotate_half(k) * sin_c
 
-    kpre = gather_kv_pages(kpool, row)[None]
-    vpre = gather_kv_pages(vpool, row)[None]
-    from ...ops.pallas.flash_attention import sdpa
-    kcat = jnp.concatenate([kpre.astype(k.dtype), k], axis=1)
-    vcat = jnp.concatenate([vpre.astype(v.dtype), v], axis=1)
-    attn = sdpa(q, kcat, vcat, attn_mask=mask,
-                is_causal=False).reshape(b, s, nh_l * hd)
-    part = _mm(attn, w["o"])
-    if lora:
-        part = part + lora_delta(lora, "o", li, attn, aidx)
-    x = x + jax.lax.psum(part, axis)
-    h = _rms(x, w["ln2"], cfg.rms_norm_eps)
-    return x + _ffn_tp(w, h, axis, lora, aidx, li), k, v
+    with jax.named_scope("attn.prefill"):
+        kpre = gather_kv_pages(kpool, row)[None]
+        vpre = gather_kv_pages(vpool, row)[None]
+        from ...ops.pallas.flash_attention import sdpa
+        kcat = jnp.concatenate([kpre.astype(k.dtype), k], axis=1)
+        vcat = jnp.concatenate([vpre.astype(v.dtype), v], axis=1)
+        attn = sdpa(q, kcat, vcat, attn_mask=mask,
+                    is_causal=False).reshape(b, s, nh_l * hd)
+    with jax.named_scope("attn.out"):
+        part = _mm(attn, w["o"])
+        if lora:
+            part = part + lora_delta(lora, "o", li, attn, aidx)
+        x = x + jax.lax.psum(part, axis)
+    with jax.named_scope("mlp"):
+        h = _rms(x, w["ln2"], cfg.rms_norm_eps)
+        return x + _ffn_tp(w, h, axis, lora, aidx, li), k, v
 
 
 # ------------------------------------------------- int8 KV page bodies
@@ -220,37 +233,42 @@ def decode_layer_paged_quant(w, x, kpool, vpool, kscale, vscale, table,
     b = x.shape[0]
     hd = cfg.head_dim
     ps = kpool.shape[2]
-    h = _rms(x[:, None], w["ln1"], cfg.rms_norm_eps)[:, 0]
-    qp, kp, vp, nh_l, kvh_l = _proj_qkv(w, h, cfg, axis, lora, aidx, li)
-    q = qp.reshape(b, nh_l, hd)
-    k = kp.reshape(b, kvh_l, hd)
-    v = vp.reshape(b, kvh_l, hd)
-    cos_c = cos1[:, None, :].astype(q.dtype)
-    sin_c = sin1[:, None, :].astype(q.dtype)
-    q = q * cos_c + _rotate_half(q) * sin_c
-    k = k * cos_c + _rotate_half(k) * sin_c
+    with jax.named_scope("attn.qkv"):
+        h = _rms(x[:, None], w["ln1"], cfg.rms_norm_eps)[:, 0]
+        qp, kp, vp, nh_l, kvh_l = _proj_qkv(w, h, cfg, axis, lora, aidx, li)
+        q = qp.reshape(b, nh_l, hd)
+        k = kp.reshape(b, kvh_l, hd)
+        v = vp.reshape(b, kvh_l, hd)
+        cos_c = cos1[:, None, :].astype(q.dtype)
+        sin_c = sin1[:, None, :].astype(q.dtype)
+        q = q * cos_c + _rotate_half(q) * sin_c
+        k = k * cos_c + _rotate_half(k) * sin_c
 
-    page = jnp.take_along_axis(table, (pos // ps)[:, None], axis=1)[:, 0]
-    off = pos % ps
-    heads = jnp.arange(kvh_l)
-    qk, sk = quantize_kv_rows(k)
-    qv, sv = quantize_kv_rows(v)
-    idx = (page[:, None], heads[None, :], off[:, None])
-    kpool = kpool.at[idx].set(qk)
-    vpool = vpool.at[idx].set(qv)
-    kscale = kscale.at[idx].set(sk)
-    vscale = vscale.at[idx].set(sv)
+    with jax.named_scope("kv.write"):
+        page = jnp.take_along_axis(table, (pos // ps)[:, None], axis=1)[:, 0]
+        off = pos % ps
+        heads = jnp.arange(kvh_l)
+        qk, sk = quantize_kv_rows(k)
+        qv, sv = quantize_kv_rows(v)
+        idx = (page[:, None], heads[None, :], off[:, None])
+        kpool = kpool.at[idx].set(qk)
+        vpool = vpool.at[idx].set(qv)
+        kscale = kscale.at[idx].set(sk)
+        vscale = vscale.at[idx].set(sv)
 
-    attn = paged_attention_quant(
-        q, kpool, vpool, kscale, vscale, table, pos + 1,
-        tp_axis=axis).reshape(b, nh_l * hd)
-    part = _mm(attn, w["o"])
-    if lora:
-        part = part + lora_delta(lora, "o", li, attn, aidx)
-    x = x + _out_reduce(part, axis)
-    h = _rms(x[:, None], w["ln2"], cfg.rms_norm_eps)[:, 0]
-    return (x + _ffn_quant(w, h, axis, lora, aidx, li), kpool, vpool,
-            kscale, vscale)
+    with jax.named_scope("attn.decode"):
+        attn = paged_attention_quant(
+            q, kpool, vpool, kscale, vscale, table, pos + 1,
+            tp_axis=axis).reshape(b, nh_l * hd)
+    with jax.named_scope("attn.out"):
+        part = _mm(attn, w["o"])
+        if lora:
+            part = part + lora_delta(lora, "o", li, attn, aidx)
+        x = x + _out_reduce(part, axis)
+    with jax.named_scope("mlp"):
+        h = _rms(x[:, None], w["ln2"], cfg.rms_norm_eps)[:, 0]
+        return (x + _ffn_quant(w, h, axis, lora, aidx, li), kpool, vpool,
+                kscale, vscale)
 
 
 def prefill_layer_cached_quant(w, x, kpool, vpool, kscale, vscale, row,
@@ -262,26 +280,30 @@ def prefill_layer_cached_quant(w, x, kpool, vpool, kscale, vscale, row,
     Returns (out, k_suffix, v_suffix) like the dense mirrors."""
     b, s, _ = x.shape
     hd = cfg.head_dim
-    h = _rms(x, w["ln1"], cfg.rms_norm_eps)
-    qp, kp, vp, nh_l, kvh_l = _proj_qkv(w, h, cfg, axis, lora, aidx, li)
-    q = qp.reshape(b, s, nh_l, hd)
-    k = kp.reshape(b, s, kvh_l, hd)
-    v = vp.reshape(b, s, kvh_l, hd)
-    cos_c = cos_s[None, :, None, :].astype(q.dtype)
-    sin_c = sin_s[None, :, None, :].astype(q.dtype)
-    q = q * cos_c + _rotate_half(q) * sin_c
-    k = k * cos_c + _rotate_half(k) * sin_c
+    with jax.named_scope("attn.qkv"):
+        h = _rms(x, w["ln1"], cfg.rms_norm_eps)
+        qp, kp, vp, nh_l, kvh_l = _proj_qkv(w, h, cfg, axis, lora, aidx, li)
+        q = qp.reshape(b, s, nh_l, hd)
+        k = kp.reshape(b, s, kvh_l, hd)
+        v = vp.reshape(b, s, kvh_l, hd)
+        cos_c = cos_s[None, :, None, :].astype(q.dtype)
+        sin_c = sin_s[None, :, None, :].astype(q.dtype)
+        q = q * cos_c + _rotate_half(q) * sin_c
+        k = k * cos_c + _rotate_half(k) * sin_c
 
-    kpre = gather_kv_pages_quant(kpool, kscale, row, k.dtype)[None]
-    vpre = gather_kv_pages_quant(vpool, vscale, row, v.dtype)[None]
-    from ...ops.pallas.flash_attention import sdpa
-    kcat = jnp.concatenate([kpre, k], axis=1)
-    vcat = jnp.concatenate([vpre, v], axis=1)
-    attn = sdpa(q, kcat, vcat, attn_mask=mask,
-                is_causal=False).reshape(b, s, nh_l * hd)
-    part = _mm(attn, w["o"])
-    if lora:
-        part = part + lora_delta(lora, "o", li, attn, aidx)
-    x = x + _out_reduce(part, axis)
-    h = _rms(x, w["ln2"], cfg.rms_norm_eps)
-    return x + _ffn_quant(w, h, axis, lora, aidx, li), k, v
+    with jax.named_scope("attn.prefill"):
+        kpre = gather_kv_pages_quant(kpool, kscale, row, k.dtype)[None]
+        vpre = gather_kv_pages_quant(vpool, vscale, row, v.dtype)[None]
+        from ...ops.pallas.flash_attention import sdpa
+        kcat = jnp.concatenate([kpre, k], axis=1)
+        vcat = jnp.concatenate([vpre, v], axis=1)
+        attn = sdpa(q, kcat, vcat, attn_mask=mask,
+                    is_causal=False).reshape(b, s, nh_l * hd)
+    with jax.named_scope("attn.out"):
+        part = _mm(attn, w["o"])
+        if lora:
+            part = part + lora_delta(lora, "o", li, attn, aidx)
+        x = x + _out_reduce(part, axis)
+    with jax.named_scope("mlp"):
+        h = _rms(x, w["ln2"], cfg.rms_norm_eps)
+        return x + _ffn_quant(w, h, axis, lora, aidx, li), k, v

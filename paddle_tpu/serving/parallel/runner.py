@@ -515,8 +515,8 @@ class ModelRunner:
         kv_quant = self.kv_quant
         runner = self
 
-        def step(state, kpool, vpool, kscale, vscale, table, pos, tok,
-                 active, ring, ridx, cos, sin, lora, aidx):
+        def decode_step(state, kpool, vpool, kscale, vscale, table, pos,
+                        tok, active, ring, ridx, cos, sin, lora, aidx):
             # python body runs at trace time only: a second execution of
             # this line means an admission/eviction re-traced the step
             runner.decode_traces += 1
@@ -525,10 +525,11 @@ class ModelRunner:
             # (deferred-sync overrun); clamp so its rope/table lookups
             # stay in range — overrun writes land in the slot's own
             # reserved tail or the dump page, never another sequence
-            posc = jnp.minimum(pos, rope_len - 1)
-            emb = jnp.take(state["llama.embed_tokens.weight"], tok,
-                           axis=0)
-            cos1, sin1 = _rope_at(cos, sin, posc)
+            with jax.named_scope("embed"):
+                posc = jnp.minimum(pos, rope_len - 1)
+                emb = jnp.take(state["llama.embed_tokens.weight"], tok,
+                               axis=0)
+                cos1, sin1 = _rope_at(cos, sin, posc)
             h = emb
             kps, vps, kss, vss = [], [], [], []
             for i in range(L):
@@ -546,26 +547,28 @@ class ModelRunner:
                         posc, cfg, lora, aidx, i)
                 kps.append(kp_)
                 vps.append(vp_)
-            kpool = jnp.stack(kps)
-            vpool = jnp.stack(vps)
-            if kv_quant:
-                kscale = jnp.stack(kss)
-                vscale = jnp.stack(vss)
-            h = _rms(h[:, None], state["llama.norm.weight"],
-                     cfg.rms_norm_eps)[:, 0]
-            logits = _logits_of(state, h).astype(jnp.float32)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            act = active.astype(bool)
-            pos2 = pos + active                 # idle slots stay parked
-            tok2 = jnp.where(act, nxt, tok)     # greedy chains on device
-            ring2 = (ring.at[ridx, :, 0].set(nxt) if wide_ring
-                     else ring.at[ridx].set(nxt))
-            ridx2 = (ridx + 1) % ring.shape[0]
+            with jax.named_scope("kv.write"):
+                kpool = jnp.stack(kps)
+                vpool = jnp.stack(vps)
+                if kv_quant:
+                    kscale = jnp.stack(kss)
+                    vscale = jnp.stack(vss)
+            with jax.named_scope("head"):
+                h = _rms(h[:, None], state["llama.norm.weight"],
+                         cfg.rms_norm_eps)[:, 0]
+                logits = _logits_of(state, h).astype(jnp.float32)
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                act = active.astype(bool)
+                pos2 = pos + active                 # idle slots stay parked
+                tok2 = jnp.where(act, nxt, tok)     # greedy chains on device
+                ring2 = (ring.at[ridx, :, 0].set(nxt) if wide_ring
+                         else ring.at[ridx].set(nxt))
+                ridx2 = (ridx + 1) % ring.shape[0]
             return (kpool, vpool, kscale, vscale, pos2, tok2, ring2,
                     ridx2, logits if emit_logits
                     else jnp.zeros((), jnp.float32))
 
-        return step
+        return decode_step
 
     def _build_step_tp(self):
         """The shard_map body: same step, per-shard layers.  Everything
@@ -580,14 +583,15 @@ class ModelRunner:
         kv_quant = self.kv_quant
         runner = self
 
-        def step(state, kpool, vpool, kscale, vscale, table, pos, tok,
-                 active, ring, ridx, cos, sin, lora, aidx):
+        def decode_step(state, kpool, vpool, kscale, vscale, table, pos,
+                        tok, active, ring, ridx, cos, sin, lora, aidx):
             runner.decode_traces += 1
             _M_STEP_TRACES.inc()
-            posc = jnp.minimum(pos, rope_len - 1)
-            emb = jnp.take(state["llama.embed_tokens.weight"], tok,
-                           axis=0)
-            cos1, sin1 = _rope_at(cos, sin, posc)
+            with jax.named_scope("embed"):
+                posc = jnp.minimum(pos, rope_len - 1)
+                emb = jnp.take(state["llama.embed_tokens.weight"], tok,
+                               axis=0)
+                cos1, sin1 = _rope_at(cos, sin, posc)
             h = emb
             kps, vps, kss, vss = [], [], [], []
             for i in range(L):
@@ -605,26 +609,28 @@ class ModelRunner:
                         posc, cfg, TP_AXIS, lora, aidx, i)
                 kps.append(kp_)
                 vps.append(vp_)
-            kpool = jnp.stack(kps)
-            vpool = jnp.stack(vps)
-            if kv_quant:
-                kscale = jnp.stack(kss)
-                vscale = jnp.stack(vss)
-            h = _rms(h[:, None], state["llama.norm.weight"],
-                     cfg.rms_norm_eps)[:, 0]
-            logits = _logits_of(state, h).astype(jnp.float32)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            act = active.astype(bool)
-            pos2 = pos + active
-            tok2 = jnp.where(act, nxt, tok)
-            ring2 = (ring.at[ridx, :, 0].set(nxt) if wide_ring
-                     else ring.at[ridx].set(nxt))
-            ridx2 = (ridx + 1) % ring.shape[0]
+            with jax.named_scope("kv.write"):
+                kpool = jnp.stack(kps)
+                vpool = jnp.stack(vps)
+                if kv_quant:
+                    kscale = jnp.stack(kss)
+                    vscale = jnp.stack(vss)
+            with jax.named_scope("head"):
+                h = _rms(h[:, None], state["llama.norm.weight"],
+                         cfg.rms_norm_eps)[:, 0]
+                logits = _logits_of(state, h).astype(jnp.float32)
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                act = active.astype(bool)
+                pos2 = pos + active
+                tok2 = jnp.where(act, nxt, tok)
+                ring2 = (ring.at[ridx, :, 0].set(nxt) if wide_ring
+                         else ring.at[ridx].set(nxt))
+                ridx2 = (ridx + 1) % ring.shape[0]
             return (kpool, vpool, kscale, vscale, pos2, tok2, ring2,
                     ridx2, logits if emit_logits
                     else jnp.zeros((), jnp.float32))
 
-        return step
+        return decode_step
 
     def _make_verify_fn(self):
         if self.tp == 1:
@@ -676,31 +682,32 @@ class ModelRunner:
         kv_quant = self.kv_quant
         runner = self
 
-        def verify(state, kpool, vpool, kscale, vscale, table, pos,
-                   tok, active, ring, ridx, draft, dlen, cos, sin,
-                   lora, aidx):
+        def verify_step(state, kpool, vpool, kscale, vscale, table, pos,
+                        tok, active, ring, ridx, draft, dlen, cos, sin,
+                        lora, aidx):
             # trace-time counters, exactly like the plain step body
             runner.decode_traces += 1
             runner.verify_traces += 1
             _M_STEP_TRACES.inc()
             _M_VERIFY_TRACES.inc()
-            S = tok.shape[0]
-            # [S, M] candidate grid: column 0 is the slot's current
-            # token (the plain step's input), columns 1..k its drafts
-            grid = jnp.concatenate([tok[:, None], draft], axis=1)
-            offs = jnp.arange(M, dtype=jnp.int32)
-            pos_f = (pos[:, None] + offs[None, :]).reshape(-1)
-            posc = jnp.minimum(pos_f, rope_len - 1)
-            tok_f = grid.reshape(-1)
-            table_f = jnp.repeat(table, M, axis=0)
-            # every candidate row of a slot shares its adapter; `lora`
-            # is a pytree whose STRUCTURE (empty vs non-empty tuple)
-            # carries the on/off bit — truthiness is trace-time static
-            # tpu-lint: disable=jit-traced-branch
-            aidx_f = jnp.repeat(aidx, M) if lora else aidx
-            emb = jnp.take(state["llama.embed_tokens.weight"], tok_f,
-                           axis=0)
-            cos1, sin1 = _rope_at(cos, sin, posc)
+            with jax.named_scope("embed"):
+                S = tok.shape[0]
+                # [S, M] candidate grid: column 0 is the slot's current
+                # token (the plain step's input), columns 1..k its drafts
+                grid = jnp.concatenate([tok[:, None], draft], axis=1)
+                offs = jnp.arange(M, dtype=jnp.int32)
+                pos_f = (pos[:, None] + offs[None, :]).reshape(-1)
+                posc = jnp.minimum(pos_f, rope_len - 1)
+                tok_f = grid.reshape(-1)
+                table_f = jnp.repeat(table, M, axis=0)
+                # every candidate row of a slot shares its adapter; `lora`
+                # is a pytree whose STRUCTURE (empty vs non-empty tuple)
+                # carries the on/off bit — truthiness is trace-time static
+                # tpu-lint: disable=jit-traced-branch
+                aidx_f = jnp.repeat(aidx, M) if lora else aidx
+                emb = jnp.take(state["llama.embed_tokens.weight"], tok_f,
+                               axis=0)
+                cos1, sin1 = _rope_at(cos, sin, posc)
             h = emb
             kps, vps, kss, vss = [], [], [], []
             for i in range(L):
@@ -722,38 +729,40 @@ class ModelRunner:
                         posc, cfg, lora, aidx_f, i)
                 kps.append(kp_)
                 vps.append(vp_)
-            kpool = jnp.stack(kps)
-            vpool = jnp.stack(vps)
-            if kv_quant:
-                kscale = jnp.stack(kss)
-                vscale = jnp.stack(vss)
-            h = _rms(h[:, None], state["llama.norm.weight"],
-                     cfg.rms_norm_eps)[:, 0]
-            logits = _logits_of(state, h).astype(jnp.float32)
-            y = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            y = y.reshape(S, M)
-            # longest matching prefix: draft[:, j] proposed what the
-            # target's own argmax y[:, j] confirms (or not)
-            m = ((draft == y[:, :k]) &
-                 (offs[None, :k] < dlen[:, None])).astype(jnp.int32)
-            # cast back: cumprod/sum promote to int64 under x64, which
-            # would change pos2's dtype and re-trace the plain step
-            acc = jnp.cumprod(m, axis=1).sum(axis=1).astype(jnp.int32)
-            commit = (acc + 1) * active                     # [S]; idle: 0
-            pos2 = pos + commit
-            tok_new = jnp.take_along_axis(y, acc[:, None], axis=1)[:, 0]
-            tok2 = jnp.where(active.astype(bool), tok_new, tok)
-            ring2 = ring.at[ridx].set(y)
-            ridx2 = (ridx + 1) % ring.shape[0]
+            with jax.named_scope("kv.write"):
+                kpool = jnp.stack(kps)
+                vpool = jnp.stack(vps)
+                if kv_quant:
+                    kscale = jnp.stack(kss)
+                    vscale = jnp.stack(vss)
+            with jax.named_scope("head"):
+                h = _rms(h[:, None], state["llama.norm.weight"],
+                         cfg.rms_norm_eps)[:, 0]
+                logits = _logits_of(state, h).astype(jnp.float32)
+                y = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                y = y.reshape(S, M)
+                # longest matching prefix: draft[:, j] proposed what the
+                # target's own argmax y[:, j] confirms (or not)
+                m = ((draft == y[:, :k]) &
+                     (offs[None, :k] < dlen[:, None])).astype(jnp.int32)
+                # cast back: cumprod/sum promote to int64 under x64, which
+                # would change pos2's dtype and re-trace the plain step
+                acc = jnp.cumprod(m, axis=1).sum(axis=1).astype(jnp.int32)
+                commit = (acc + 1) * active                     # [S]; idle: 0
+                pos2 = pos + commit
+                tok_new = jnp.take_along_axis(y, acc[:, None], axis=1)[:, 0]
+                tok2 = jnp.where(active.astype(bool), tok_new, tok)
+                ring2 = ring.at[ridx].set(y)
+                ridx2 = (ridx + 1) % ring.shape[0]
             return (kpool, vpool, kscale, vscale, pos2, tok2, ring2,
                     ridx2)
 
-        return verify
+        return verify_step
 
     def _make_copy_page_fn(self):
         kv_quant = self.kv_quant
 
-        def copy(kp, vp, ks, vs, src, dst):
+        def copy_page(kp, vp, ks, vs, src, dst):
             kp2 = kp.at[:, dst].set(kp[:, src])
             vp2 = vp.at[:, dst].set(vp[:, src])
             if kv_quant:        # scale rows travel with their page
@@ -763,14 +772,14 @@ class ModelRunner:
 
         if self.tp == 1:
             # CoW page copy: src/dst are data — one trace for the engine
-            return jax.jit(copy, donate_argnums=(0, 1, 2, 3))
+            return jax.jit(copy_page, donate_argnums=(0, 1, 2, 3))
         from jax.sharding import PartitionSpec as P
         pool = self._pool_pspec
         sspec = self._scale_pspec if kv_quant else P()
         # per-shard copy: a page holds every local head's rows, so the
         # CoW duplicate is collective-free
         mapped = jax.shard_map(
-            copy, mesh=self.mesh,
+            copy_page, mesh=self.mesh,
             in_specs=(pool, pool, sspec, sspec, P(), P()),
             out_specs=(pool, pool, sspec, sspec), check_vma=False)
         return jax.jit(mapped, donate_argnums=(0, 1, 2, 3))
@@ -789,8 +798,9 @@ class ModelRunner:
         def prefill(state, ids, length, table_row, kpool, vpool,
                     kscale, vscale, cos, sin, lora, aidx):
             _M_PREFILL_TRACES.labels(str(bucket)).inc()
-            x = jnp.take(state["llama.embed_tokens.weight"], ids, axis=0)
-            pmask = jnp.arange(bucket)[None, :] < length
+            with jax.named_scope("embed"):
+                x = jnp.take(state["llama.embed_tokens.weight"], ids, axis=0)
+                pmask = jnp.arange(bucket)[None, :] < length
             for i in range(L):
                 w = _layer_weights(state, i)
                 if tp == 1:
@@ -801,30 +811,32 @@ class ModelRunner:
                     x, k, v = prefill_layer_tp(w, x, cos[:bucket],
                                                sin[:bucket], pmask, cfg,
                                                TP_AXIS, lora, aidx, i)
-                if kv_quant:
-                    # quantize the whole prompt's KV once per layer,
-                    # then page the int8 rows + their scales
-                    qk, sk = quantize_kv_rows(k[0])
-                    qv, sv = quantize_kv_rows(v[0])
-                    k, v = qk[None], qv[None]
-                for p in range(n_pages):
-                    sl = slice(p * ps, (p + 1) * ps)
-                    rows_k = k[0, sl].swapaxes(0, 1)
-                    rows_v = v[0, sl].swapaxes(0, 1)
-                    kpool = kpool.at[i, table_row[p]].set(
-                        rows_k.astype(kpool.dtype))
-                    vpool = vpool.at[i, table_row[p]].set(
-                        rows_v.astype(vpool.dtype))
+                with jax.named_scope("kv.write"):
                     if kv_quant:
-                        kscale = kscale.at[i, table_row[p]].set(
-                            sk[sl].swapaxes(0, 1))
-                        vscale = vscale.at[i, table_row[p]].set(
-                            sv[sl].swapaxes(0, 1))
-            x = _rms(x, state["llama.norm.weight"], cfg.rms_norm_eps)
-            last = jnp.take_along_axis(
-                x, (length - 1)[:, None, None].astype(jnp.int32),
-                axis=1)[:, 0]
-            logits = _logits_of(state, last).astype(jnp.float32)
+                        # quantize the whole prompt's KV once per layer,
+                        # then page the int8 rows + their scales
+                        qk, sk = quantize_kv_rows(k[0])
+                        qv, sv = quantize_kv_rows(v[0])
+                        k, v = qk[None], qv[None]
+                    for p in range(n_pages):
+                        sl = slice(p * ps, (p + 1) * ps)
+                        rows_k = k[0, sl].swapaxes(0, 1)
+                        rows_v = v[0, sl].swapaxes(0, 1)
+                        kpool = kpool.at[i, table_row[p]].set(
+                            rows_k.astype(kpool.dtype))
+                        vpool = vpool.at[i, table_row[p]].set(
+                            rows_v.astype(vpool.dtype))
+                        if kv_quant:
+                            kscale = kscale.at[i, table_row[p]].set(
+                                sk[sl].swapaxes(0, 1))
+                            vscale = vscale.at[i, table_row[p]].set(
+                                sv[sl].swapaxes(0, 1))
+            with jax.named_scope("head"):
+                x = _rms(x, state["llama.norm.weight"], cfg.rms_norm_eps)
+                last = jnp.take_along_axis(
+                    x, (length - 1)[:, None, None].astype(jnp.int32),
+                    axis=1)[:, 0]
+                logits = _logits_of(state, last).astype(jnp.float32)
             return kpool, vpool, kscale, vscale, logits
 
         # kpool/vpool donation: prefill updates the pool in place instead
@@ -865,29 +877,30 @@ class ModelRunner:
 
         kv_quant = self.kv_quant
 
-        def prefill(state, ids, length, cached_len, row, kpool, vpool,
-                    kscale, vscale, cos, sin, lora, aidx):
+        def prefill_cached(state, ids, length, cached_len, row, kpool,
+                           vpool, kscale, vscale, cos, sin, lora, aidx):
             _M_PREFILL_TRACES.labels(f"cached:{bucket}").inc()
-            x = jnp.take(state["llama.embed_tokens.weight"], ids, axis=0)
-            j = jnp.arange(bucket)
-            absp = cached_len + j               # absolute positions
-            posc = jnp.minimum(absp, rope_len - 1)
-            cos_s = jnp.take(cos, posc, axis=0)
-            sin_s = jnp.take(sin, posc, axis=0)
-            # suffix queries see: resident prefix keys (< cached_len),
-            # then causal within the (padded) suffix
-            t_pre = jnp.arange(W * ps)
-            pre_ok = jnp.broadcast_to(t_pre[None, :] < cached_len,
-                                      (bucket, W * ps))
-            suf_ok = (j[None, :] <= j[:, None]) & (j[None, :] < length[0])
-            mask = jnp.concatenate([pre_ok, suf_ok], axis=1)[None, None]
-            # per-token write targets (padding lands on the dump page)
-            valid = j < length[0]
-            page_w = jnp.where(valid,
-                               row[jnp.minimum(absp // ps, W - 1)], dump)
-            off = absp % ps
-            heads = jnp.arange(kvh_l)
-            widx = (page_w[:, None], heads[None, :], off[:, None])
+            with jax.named_scope("embed"):
+                x = jnp.take(state["llama.embed_tokens.weight"], ids, axis=0)
+                j = jnp.arange(bucket)
+                absp = cached_len + j               # absolute positions
+                posc = jnp.minimum(absp, rope_len - 1)
+                cos_s = jnp.take(cos, posc, axis=0)
+                sin_s = jnp.take(sin, posc, axis=0)
+                # suffix queries see: resident prefix keys (< cached_len),
+                # then causal within the (padded) suffix
+                t_pre = jnp.arange(W * ps)
+                pre_ok = jnp.broadcast_to(t_pre[None, :] < cached_len,
+                                          (bucket, W * ps))
+                suf_ok = (j[None, :] <= j[:, None]) & (j[None, :] < length[0])
+                mask = jnp.concatenate([pre_ok, suf_ok], axis=1)[None, None]
+                # per-token write targets (padding lands on the dump page)
+                valid = j < length[0]
+                page_w = jnp.where(valid,
+                                   row[jnp.minimum(absp // ps, W - 1)], dump)
+                off = absp % ps
+                heads = jnp.arange(kvh_l)
+                widx = (page_w[:, None], heads[None, :], off[:, None])
             for i in range(L):
                 w = _layer_weights(state, i)
                 if kv_quant:
@@ -895,16 +908,18 @@ class ModelRunner:
                         w, x, kpool[i], vpool[i], kscale[i], vscale[i],
                         row, cos_s, sin_s, mask, cfg,
                         TP_AXIS if tp > 1 else None, lora, aidx, i)
-                    qk, sk = quantize_kv_rows(k[0])
-                    qv, sv = quantize_kv_rows(v[0])
-                    kpool = kpool.at[(i,) + widx].set(qk)
-                    vpool = vpool.at[(i,) + widx].set(qv)
-                    kscale = kscale.at[(i,) + widx].set(sk)
-                    vscale = vscale.at[(i,) + widx].set(sv)
+                    with jax.named_scope("kv.write"):
+                        qk, sk = quantize_kv_rows(k[0])
+                        qv, sv = quantize_kv_rows(v[0])
+                        kpool = kpool.at[(i,) + widx].set(qk)
+                        vpool = vpool.at[(i,) + widx].set(qv)
+                        kscale = kscale.at[(i,) + widx].set(sk)
+                        vscale = vscale.at[(i,) + widx].set(sv)
                     continue
                 if tp == 1:
-                    kpre = gather_kv_pages(kpool[i], row)
-                    vpre = gather_kv_pages(vpool[i], row)
+                    with jax.named_scope("attn.prefill"):
+                        kpre = gather_kv_pages(kpool[i], row)
+                        vpre = gather_kv_pages(vpool[i], row)
                     x, k, v = _prefill_layer_cached(
                         w, x, kpre[None], vpre[None], cos_s, sin_s,
                         mask, cfg, lora, aidx, i)
@@ -912,25 +927,27 @@ class ModelRunner:
                     x, k, v = prefill_layer_cached_tp(
                         w, x, kpool[i], vpool[i], row, cos_s, sin_s,
                         mask, cfg, TP_AXIS, lora, aidx, i)
-                kpool = kpool.at[i, page_w[:, None], heads[None, :],
-                                 off[:, None]].set(k[0])
-                vpool = vpool.at[i, page_w[:, None], heads[None, :],
-                                 off[:, None]].set(v[0])
-            x = _rms(x, state["llama.norm.weight"], cfg.rms_norm_eps)
-            last = jnp.take_along_axis(
-                x, (length - 1)[:, None, None].astype(jnp.int32),
-                axis=1)[:, 0]
-            logits = _logits_of(state, last).astype(jnp.float32)
+                with jax.named_scope("kv.write"):
+                    kpool = kpool.at[i, page_w[:, None], heads[None, :],
+                                     off[:, None]].set(k[0])
+                    vpool = vpool.at[i, page_w[:, None], heads[None, :],
+                                     off[:, None]].set(v[0])
+            with jax.named_scope("head"):
+                x = _rms(x, state["llama.norm.weight"], cfg.rms_norm_eps)
+                last = jnp.take_along_axis(
+                    x, (length - 1)[:, None, None].astype(jnp.int32),
+                    axis=1)[:, 0]
+                logits = _logits_of(state, last).astype(jnp.float32)
             return kpool, vpool, kscale, vscale, logits
 
         if tp == 1:
-            fn = jax.jit(prefill, donate_argnums=(5, 6, 7, 8))
+            fn = jax.jit(prefill_cached, donate_argnums=(5, 6, 7, 8))
         else:
             from jax.sharding import PartitionSpec as P
             pool = self._pool_pspec
             sspec = self._scale_pspec if kv_quant else P()
             mapped = jax.shard_map(
-                prefill, mesh=self.mesh,
+                prefill_cached, mesh=self.mesh,
                 in_specs=(self._state_specs(), P(), P(), P(), P(), pool,
                           pool, sspec, sspec, P(), P(),
                           self._lora_pspecs(), P()),
@@ -1170,30 +1187,34 @@ def _prefill_layer_cached(w, x, kpre, vpre, cos_s, sin_s, mask, cfg,
     b, s, _ = x.shape
     nh, kvh, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    cfg.head_dim)
-    h = _rms(x, w["ln1"], cfg.rms_norm_eps)
-    qp, kp, vp = _qkv_proj(w, h, nh, kvh, hd, lora, aidx, li)
-    q = qp.reshape(b, s, nh, hd)
-    k = kp.reshape(b, s, kvh, hd)
-    v = vp.reshape(b, s, kvh, hd)
-    cos_c = cos_s[None, :, None, :].astype(q.dtype)
-    sin_c = sin_s[None, :, None, :].astype(q.dtype)
-    q = q * cos_c + _rotate_half(q) * sin_c
-    k = k * cos_c + _rotate_half(k) * sin_c
+    with jax.named_scope("attn.qkv"):
+        h = _rms(x, w["ln1"], cfg.rms_norm_eps)
+        qp, kp, vp = _qkv_proj(w, h, nh, kvh, hd, lora, aidx, li)
+        q = qp.reshape(b, s, nh, hd)
+        k = kp.reshape(b, s, kvh, hd)
+        v = vp.reshape(b, s, kvh, hd)
+        cos_c = cos_s[None, :, None, :].astype(q.dtype)
+        sin_c = sin_s[None, :, None, :].astype(q.dtype)
+        q = q * cos_c + _rotate_half(q) * sin_c
+        k = k * cos_c + _rotate_half(k) * sin_c
 
-    from ...ops.pallas.flash_attention import sdpa
-    kcat = jnp.concatenate([kpre.astype(k.dtype), k], axis=1)
-    vcat = jnp.concatenate([vpre.astype(v.dtype), v], axis=1)
-    attn = sdpa(q, kcat, vcat, attn_mask=mask,
-                is_causal=False).reshape(b, s, nh * hd)
-    o = _mm(attn, w["o"])
-    # `lora` pytree structure (empty tuple = off) is trace-time static
-    # tpu-lint: disable=jit-traced-branch
-    if lora:
-        from ...ops.pallas.lora_matmul import lora_delta
-        o = o + lora_delta(lora, "o", li, attn, aidx)
-    x = x + o
-    h = _rms(x, w["ln2"], cfg.rms_norm_eps)
-    return (x + _ffn(w, h, lora, aidx, li), k, v)
+    with jax.named_scope("attn.prefill"):
+        from ...ops.pallas.flash_attention import sdpa
+        kcat = jnp.concatenate([kpre.astype(k.dtype), k], axis=1)
+        vcat = jnp.concatenate([vpre.astype(v.dtype), v], axis=1)
+        attn = sdpa(q, kcat, vcat, attn_mask=mask,
+                    is_causal=False).reshape(b, s, nh * hd)
+    with jax.named_scope("attn.out"):
+        o = _mm(attn, w["o"])
+        # `lora` pytree structure (empty tuple = off) is trace-time static
+        # tpu-lint: disable=jit-traced-branch
+        if lora:
+            from ...ops.pallas.lora_matmul import lora_delta
+            o = o + lora_delta(lora, "o", li, attn, aidx)
+        x = x + o
+    with jax.named_scope("mlp"):
+        h = _rms(x, w["ln2"], cfg.rms_norm_eps)
+        return (x + _ffn(w, h, lora, aidx, li), k, v)
 
 
 def _logits_of(state, h):

@@ -62,6 +62,7 @@ class Scheduler:
         self.preempt_enabled = bool(preempt_enabled)
         self._clock = clock or time.monotonic
         self._arrivals = 0         # FIFO stamps handed out by submit()
+        self.evictions = 0         # slots freed by evict(), ever
         self._finalize = None      # engine callback: (req, reason, now)
         self._on_evict = None      # engine callback: (slot,) — park it
         self._preempt = None       # engine callback: (slot,) -> bool
@@ -262,6 +263,7 @@ class Scheduler:
         if req is None:
             return
         self.slots[slot] = None
+        self.evictions += 1
         self.blocks.free_seq(req.id)
         if self._on_evict is not None:
             self._on_evict(slot)

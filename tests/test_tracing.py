@@ -184,6 +184,263 @@ class TestTracer:
         assert "span-worker" in names
 
 
+# ----------------------------------------------------------------- phase
+class TestPhase:
+    def test_nests_and_commits_one_span_each(self):
+        tr = tracing.Tracer(max_spans=8)
+        with tr.phase("outer", k="v") as outer:
+            assert tr.current_span() is outer
+            assert outer.seconds == 0.0         # still open
+            with tr.phase("inner") as inner:
+                inner.set_attribute("rows", 3)
+                time.sleep(0.002)
+            assert tr.current_span() is outer
+        assert tr.current_span() is None
+        assert [s.name for s in tr.spans()] == ["inner", "outer"]
+        assert inner.parent_id == outer.span_id
+        assert inner.trace_id == outer.trace_id
+        assert outer.parent_id is None
+        assert outer.attributes == {"k": "v"}
+        assert inner.attributes == {"rows": 3}
+        # the seconds handed back are the ring's own interval
+        assert inner.seconds == inner.end_time - inner.start >= 0.002
+        assert outer.start <= inner.start
+        assert inner.end_time <= outer.end_time
+
+    def test_explicit_parent_and_forced_root(self):
+        tr = tracing.Tracer(max_spans=8)
+        root = tr.start_span("request")
+        with tr.phase("step", parent=None) as step:
+            with tr.phase("prefill", parent=root) as prefill:
+                pass
+        root.end()
+        assert step.parent_id is None
+        assert step.trace_id != root.trace_id
+        assert prefill.parent_id == root.span_id
+        assert prefill.trace_id == root.trace_id
+
+    def test_an_exception_still_commits_and_is_recorded(self):
+        tr = tracing.Tracer(max_spans=8)
+        with pytest.raises(ValueError):
+            with tr.phase("failing") as ph:
+                raise ValueError("boom")
+        assert tr.spans(name="failing") == [ph]
+        assert "boom" in ph.attributes["error"] and ph.seconds > 0.0
+        assert tr.current_span() is None
+
+    def test_shows_in_a_profile_under_the_same_name(self, tmp_path):
+        """Inside a ``jax.profiler`` session the phase is a host event
+        of the same name and length, on the profiler's clock."""
+        import glob
+        import jax
+        from jax.profiler import ProfileData
+        tr = tracing.Tracer(max_spans=8)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with tr.phase("phase.probe.outer") as outer:
+                with tr.phase("phase.probe.inner") as inner:
+                    time.sleep(0.005)
+        finally:
+            jax.profiler.stop_trace()
+        path = glob.glob(os.path.join(
+            str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))[-1]
+        seen = {e.name: e for plane in ProfileData.from_file(path).planes
+                for line in plane.lines for e in line.events
+                if e.name.startswith("phase.probe.")}
+        assert set(seen) == {"phase.probe.outer", "phase.probe.inner"}
+        for span in (outer, inner):
+            ev = seen[span.name]
+            # the annotation opens first and closes last
+            assert ev.duration_ns * 1e-9 >= span.seconds
+            assert ev.duration_ns * 1e-9 - span.seconds < 0.002
+        # one offset puts both ring spans on the profiler's clock
+        offs = [seen[s.name].start_ns * 1e-9 - s.start
+                for s in (outer, inner)]
+        assert abs(offs[0] - offs[1]) < 0.001
+
+
+class TestEnginePhases:
+    STEP_PARTS = ["engine.schedule", "engine.decode.dispatch",
+                  "engine.host_sync", "engine.emit"]
+
+    def _run(self, tiny_model, **kw):
+        obs.tracer().reset()
+        engine = create_engine(tiny_model, max_slots=2, page_size=PAGE,
+                               num_pages=64, max_model_len=128,
+                               sync_interval=1, **kw)
+        reqs = [engine.submit(np.array(PROMPT[:n], np.int32),
+                              GenerationConfig(max_new_tokens=m))
+                for n, m in ((19, 6), (7, 4), (12, 5))]
+        engine.run_until_complete(max_steps=200)
+        assert all(r.finish_reason == "length" for r in reqs)
+        return engine, reqs, obs.tracer().spans()
+
+    def test_every_step_holds_its_parts_in_order(self, tiny_model):
+        engine, reqs, spans = self._run(tiny_model)
+        steps = [s for s in spans if s.name == "engine.step"]
+        assert len(steps) == engine.progress
+        assert [s.attributes["step"] for s in steps] == list(
+            range(engine.progress))
+        decoding = [s for s in steps if s.attributes["active"]]
+        assert len(decoding) == engine.decode_steps > 0
+        for step in steps:
+            kids = sorted((s for s in spans if s.parent_id == step.span_id),
+                          key=lambda s: s.start)
+            want = self.STEP_PARTS if step.attributes["active"] \
+                else self.STEP_PARTS[:1]
+            assert [k.name for k in kids] == want
+            assert step.parent_id is None
+            at = step.start
+            for k in kids:                  # in order, inside, disjoint
+                assert at <= k.start <= k.end_time <= step.end_time
+                at = k.end_time
+        names = {s.name for s in spans}
+        assert "engine.decode_segment" not in names
+        emits = [s for s in spans if s.name == "engine.emit"]
+        assert all(s.attributes["rows"] == s.attributes["steps"] == 1
+                   for s in emits)
+        # every token but each request's first comes out of an emit
+        assert sum(s.attributes["tokens"] for s in emits) == sum(
+            r.num_generated - 1 for r in reqs)
+        admitted = [s.attributes["admitted"] for s in spans
+                    if s.name == "engine.schedule"]
+        assert sum(admitted) == len(reqs) and max(admitted) == 2
+        evicted = sum(s.attributes["evicted"] for s in spans
+                      if s.name == "engine.schedule")
+        assert evicted == 0                 # finishes evict inside emit
+
+    def test_prefill_keeps_its_request_and_gains_two_children(
+            self, tiny_model):
+        engine, reqs, spans = self._run(tiny_model)
+        prefills = [s for s in spans if s.name == "engine.prefill"]
+        assert len(prefills) == len(reqs)
+        steps = [s for s in spans if s.name == "engine.step"]
+        for req, pre in zip(reqs, prefills):
+            assert pre.parent_id == req.root_span.span_id
+            assert pre.attributes["kind"] == "full"
+            assert pre.attributes["req"] == req.id
+            kids = sorted((s for s in spans if s.parent_id == pre.span_id),
+                          key=lambda s: s.start)
+            assert [k.name for k in kids] == ["engine.prefill.dispatch",
+                                              "engine.prefill.fetch"]
+            assert pre.start <= kids[0].start
+            assert kids[-1].end_time <= pre.end_time
+            # by time it lies inside one engine.step, after its schedule
+            assert sum(st.start <= pre.start and pre.end_time <= st.end_time
+                       for st in steps) == 1
+
+    def test_timings_are_the_spans_intervals(self, tiny_model):
+        engine, reqs, spans = self._run(tiny_model)
+
+        def total(name):
+            return sum(s.seconds for s in spans if s.name == name)
+
+        t = engine.timings
+        assert set(t) == {"schedule_s", "prefill_s", "decode_s",
+                          "host_sync_s", "emit_s", "queue_wait_s"}
+        for key, name in (("schedule_s", "engine.schedule"),
+                          ("prefill_s", "engine.prefill"),
+                          ("decode_s", "engine.decode.dispatch"),
+                          ("host_sync_s", "engine.host_sync"),
+                          ("emit_s", "engine.emit")):
+            assert t[key] == pytest.approx(total(name), rel=1e-9), key
+        parts = sum(t[k] for k in ("schedule_s", "prefill_s", "decode_s",
+                                   "host_sync_s", "emit_s"))
+        whole = total("engine.step")
+        assert 0.9 * whole <= parts <= whole
+        assert t["queue_wait_s"] == pytest.approx(
+            sum(r.queue_seconds for r in reqs))
+        # the third request waited for a slot, the first two did not
+        assert reqs[2].queue_seconds > max(reqs[0].queue_seconds,
+                                           reqs[1].queue_seconds)
+
+    def test_sampling_is_a_child_of_emit(self, tiny_model):
+        obs.tracer().reset()
+        engine = create_engine(tiny_model, max_slots=2, page_size=PAGE,
+                               num_pages=64, max_model_len=128,
+                               emit_logits=True)
+        req = engine.submit(np.array(PROMPT, np.int32),
+                            GenerationConfig(max_new_tokens=4,
+                                             do_sample=True, top_k=8))
+        engine.run_until_complete(max_steps=50)
+        assert req.finish_reason == "length"
+        spans = obs.tracer().spans()
+        samples = [s for s in spans if s.name == "engine.sample"]
+        emits = {s.span_id: s for s in spans if s.name == "engine.emit"}
+        assert len(samples) == 3            # one a decode step
+        for s in samples:
+            emit = emits[s.parent_id]
+            assert emit.start <= s.start and s.end_time <= emit.end_time
+            assert "corrections" in s.attributes
+
+
+class TestDeviceNames:
+    """What a profiler's device rows are told apart by: the jitted
+    programs' names, the scopes inside them, the kernels' ``name=``."""
+    PALLAS = os.path.join(REPO, "paddle_tpu", "ops", "pallas")
+    SCOPES = ("embed", "attn.qkv", "kv.write", "attn.decode", "attn.out",
+              "mlp", "head")
+
+    @staticmethod
+    def _kernel_names(path):
+        import ast
+        names = []
+        for node in ast.walk(ast.parse(open(path).read())):
+            if (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and node.func.attr == "pallas_call"):
+                kw = {k.arg: k.value for k in node.keywords}
+                assert "name" in kw, f"{path}:{node.lineno} has no name="
+                assert isinstance(kw["name"], ast.Constant)
+                names.append(kw["name"].value)
+        return names
+
+    @pytest.mark.parametrize("module, count", [
+        ("decode_attention", 1), ("flash_attention", 6),
+        ("flash_mask", 8), ("grouped_ffn", 2), ("lora_matmul", 1),
+        ("paged_attention", 1), ("quant_matmul", 2), ("rms_norm", 1)])
+    def test_every_pallas_call_has_a_fixed_name(self, module, count):
+        names = self._kernel_names(
+            os.path.join(self.PALLAS, module + ".py"))
+        assert len(names) == count == len(set(names))
+        assert all(re.fullmatch(r"[a-z][a-z0-9_]*", n) for n in names)
+
+    def test_kernel_names_are_unique_across_files(self):
+        names = [n for f in sorted(os.listdir(self.PALLAS))
+                 if f.endswith(".py")
+                 for n in self._kernel_names(os.path.join(self.PALLAS, f))]
+        assert len(names) == 22 == len(set(names))
+        assert {"paged_attention", "flash_fwd", "flash_bwd_dq",
+                "flash_bwd_dkv", "rms_norm", "decode_attention"} <= set(
+            names)
+
+    def test_programs_and_scopes_carry_their_names(self, tiny_model):
+        engine = create_engine(tiny_model, max_slots=2, page_size=PAGE,
+                               num_pages=64, max_model_len=128,
+                               enable_prefix_cache=True)
+        r = engine.runner
+        assert r._step_fn.__name__ == "decode_step"
+        assert r._copy_page_fn.__name__ == "copy_page"
+        assert r._prefill_fn(PAGE).__name__ == "prefill"
+        assert r._prefill_cached_fn(PAGE).__name__ == "prefill_cached"
+        text = r._step_fn.lower(
+            r.state, r.kpool, r.vpool, r.kscale, r.vscale, r._table_dev,
+            r._pos_dev, r._tok_dev, r._active_dev, r._ring_dev,
+            r._ridx_dev, r._cos, r._sin, r.lora,
+            r._aidx_dev).as_text(debug_info=True)
+        assert "jit_decode_step" in text
+        for scope in self.SCOPES:
+            assert f"jit(decode_step)/{scope}/" in text, scope
+
+    def test_verify_program_carries_its_name(self, tiny_model):
+        engine = create_engine(tiny_model, max_slots=2, page_size=PAGE,
+                               num_pages=64, max_model_len=128, spec_k=2)
+        assert engine.runner._verify_fn.__name__ == "verify_step"
+
+
 # ------------------------------------------------------- flight recorder
 class TestFlightRecorder:
     def test_ring_bound_and_order(self):
@@ -693,8 +950,8 @@ class TestServeBenchTrace:
         assert res["requests"] == 3
         doc = json.loads(out.read_text())
         names = {e.get("name") for e in doc["traceEvents"]}
-        assert {"request", "engine.prefill",
-                "engine.decode_segment"} <= names
+        assert {"request", "engine.prefill", "engine.decode.dispatch",
+                "engine.host_sync", "engine.emit"} <= names
 
     def test_per_replica_latency_grouping(self):
         mod = _load_tool("serve_bench")
