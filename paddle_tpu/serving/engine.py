@@ -62,6 +62,7 @@ from ..flags import FLAGS
 from ..observability.resources import resource_tracker
 from ..models.generation import GenerationConfig
 from ..models.llama import LlamaConfig
+from ..ops.pallas.paged_attention import pages_per_block
 from .block_manager import BlockManager
 from .faults import InjectedFault, fault_plan_from_flags
 from .parallel import ModelRunner, parse_mesh
@@ -198,6 +199,11 @@ class Engine:
                 f"max_model_len {self.max_model_len} exceeds the model's "
                 f"max_position_embeddings {config.max_position_embeddings}")
         self.table_width = -(-self.max_model_len // self.page_size)
+        # the paged decode kernel's unit of work, by its own rule: the
+        # tokens one grid step covers and the steps a slot's row makes
+        blk = pages_per_block(self.page_size, self.table_width)
+        self._block_tokens = blk * self.page_size
+        self._blocks_per_row = -(-self.table_width // blk)
         if num_pages is None:       # full residency: every slot can run
             num_pages = self.max_slots * self.table_width  # at max length
         self.emit_logits = bool(emit_logits)
@@ -338,6 +344,10 @@ class Engine:
         self._last_logits = None        # device handle, fetched lazily
 
         self.decode_steps = 0       # mirror of serving_decode_steps_total
+        # grid steps of the paged decode kernel that held visible tokens,
+        # and all of them, summed over decode steps (_count_paged_blocks)
+        self.paged_blocks_live = 0
+        self.paged_blocks_grid = 0
         self.host_syncs = 0         # ring fetches (1 per sync_interval)
         self.logit_fetches = 0      # [slots, V] transfers (sampling only)
         # chunked prefill: in-flight admission prefills advanced one
@@ -1050,8 +1060,10 @@ class Engine:
         if drafts:
             self._decode_spec(reqs, drafts)
             return
+        live, grid = self._count_paged_blocks(active)
         with self._phase("engine.decode.dispatch", "decode",
-                         slots=len(active)):
+                         slots=len(active), paged_blocks_live=live,
+                         paged_blocks_grid=grid):
             logits = self.runner.decode_step()
         self.decode_steps += 1
         self._prefill_since_decode = 0      # gap witness: decode ran
@@ -1068,6 +1080,26 @@ class Engine:
             else self.sync_interval
         if len(self._pending) >= eff:
             self._sync()
+
+    def _count_paged_blocks(self, active: list[int],
+                            rows: int = 1) -> tuple[int, int]:
+        """How much of the paged decode kernel's grid this step's active
+        slots put to work: (grid steps whose block of pages holds
+        visible tokens, all their grid steps), from the host's position
+        mirror — no device fetch.  A verify step sends ``rows``
+        candidate rows a slot, each one token longer.  Nothing is
+        counted under ``kv_quant``: that decode gathers densely and
+        never reaches the kernel."""
+        if self.kv_quant:
+            return 0, 0
+        bt = self._block_tokens
+        most = self.table_width * self.page_size
+        live = sum(-(-min(int(self._pos[s]) + j + 1, most) // bt)
+                   for s in active for j in range(rows))
+        grid = len(active) * rows * self._blocks_per_row
+        self.paged_blocks_live += live
+        self.paged_blocks_grid += grid
+        return live, grid
 
     def _propose(self, reqs) -> dict:
         """Collect this step's drafts: ``{slot: [tokens]}``.  Empty —
@@ -1104,8 +1136,11 @@ class Engine:
         for slot, ds in drafts.items():
             draft_arr[slot, :len(ds)] = ds
             dlen[slot] = len(ds)
+        live, grid = self._count_paged_blocks([s for s, _ in reqs],
+                                              rows=self.spec_k + 1)
         with self._phase("engine.decode.dispatch", "decode",
-                         slots=len(reqs), verify=True):
+                         slots=len(reqs), verify=True,
+                         paged_blocks_live=live, paged_blocks_grid=grid):
             self.runner.verify_step(draft_arr, dlen)
         self.decode_steps += 1
         self._prefill_since_decode = 0      # gap witness: decode ran
@@ -1571,6 +1606,8 @@ class Engine:
             "host_syncs": self.host_syncs,
             "logit_fetches": self.logit_fetches,
             "decode_steps": self.decode_steps,
+            "paged_blocks_live": self.paged_blocks_live,
+            "paged_blocks_grid": self.paged_blocks_grid,
             "pages_allocated": b.pages_allocated,
             "prefill_chunk": self.prefill_chunk,
             "prefill_chunks": self.prefill_chunks,

@@ -13,6 +13,7 @@ that cannot load it.  Nothing runs; a compile that passes is not a chip
 run.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -64,21 +65,39 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
+def compiled_text(fn, *args) -> str:
+    """Compile ``fn`` for the described chip; the compiled program's
+    text."""
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
 def compile_kernels(fn, *args) -> int:
-    """Compile ``fn`` for the described chip; the number of Pallas
-    kernels in the compiled program."""
-    text = jax.jit(fn).lower(*args).compile().as_text()
-    return text.count('custom_call_target="tpu_custom_call"')
+    """The number of Pallas kernels in ``fn`` compiled for the described
+    chip."""
+    return compiled_text(fn, *args).count(
+        'custom_call_target="tpu_custom_call"')
 
 
-def test_paged_attention(sds):
-    # the serving decode step: 8 slots, page 16, 128 pages per sequence
-    slots, ps, width = 8, 16, 128
-    pool = sds((slots * width + 1, KVH, ps, HD))
-    n = compile_kernels(PA.paged_attention, sds((slots, NH, HD)), pool,
-                        pool, sds((slots, width), jnp.int32),
-                        sds((slots,), jnp.int32))
-    assert n == 1
+@pytest.mark.parametrize("slots,kvh,ps,width", [
+    (8, KVH, 16, 128),      # a decode step of 8 slots, 2048 tokens each
+    (32, KVH, 16, 64),      # the serving cell: 32 slots, 1024 tokens
+    (32, 2, 16, 64),        # the same inside tp=4: a shard's 2 KV heads
+    (4, KVH, 128, 5),       # the one-shot generate's pool: page 128
+], ids=["slots8", "cell", "tp-local", "one-shot"])
+def test_paged_attention(sds, slots, kvh, ps, width):
+    pool = sds((slots * width + 1, kvh, ps, HD))
+    text = compiled_text(
+        PA.paged_attention, sds((slots, 4 * kvh, HD)), pool, pool,
+        sds((slots, width), jnp.int32), sds((slots,), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    # the benchmark's roofline finds the kernel's events by the table
+    # [slots, width] and the lengths [slots] as the call's first two
+    # operands, and its breakdown names them by the stem
+    assert re.search(
+        r'%paged_attention[.\d]* = \S+ custom-call\(.*'
+        r'custom_call_target="tpu_custom_call", '
+        rf'operand_layout_constraints=\{{s32\[{slots},{width}\]\S* '
+        rf's32\[{slots}\]', text), text[-3000:]
 
 
 def test_pallas_decode(sds):

@@ -57,6 +57,80 @@ def test_paged_kernel_matches_gather_reference():
         PA._INTERPRET = saved
 
 
+# geometry: page size, KV heads, pages of the table beyond two whole
+# blocks; q heads = 4 x KV heads
+_GEOMETRY = {
+    "page16": (16, 8, 3),           # the last block holds 3 pages
+    "page16-tp-local": (16, 2, 3),      # the pool a tp=4 shard holds
+    "page16-whole-blocks": (16, 8, 0),
+    "page128": (128, 8, 1),         # the one-shot generate's pool
+}
+
+
+@pytest.mark.parametrize("order", ["rising", "falling", "empty-first"])
+@pytest.mark.parametrize("geometry", list(_GEOMETRY))
+def test_paged_kernel_blocks_match_reference(geometry, order):
+    """The block-of-pages kernel against the dense gather, under the TPU
+    interpreter with uninitialised scratch reading NaN: contexts of 1, a
+    block exactly, a block + 1, the whole table and nothing at all;
+    pages scattered over the pool; NaN in the dump page and in every
+    page no row names.  A stale buffer tail, a copy past the context or
+    a read past the table's row would show as NaN or as a difference."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    ps, kvh, beyond = _GEOMETRY[geometry]
+    rep, d = 4, 128
+    blk = PA.pages_per_block(ps, 1 << 20)
+    width, block = 2 * blk + beyond, blk * ps
+    assert PA.pages_per_block(ps, width) == blk
+    lens = [1, block, block + 1, width * ps, 0]
+    if order == "falling":
+        lens = lens[::-1]
+    elif order == "empty-first":
+        lens = [0, 0] + lens[:4]
+    rng = np.random.RandomState(len(geometry) + len(order))
+    b = len(lens)
+    need = [-(-n // ps) for n in lens]
+    n_pages = sum(need) + 4
+    dump = n_pages - 1
+    ids = rng.permutation(n_pages - 1)[:sum(need)]
+    table = np.full((b, width), dump, np.int32)
+    at = 0
+    for i, n in enumerate(need):
+        table[i, :n] = ids[at:at + n]
+        at += n
+    kpool = rng.randn(n_pages, kvh, ps, d).astype(np.float32)
+    vpool = rng.randn(n_pages, kvh, ps, d).astype(np.float32)
+    unnamed = np.setdiff1d(np.arange(n_pages), ids)
+    assert dump in unnamed and len(unnamed) == 4
+    kpool[unnamed] = np.nan
+    vpool[unnamed] = np.nan
+    q = jnp.asarray(rng.randn(b, kvh * rep, d).astype(np.float32))
+    args = (q, jnp.asarray(kpool), jnp.asarray(vpool), jnp.asarray(table),
+            jnp.asarray(np.array(lens, np.int32)))
+    PA._INTERPRET, saved = pltpu.InterpretParams(
+        uninitialized_memory="nan"), PA._INTERPRET
+    try:
+        with jax.default_matmul_precision("highest"):
+            out_k = np.asarray(PA.paged_attention(*args))
+    finally:
+        PA._INTERPRET = saved
+    # the reference gathers every column, dump page and all: give it
+    # zeros where the kernel must not have looked
+    kpool[unnamed] = 0.0
+    vpool[unnamed] = 0.0
+    with jax.default_matmul_precision("highest"):
+        out_x = np.asarray(PA.paged_attention_xla(
+            q, jnp.asarray(kpool), jnp.asarray(vpool), *args[3:]))
+    assert np.isfinite(out_k).all()
+    for i, n in enumerate(lens):
+        if n == 0:
+            assert not out_k[i].any()       # nothing visible: zeros
+        else:
+            np.testing.assert_allclose(out_k[i], out_x[i], atol=1e-4,
+                                       rtol=1e-4)
+
+
 def _tiny_model():
     from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
     cfg = LlamaConfig(vocab_size=256, hidden_size=64,

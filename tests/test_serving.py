@@ -541,6 +541,50 @@ def test_engine_sync_interval_host_syncs_and_logits_skip(tiny_model):
     assert eng.decode_traces == 1
 
 
+def test_engine_paged_block_counters(tiny_model, monkeypatch):
+    """``paged_blocks_live / paged_blocks_grid``: the share of the paged
+    decode kernel's grid steps that hold visible tokens, from the
+    lengths the host holds; worked by hand for three requests.  The
+    counters only grow, so differences of ``stats()`` add up."""
+    from paddle_tpu.ops.pallas import paged_attention as PA
+    obs.tracer().reset()
+    # blocks of 32 tokens = 4 pages; a row of 12 pages makes 3 grid steps
+    monkeypatch.setattr(PA, "BLOCK_TOKENS", 32)
+    eng = create_engine(tiny_model, max_slots=2, page_size=8,
+                        max_model_len=96)
+    keys = ("paged_blocks_live", "paged_blocks_grid")
+
+    def counted():
+        st = eng.stats()
+        return np.array([st[k] for k in keys])
+
+    def serve(*jobs):
+        reqs = [eng.submit(np.arange(1, n + 1).astype(np.int32),
+                           GenerationConfig(max_new_tokens=m))
+                for n, m in jobs]
+        eng.run_until_complete(max_steps=200)
+        assert all(r.state == RequestState.DONE for r in reqs)
+
+    s0 = counted()
+    assert s0.tolist() == [0, 0]
+    # prompt 30, 5 tokens: the first from the prefill, then 4 decode
+    # steps that see 31, 32, 33, 34 tokens = 1 + 1 + 2 + 2 blocks of 12;
+    # prompt 63, 4 tokens: 3 steps over 64, 65, 66 = 2 + 3 + 3 of 9
+    serve((30, 5), (63, 4))
+    s1 = counted()
+    assert (s1 - s0).tolist() == [14, 21]
+    # prompt 10, 3 tokens: 2 steps over 11, 12 tokens = 1 + 1 of 6
+    serve((10, 3))
+    s2 = counted()
+    assert (s2 - s1).tolist() == [2, 6]
+    assert (s2 - s0).tolist() == [16, 27]
+    # the same numbers ride the dispatch spans, step by step
+    spans = [s for s in obs.tracer().spans()
+             if s.name == "engine.decode.dispatch"]
+    assert len(spans) == eng.decode_steps
+    assert [sum(s.attributes[k] for s in spans) for k in keys] == [16, 27]
+
+
 def test_engine_prefix_cache_staggered_no_retrace(tiny_model):
     """Admissions/evictions with caching enabled (shared-prefix
     workload, staggered arrivals, deferred sync) never retrace the
