@@ -148,7 +148,7 @@ def runner_kernels(runner) -> dict:
     out = {"decode_step": kernels_in(r._step_fn, (
         r.state, *pools, r._table_dev, r._pos_dev, r._tok_dev,
         r._active_dev, r._ring_dev, r._ridx_dev, r._cos, r._sin, r.lora,
-        r._aidx_dev))}
+        r._aidx_dev, r._counters_dev))}
     for bucket, fn in sorted(r._prefill_fns.items()):
         out[f"prefill[{bucket}]"] = kernels_in(fn, (
             r.state, jnp.zeros((1, bucket), i32), jnp.zeros((1,), i32),

@@ -144,7 +144,7 @@ def kernel_route(q, k, v, dropout_p=0.0) -> bool:
     return (
         dropout_p == 0.0
         and q.dtype == k.dtype == v.dtype   # kernels matmul in input dtype
-        and q.shape[-1] in (64, 128, 256)
+        and q.shape[-1] in (64, 128, 192, 256)
         and q.shape[1] >= 128 and k.shape[1] >= 128
         and jax.default_backend() not in ("cpu",))
 

@@ -737,9 +737,9 @@ class BlockManager:
                 "cached_pages": self.cached_pages,
                 "cached_tokens": self.cached_tokens}
 
-    def pool_bytes(self, *, num_layers: int, num_kv_heads: int,
-                   head_dim: int, dtype_itemsize: int, tp: int = 1,
-                   kv_quant: bool = False) -> dict:
+    def pool_bytes(self, *, num_layers: int, num_kv_heads: int = 1,
+                   head_dim: int = 0, dtype_itemsize: int, tp: int = 1,
+                   kv_quant: bool = False, latent_width: int = 0) -> dict:
         """KV pool sizing for the engine's pool arrays, head-sharded
         over a tp-way mesh.  The pool the runner builds is
         ``2 * [L, num_pages+1, kvh, page_size, hd]`` (k + v, one extra
@@ -748,12 +748,19 @@ class BlockManager:
         whole accounting) stays host-side and mesh-agnostic — the same
         page ids address every shard.  ``kv_quant`` sizes the int8 page
         mode: 1-byte KV elements plus the two f32 scale pools
-        (``2 * [L, rows, kvh, page_size]``)."""
+        (``2 * [L, rows, kvh, page_size]``).  ``latent_width`` sizes a
+        latent cache instead: ONE pool ``[L, rows, page_size, width]``,
+        a row a token a layer, no heads."""
         if tp < 1 or num_kv_heads % tp:
             raise ValueError(
                 f"tp={tp} must be >= 1 and divide num_kv_heads="
                 f"{num_kv_heads} (the pool shards along the head axis)")
         rows = self.num_pages + 1           # + dump page
+        if latent_width:
+            total = (num_layers * rows * self.page_size * latent_width
+                     * dtype_itemsize)
+            return {"total_bytes": total, "per_device_bytes": total,
+                    "rows": rows, "tp": 1, "kv_quant": False}
         elems = (2 * num_layers * rows * num_kv_heads * self.page_size
                  * head_dim)
         if kv_quant:

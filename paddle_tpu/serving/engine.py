@@ -66,6 +66,7 @@ from ..ops.pallas.paged_attention import pages_per_block
 from .block_manager import BlockManager
 from .faults import InjectedFault, fault_plan_from_flags
 from .parallel import ModelRunner, parse_mesh
+from .parallel import latent as _latent
 from .request import Request, RequestState
 from .scheduler import Scheduler
 
@@ -171,6 +172,11 @@ class Engine:
         if quant not in ("", "int8", "int4"):
             raise ValueError(
                 f"quant must be '', 'int8', or 'int4', got {quant!r}")
+        # the model description names its family; what a family does not
+        # have is refused here, by name, before anything is built
+        self.latent = _latent.is_latent(config)
+        if self.latent:     # the runner refuses tp, kv_quant and spec_k
+            _latent.check_options(quant=bool(quant), lora=lora is not None)
         self.quant = quant
         self.kv_quant = bool(kv_quant)
         if self.quant:
@@ -294,15 +300,23 @@ class Engine:
             _obs.set_active_requestlog(requestlog)
 
         L = config.num_hidden_layers
-        kvh, hd = config.num_key_value_heads, config.head_dim
-        dtype = state["llama.embed_tokens.weight"].dtype
-        self._embed_itemsize = int(np.dtype(dtype).itemsize)
-        # head-sharded pool sizing: the BlockManager knows how many
-        # bytes each mesh position holds, the runner reports it
-        sizing = self.blocks.pool_bytes(
-            num_layers=L, num_kv_heads=kvh, head_dim=hd,
-            dtype_itemsize=int(np.dtype(dtype).itemsize), tp=self.tp,
-            kv_quant=self.kv_quant)
+        if self.latent:
+            dtype = state[_latent.EMBED].dtype
+            self._embed_itemsize = int(np.dtype(dtype).itemsize)
+            # one latent row a token a layer, no heads to shard
+            sizing = self.blocks.pool_bytes(
+                num_layers=L, dtype_itemsize=self._embed_itemsize,
+                latent_width=_latent.pool_shape(config, 0, 1)[-1])
+        else:
+            kvh, hd = config.num_key_value_heads, config.head_dim
+            dtype = state["llama.embed_tokens.weight"].dtype
+            self._embed_itemsize = int(np.dtype(dtype).itemsize)
+            # head-sharded pool sizing: the BlockManager knows how many
+            # bytes each mesh position holds, the runner reports it
+            sizing = self.blocks.pool_bytes(
+                num_layers=L, num_kv_heads=kvh, head_dim=hd,
+                dtype_itemsize=self._embed_itemsize, tp=self.tp,
+                kv_quant=self.kv_quant)
         # the device half: mesh, weight placement, pools, decode state,
         # and every jitted program live behind the runner seam.  The
         # kwargs are kept so recover() can rebuild an identical runner
@@ -348,6 +362,9 @@ class Engine:
         # and all of them, summed over decode steps (_count_paged_blocks)
         self.paged_blocks_live = 0
         self.paged_blocks_grid = 0
+        # the device's expert counters as of the last stats() (the decode
+        # span carries them; a step never fetches them)
+        self._moe_seen: dict = {}
         self.host_syncs = 0         # ring fetches (1 per sync_interval)
         self.logit_fetches = 0      # [slots, V] transfers (sampling only)
         # chunked prefill: in-flight admission prefills advanced one
@@ -1063,7 +1080,7 @@ class Engine:
         live, grid = self._count_paged_blocks(active)
         with self._phase("engine.decode.dispatch", "decode",
                          slots=len(active), paged_blocks_live=live,
-                         paged_blocks_grid=grid):
+                         paged_blocks_grid=grid, **self._moe_seen):
             logits = self.runner.decode_step()
         self.decode_steps += 1
         self._prefill_since_decode = 0      # gap witness: decode ran
@@ -1608,6 +1625,7 @@ class Engine:
             "decode_steps": self.decode_steps,
             "paged_blocks_live": self.paged_blocks_live,
             "paged_blocks_grid": self.paged_blocks_grid,
+            **self._moe_counters(),
             "pages_allocated": b.pages_allocated,
             "prefill_chunk": self.prefill_chunk,
             "prefill_chunks": self.prefill_chunks,
@@ -1633,6 +1651,13 @@ class Engine:
                                 if self.faults is not None else {}),
         }
 
+    def _moe_counters(self) -> dict:
+        """The expert layers' counters, fetched from the device now (a
+        family without experts has none).  The decode step's span shows
+        what the last call here read."""
+        self._moe_seen = self.runner.moe_counters()
+        return self._moe_seen
+
     def _page_bytes(self, *, dense: bool = False) -> int:
         """Bytes one KV page pair (k + v, full heads) occupies — the
         unit every spill/restore moves.  Under ``kv_quant`` that is the
@@ -1640,6 +1665,9 @@ class Engine:
         ``dense=True`` prices the same page at the checkpoint dtype
         (the savings baseline)."""
         cfg = self.config
+        if self.latent:
+            return int(np.prod(self.runner.kpool.shape[2:])
+                       * cfg.num_hidden_layers * self._embed_itemsize)
         rows = (cfg.num_hidden_layers * cfg.num_key_value_heads
                 * self.page_size)
         elems = rows * cfg.head_dim

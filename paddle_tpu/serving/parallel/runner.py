@@ -16,6 +16,10 @@ Two construction modes, selected by ``tp``:
     seam existed — no mesh, no ``shard_map``, no ``device_put`` — so
     ``mesh_shape=(1,)`` reduces bit-for-bit to the previous behavior.
 
+A model description whose ``family`` is ``"deepseek_v3"`` keeps the
+seam and changes the cache: one pool of latent rows, and the programs of
+``latent.py`` (one chip only; the value pool is then the empty tuple).
+
 ``tp > 1``
     A 1-axis ``jax.sharding.Mesh`` over the first ``tp`` devices.
     q/k/v/gate/up are column-sharded and o/down row-sharded with
@@ -50,6 +54,7 @@ from ...models.llama_hybrid import _rms
 from ...ops.pallas.paged_attention import (gather_kv_pages,
                                            quantize_kv_rows)
 from ...ops.pallas.quant_matmul import QuantizedWeight
+from . import latent
 from .layers import (decode_layer_paged_quant, decode_layer_paged_tp,
                      prefill_layer_cached_quant, prefill_layer_cached_tp,
                      prefill_layer_tp)
@@ -135,23 +140,39 @@ class ModelRunner:
             raise ValueError(
                 f"lora_slots={self.lora_slots} requires lora_rank >= 1,"
                 f" got {self.lora_rank}")
-        validate_tp(config, self.tp)
+        # the cache the model description asks for: K and V pages per
+        # head, or (latent.py) one pool of latent rows and no V pool
+        self.latent = latent.is_latent(config)
+        if self.latent:
+            latent.check_options(tp=self.tp > 1, kv_quant=self.kv_quant,
+                                 lora_slots=self.lora_slots > 0,
+                                 spec_k=self.spec_k > 0)
+        else:
+            validate_tp(config, self.tp)
         self._validate_quantized_state(state)
 
         L = config.num_hidden_layers
-        kvh, hd = config.num_key_value_heads, config.head_dim
-        dtype = state["llama.embed_tokens.weight"].dtype
         pool_rows = self.num_pages + 1               # + dump page
-        pool_shape = (L, pool_rows, kvh, self.page_size, hd)
+        self._rope_len = self.table_width * self.page_size
+        if self.latent:
+            from ...models.deepseek_v3 import rope_tables
+            dtype = state[latent.EMBED].dtype
+            pool_shape = latent.pool_shape(config, self.num_pages,
+                                           self.page_size)
+            scale_shape = ()
+            cos, sin = rope_tables(config, self._rope_len)
+        else:
+            kvh, hd = config.num_key_value_heads, config.head_dim
+            dtype = state["llama.embed_tokens.weight"].dtype
+            pool_shape = (L, pool_rows, kvh, self.page_size, hd)
+            scale_shape = (L, pool_rows, kvh, self.page_size)
+            cos, sin = _rope_tables(self._rope_len, hd, config.rope_theta)
         # int8 KV page mode: pools store int8, one f32 scale per
         # (layer, page row, head, slot) rides in separate scale pools.
         # Dense mode keeps EXACTLY the old arrays — the scale members
         # become empty tuples, which contribute zero pytree leaves to
         # every jitted signature, so the dense jaxprs are unchanged.
         pool_dtype = jnp.int8 if self.kv_quant else dtype
-        scale_shape = (L, pool_rows, kvh, self.page_size)
-        self._rope_len = self.table_width * self.page_size
-        cos, sin = _rope_tables(self._rope_len, hd, config.rope_theta)
         cos = cos.astype(jnp.float32)
         sin = sin.astype(jnp.float32)
         table0 = np.full((self.max_slots, self.table_width),
@@ -169,7 +190,8 @@ class ModelRunner:
             self.devices = list(jax.devices()[:1]) if jax.devices() else []
             self.state = state
             self.kpool = jnp.zeros(pool_shape, pool_dtype)
-            self.vpool = jnp.zeros(pool_shape, pool_dtype)
+            self.vpool = (() if self.latent
+                          else jnp.zeros(pool_shape, pool_dtype))
             if self.kv_quant:
                 self.kscale = jnp.zeros(scale_shape, jnp.float32)
                 self.vscale = jnp.zeros(scale_shape, jnp.float32)
@@ -187,6 +209,10 @@ class ModelRunner:
             self._active_dev = jnp.zeros((self.max_slots,), jnp.int32)
             self._ring_dev = jnp.zeros(ring_shape, jnp.int32)
             self._ridx_dev = jnp.zeros((), jnp.int32)
+            # the expert layers' counters, kept on the device by the
+            # decode step (no leaves where the family has no experts)
+            self._counters_dev = (latent.counters0() if self.latent
+                                  else ())
         else:
             from jax.sharding import Mesh, NamedSharding, PartitionSpec
             self._check_state_shardable(state)
@@ -239,6 +265,7 @@ class ModelRunner:
                 jnp.zeros(ring_shape, jnp.int32), rep)
             self._ridx_dev = jax.device_put(
                 jnp.zeros((), jnp.int32), rep)
+            self._counters_dev = ()
 
         self.decode_traces = 0      # python mirror of _M_STEP_TRACES
         self.verify_traces = 0      # python mirror of _M_VERIFY_TRACES
@@ -254,7 +281,8 @@ class ModelRunner:
         # the resource snapshot (CPU devices export no memory_stats, so
         # /debug/resources reports these alongside whatever stats exist)
         itemsize = jnp.dtype(pool_dtype).itemsize
-        pool_total = 2 * int(np.prod(pool_shape)) * itemsize
+        pool_total = ((1 if self.latent else 2)
+                      * int(np.prod(pool_shape)) * itemsize)
         if self.kv_quant:           # + the f32 scale pools
             pool_total += 2 * int(np.prod(scale_shape)) * 4
         self._pool_bytes_per_device = (
@@ -492,7 +520,7 @@ class ModelRunner:
     def _make_step_fn(self):
         if self.tp == 1:
             return jax.jit(self._build_step(),
-                           donate_argnums=(1, 2, 3, 4, 6, 7, 9, 10))
+                           donate_argnums=(1, 2, 3, 4, 6, 7, 9, 10, 15))
         from jax.sharding import PartitionSpec as P
         pool = self._pool_pspec
         sspec = self._scale_pspec if self.kv_quant else P()
@@ -500,13 +528,20 @@ class ModelRunner:
             self._build_step_tp(), mesh=self.mesh,
             in_specs=(self._state_specs(), pool, pool, sspec, sspec,
                       P(), P(), P(), P(), P(), P(), P(), P(),
-                      self._lora_pspecs(), P()),
+                      self._lora_pspecs(), P(), P()),
             out_specs=(pool, pool, sspec, sspec, P(), P(), P(), P(),
-                       P()),
+                       P(), P()),
             check_vma=False)
         return jax.jit(mapped, donate_argnums=(1, 2, 3, 4, 6, 7, 9, 10))
 
+    def _count_step_trace(self):
+        """Runs when a decode step is traced, never when it runs."""
+        self.decode_traces += 1
+        _M_STEP_TRACES.inc()
+
     def _build_step(self):
+        if self.latent:
+            return latent.build_step(self, self._count_step_trace)
         cfg = self.config
         L = cfg.num_hidden_layers
         emit_logits = self.emit_logits
@@ -516,7 +551,8 @@ class ModelRunner:
         runner = self
 
         def decode_step(state, kpool, vpool, kscale, vscale, table, pos,
-                        tok, active, ring, ridx, cos, sin, lora, aidx):
+                        tok, active, ring, ridx, cos, sin, lora, aidx,
+                        counters):
             # python body runs at trace time only: a second execution of
             # this line means an admission/eviction re-traced the step
             runner.decode_traces += 1
@@ -566,7 +602,7 @@ class ModelRunner:
                 ridx2 = (ridx + 1) % ring.shape[0]
             return (kpool, vpool, kscale, vscale, pos2, tok2, ring2,
                     ridx2, logits if emit_logits
-                    else jnp.zeros((), jnp.float32))
+                    else jnp.zeros((), jnp.float32), counters)
 
         return decode_step
 
@@ -584,7 +620,8 @@ class ModelRunner:
         runner = self
 
         def decode_step(state, kpool, vpool, kscale, vscale, table, pos,
-                        tok, active, ring, ridx, cos, sin, lora, aidx):
+                        tok, active, ring, ridx, cos, sin, lora, aidx,
+                        counters):
             runner.decode_traces += 1
             _M_STEP_TRACES.inc()
             with jax.named_scope("embed"):
@@ -628,7 +665,7 @@ class ModelRunner:
                 ridx2 = (ridx + 1) % ring.shape[0]
             return (kpool, vpool, kscale, vscale, pos2, tok2, ring2,
                     ridx2, logits if emit_logits
-                    else jnp.zeros((), jnp.float32))
+                    else jnp.zeros((), jnp.float32), counters)
 
         return decode_step
 
@@ -763,12 +800,10 @@ class ModelRunner:
         kv_quant = self.kv_quant
 
         def copy_page(kp, vp, ks, vs, src, dst):
-            kp2 = kp.at[:, dst].set(kp[:, src])
-            vp2 = vp.at[:, dst].set(vp[:, src])
-            if kv_quant:        # scale rows travel with their page
-                ks = ks.at[:, dst].set(ks[:, src])
-                vs = vs.at[:, dst].set(vs[:, src])
-            return kp2, vp2, ks, vs
+            # every pool there is (scale rows travel with their page; a
+            # latent cache has the one pool): an absent one has no leaves
+            return jax.tree.map(lambda p: p.at[:, dst].set(p[:, src]),
+                                (kp, vp, ks, vs))
 
         if self.tp == 1:
             # CoW page copy: src/dst are data — one trace for the engine
@@ -787,6 +822,12 @@ class ModelRunner:
     def _prefill_fn(self, bucket: int):
         fn = self._prefill_fns.get(bucket)
         if fn is not None:
+            return fn
+        if self.latent:
+            fn = jax.jit(latent.build_prefill(
+                self, bucket, _M_PREFILL_TRACES.labels(str(bucket)).inc),
+                donate_argnums=(4, 5, 6, 7))
+            self._prefill_fns[bucket] = fn
             return fn
         cfg = self.config
         L = cfg.num_hidden_layers
@@ -865,6 +906,13 @@ class ModelRunner:
         length, table row, and positions are all data."""
         fn = self._prefill_cached_fns.get(bucket)
         if fn is not None:
+            return fn
+        if self.latent:
+            fn = jax.jit(latent.build_prefill_cached(
+                self, bucket,
+                _M_PREFILL_TRACES.labels(f"cached:{bucket}").inc),
+                donate_argnums=(5, 6, 7, 8))
+            self._prefill_cached_fns[bucket] = fn
             return fn
         cfg = self.config
         L = cfg.num_hidden_layers
@@ -967,11 +1015,12 @@ class ModelRunner:
         t0 = time.perf_counter()
         (self.kpool, self.vpool, self.kscale, self.vscale,
          self._pos_dev, self._tok_dev, self._ring_dev, self._ridx_dev,
-         logits) = self._step_fn(
+         logits, self._counters_dev) = self._step_fn(
             self.state, self.kpool, self.vpool, self.kscale,
             self.vscale, self._table_dev, self._pos_dev, self._tok_dev,
             self._active_dev, self._ring_dev, self._ridx_dev,
-            self._cos, self._sin, self.lora, self._aidx_dev)
+            self._cos, self._sin, self.lora, self._aidx_dev,
+            self._counters_dev)
         if self.decode_traces != traces_before:
             sig = f"slots={self.max_slots} ring={self.sync_interval}"
             if self.tp > 1:
@@ -1076,23 +1125,22 @@ class ModelRunner:
         of shape [L, kvh, page_size, hd] (full heads — shards gather
         transparently on the mesh), plus ``(kscale, vscale)``
         [L, kvh, page_size] f32 when the pools are int8 — the spill
-        tier moves the quantized bytes, never a dequantized copy.
+        tier moves the quantized bytes, never a dequantized copy; a
+        latent cache's page is one array [L, page_size, width].
         Preemption-spill only: this is a host sync per call, never on
         the steady decode path."""
-        if self.kv_quant:
-            return (np.asarray(self.kpool[:, page]),
-                    np.asarray(self.vpool[:, page]),
-                    np.asarray(self.kscale[:, page]),
-                    np.asarray(self.vscale[:, page]))
-        return (np.asarray(self.kpool[:, page]),
-                np.asarray(self.vpool[:, page]))
+        return tuple(np.asarray(p[:, page]) for p in jax.tree.leaves(
+            (self.kpool, self.vpool, self.kscale, self.vscale)))
 
-    def write_page(self, page: int, k, v, kscale=None, vscale=None):
+    def write_page(self, page: int, k, v=None, kscale=None, vscale=None):
         """Host -> device copy of one KV page (preempted-request resume
         unparking a host-tier copy).  Eager per-call dispatch is fine —
         this runs once per restored page at admission, not per step."""
         kpool = self.kpool.at[:, page].set(
             jnp.asarray(k, self.kpool.dtype))
+        if self.latent:                 # one pool: a page is its rows
+            self.kpool = kpool
+            return
         vpool = self.vpool.at[:, page].set(
             jnp.asarray(v, self.vpool.dtype))
         if self.kv_quant:
@@ -1132,6 +1180,14 @@ class ModelRunner:
         if self.lora_slots:
             self._aidx_dev = self._aidx_dev.at[slot].set(
                 int(adapter_row))
+
+    def moe_counters(self) -> dict:
+        """The expert layers' counters since the runner was built, by
+        name ({} for a family without experts).  A device fetch: on
+        demand, never inside a step."""
+        if not self.latent:
+            return {}
+        return latent.counters_by_name(np.asarray(self._counters_dev))
 
     def fetch_ring(self) -> np.ndarray:
         """The host sync: ONE [sync_interval, slots] int32 transfer."""

@@ -22,6 +22,8 @@ import pytest
 from paddle_tpu.ops.pallas import decode_attention as DA
 from paddle_tpu.ops.pallas import flash_attention as FA
 from paddle_tpu.ops.pallas import flash_mask as FM
+from paddle_tpu.ops.pallas import grouped_ffn as GF
+from paddle_tpu.ops.pallas import mla_paged_attention as MLA
 from paddle_tpu.ops.pallas import paged_attention as PA
 from paddle_tpu.ops.pallas import rms_norm as RN
 
@@ -98,6 +100,89 @@ def test_paged_attention(sds, slots, kvh, ps, width):
         r'custom_call_target="tpu_custom_call", '
         rf'operand_layout_constraints=\{{s32\[{slots},{width}\]\S* '
         rf's32\[{slots}\]', text), text[-3000:]
+
+
+def _metric_events(name):
+    """The patterns by which a roofline metric finds its kernel's
+    events in the trace."""
+    import json
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "layer_metrics",
+                           name + ".json")) as f:
+        return json.load(f)["args"]["events"]
+
+
+def _hlo_lines(text, stem):
+    """The compiled program's instructions called ``stem``, as the v5e's
+    trace names their events: the line from its ``%``."""
+    lines = [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()]
+    return [ln for ln in lines if ln.startswith("%" + stem)]
+
+
+def test_mla_paged_attention_at_the_cells_shapes(sds):
+    """64 slots, 256 pages a slot, 5 layers in one pool, rows of 576
+    values (declared at the 640 lanes they occupy), 64 heads over a
+    latent of 512 + 64."""
+    slots, width, heads = 64, 256, 64
+    assert MLA.row_width(576) == 640
+    text = compiled_text(
+        lambda ql, qr, pool, t, n: MLA.mla_paged_attention(
+            ql, qr, pool, 3, t, n, sm_scale=0.1447),
+        sds((slots, heads, 512)), sds((slots, heads, 64)),
+        sds((5, slots * width + 1, 16, 640)),
+        sds((slots, width), jnp.int32), sds((slots,), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    lines = _hlo_lines(text, "mla_paged_attention")
+    assert len(lines) == 1
+    assert any(re.search(p, lines[0])
+               for p in _metric_events("mla_decode_roofline.serve"))
+
+
+def test_cache_write_rewrites_pages_in_place(sds):
+    """The decode step's 64 new rows: whole pages through VMEM, the pool
+    aliased to the output, so the compiled call declares no copy."""
+    slots = 64
+    compiled = jax.jit(
+        lambda pool, page, off, rows: MLA.write_rows(pool, 2, page, off,
+                                                     rows),
+        donate_argnums=0).lower(
+        sds((5, slots * 256 + 1, 16, 640)), sds((slots,), jnp.int32),
+        sds((slots,), jnp.int32), sds((slots, 576))).compile()
+    assert len(_hlo_lines(compiled.as_text(), "mla_cache_write")) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+@pytest.mark.parametrize("rows,k,n,tile", [
+    (768, 7168, 2048, 16),      # a decode step's gate / up: 64 x 8 + 16 x 16
+    (768, 2048, 7168, 16),      # ... and its down
+    (10240, 7168, 2048, 128),   # a prefill of 1024 tokens
+], ids=["decode-up", "decode-down", "prefill-up"])
+def test_grouped_matmul_at_the_cells_shapes(sds, rows, k, n, tile):
+    """16 held experts of 7168 x 2048: a block fits VMEM only tiled."""
+    text = compiled_text(
+        lambda x, w, e, live: GF.grouped_matmul(x, w, e, live, tile_m=tile),
+        sds((rows, k)), sds((16, k, n)), sds((rows // tile,), jnp.int32),
+        sds((), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    lines = _hlo_lines(text, "grouped_matmul")
+    assert len(lines) == 1
+    # the experts' roofline times the decode step's calls only
+    timed = any(re.search(p, lines[0])
+                for p in _metric_events("moe_experts_roofline.serve"))
+    assert timed == (rows == 768)
+
+
+def test_flash_masked_at_the_latent_familys_head_dim(sds):
+    # MLA's prefill: 64 heads of 128 + 64 = 192, values 192, no padding
+    s, d = 1024, 192
+
+    def prefill_attention(q, k, v, mask):
+        vecs = FM.padding_mask_to_intervals(mask[:, :, 0, :], s)
+        return FA._pallas_sdpa_masked(q, k, v, vecs, True)
+
+    n = compile_kernels(prefill_attention, *[sds((1, s, 64, d))] * 3,
+                        sds((1, 1, 1, s), jnp.bool_))
+    assert n == 1
 
 
 def test_pallas_decode(sds):

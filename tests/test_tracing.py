@@ -400,8 +400,9 @@ class TestDeviceNames:
 
     @pytest.mark.parametrize("module, count", [
         ("decode_attention", 1), ("flash_attention", 6),
-        ("flash_mask", 8), ("grouped_ffn", 2), ("lora_matmul", 1),
-        ("paged_attention", 1), ("quant_matmul", 2), ("rms_norm", 1)])
+        ("flash_mask", 8), ("grouped_ffn", 3), ("lora_matmul", 1),
+        ("mla_paged_attention", 2), ("paged_attention", 1),
+        ("quant_matmul", 2), ("rms_norm", 1)])
     def test_every_pallas_call_has_a_fixed_name(self, module, count):
         names = self._kernel_names(
             os.path.join(self.PALLAS, module + ".py"))
@@ -412,10 +413,11 @@ class TestDeviceNames:
         names = [n for f in sorted(os.listdir(self.PALLAS))
                  if f.endswith(".py")
                  for n in self._kernel_names(os.path.join(self.PALLAS, f))]
-        assert len(names) == 22 == len(set(names))
+        assert len(names) == 25 == len(set(names))
         assert {"paged_attention", "flash_fwd", "flash_bwd_dq",
-                "flash_bwd_dkv", "rms_norm", "decode_attention"} <= set(
-            names)
+                "flash_bwd_dkv", "rms_norm", "decode_attention",
+                "mla_paged_attention", "mla_cache_write",
+                "grouped_matmul"} <= set(names)
 
     def test_programs_and_scopes_carry_their_names(self, tiny_model):
         engine = create_engine(tiny_model, max_slots=2, page_size=PAGE,
@@ -430,10 +432,78 @@ class TestDeviceNames:
             r.state, r.kpool, r.vpool, r.kscale, r.vscale, r._table_dev,
             r._pos_dev, r._tok_dev, r._active_dev, r._ring_dev,
             r._ridx_dev, r._cos, r._sin, r.lora,
-            r._aidx_dev).as_text(debug_info=True)
+            r._aidx_dev, r._counters_dev).as_text(debug_info=True)
         assert "jit_decode_step" in text
         for scope in self.SCOPES:
             assert f"jit(decode_step)/{scope}/" in text, scope
+
+    @staticmethod
+    def _latent_engine():
+        import jax.numpy as jnp
+        from paddle_tpu.models import deepseek_v3 as ds
+        from paddle_tpu.serving.engine import Engine
+        cfg = ds.DeepseekV3Config(
+            vocab_size=64, hidden_size=32, intermediate_size=64,
+            moe_intermediate_size=16, num_hidden_layers=2,
+            first_k_dense_replace=1, num_attention_heads=2,
+            q_lora_rank=16, kv_lora_rank=16, qk_nope_head_dim=8,
+            qk_rope_head_dim=8, v_head_dim=8, n_routed_experts=8,
+            num_experts_per_tok=2, n_group=2, topk_group=1,
+            max_position_embeddings=128, local_experts=(0, 4),
+            dtype="float32")
+        rng = np.random.default_rng(0)
+        state = {k: jnp.asarray(0.1 * rng.normal(size=shape), jnp.float32)
+                 for k, shape in ds.weight_shapes(cfg).items()}
+        return Engine(config=cfg, state=state, max_slots=2,
+                      page_size=PAGE, max_model_len=64)
+
+    def test_latent_familys_programs_and_scopes(self):
+        """The deepseek_v3 family keeps the programs' names and names
+        its own parts: latent attention's two projections, the cache
+        write, the router, the held experts, the shared expert."""
+        import jax.numpy as jnp
+        r = self._latent_engine().runner
+        assert r._step_fn.__name__ == "decode_step"
+        assert r._prefill_fn(PAGE).__name__ == "prefill"
+        assert r._prefill_cached_fn(PAGE).__name__ == "prefill_cached"
+        assert r.vpool == () and r.kpool.ndim == 4
+        text = r._step_fn.lower(
+            r.state, r.kpool, r.vpool, r.kscale, r.vscale, r._table_dev,
+            r._pos_dev, r._tok_dev, r._active_dev, r._ring_dev,
+            r._ridx_dev, r._cos, r._sin, r.lora,
+            r._aidx_dev, r._counters_dev).as_text(debug_info=True)
+        for scope in ("embed", "attn.mla.q", "attn.mla.kv", "kv.write",
+                      "attn.decode", "attn.out", "mlp", "moe.route",
+                      "moe.experts", "moe.shared", "head"):
+            assert f"jit(decode_step)/{scope}/" in text, scope
+        text = r._prefill_fn(PAGE).lower(
+            r.state, jnp.zeros((1, PAGE), jnp.int32),
+            jnp.ones((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
+            r.kpool, r.vpool, r.kscale, r.vscale, r._cos, r._sin, (),
+            ()).as_text(debug_info=True)
+        for scope in ("attn.mla.q", "attn.mla.kv", "attn.prefill",
+                      "kv.write", "moe.experts", "head"):
+            assert f"jit(prefill)/{scope}/" in text, scope
+
+    def test_decode_span_carries_the_expert_counters(self):
+        """``engine.decode.dispatch`` shows the device's expert counters
+        as ``stats()`` last read them: a step never fetches them."""
+        obs.tracer().reset()
+        engine = self._latent_engine()
+        engine.submit(np.array(PROMPT, np.int32),
+                      GenerationConfig(max_new_tokens=4))
+        engine.step()
+        engine.step()
+        seen = engine.stats()
+        assert seen["moe_routed_pairs"] >= 2
+        engine.run_until_complete(max_steps=50)
+        spans = [s for s in obs.tracer().spans()
+                 if s.name == "engine.decode.dispatch"]
+        assert "moe_routed_pairs" not in spans[0].attributes
+        assert spans[-1].attributes["moe_routed_pairs"] == \
+            seen["moe_routed_pairs"]
+        assert {"moe_local_pairs", "moe_experts_live",
+                "paged_blocks_live"} <= set(spans[-1].attributes)
 
     def test_verify_program_carries_its_name(self, tiny_model):
         engine = create_engine(tiny_model, max_slots=2, page_size=PAGE,
