@@ -265,16 +265,19 @@ def _decode_layer(w, x, kcache, vcache, cos1, sin1, pos, cfg: LlamaConfig):
 
 # ------------------------------------------------------- paged decode step
 def _decode_layer_paged(w, x, kpool, vpool, table, cos1, sin1, pos,
-                        cfg: LlamaConfig, lora=(), aidx=None, li=0):
-    """Paged-cache decode layer: pools [P, kvH, ps, D], table
-    [B, max_pages]; pos [B] is the CURRENT token's position.  The
-    write targets page table[b, pos // ps] slot pos % ps — always a
-    real reserved page; reads go through the paged kernel (reference
+                        cfg: LlamaConfig, lora=(), aidx=None, *, li):
+    """Paged-cache decode layer ``li``: pools [L, P, kvH, ps, D],
+    every layer's, passed whole and returned whole — the row scatter
+    and the kernel both take the layer as an index, so a donated pool
+    is updated where it lies.  table [B, max_pages]; pos [B] is the
+    CURRENT token's position.  The write targets page
+    table[b, pos // ps] slot pos % ps — always a real reserved page;
+    reads go through the paged kernel (reference
     block_multi_head_attention_kernel.cu)."""
     b = x.shape[0]
     nh, kvh, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    cfg.head_dim)
-    ps = kpool.shape[2]
+    ps = kpool.shape[3]
     with jax.named_scope("attn.qkv"):
         h = _rms(x[:, None], w["ln1"], cfg.rms_norm_eps)[:, 0]
         qp, kp, vp = _qkv_proj(w, h, nh, kvh, hd, lora, aidx, li)
@@ -289,14 +292,14 @@ def _decode_layer_paged(w, x, kpool, vpool, table, cos1, sin1, pos,
     with jax.named_scope("kv.write"):
         page = jnp.take_along_axis(table, (pos // ps)[:, None], axis=1)[:, 0]
         off = pos % ps
-        heads = jnp.arange(kvh)
-        kpool = kpool.at[page[:, None], heads[None, :], off[:, None]].set(k)
-        vpool = vpool.at[page[:, None], heads[None, :], off[:, None]].set(v)
+        idx = (li, page[:, None], jnp.arange(kvh)[None, :], off[:, None])
+        kpool = kpool.at[idx].set(k)
+        vpool = vpool.at[idx].set(v)
 
     with jax.named_scope("attn.decode"):
         from ..ops.pallas.paged_attention import select_paged_attention
         attn = select_paged_attention()(
-            q, kpool, vpool, table, pos + 1).reshape(b, nh * hd)
+            q, kpool, vpool, li, table, pos + 1).reshape(b, nh * hd)
     with jax.named_scope("attn.out"):
         o = _mm(attn, w["o"])
         if lora:
@@ -406,16 +409,11 @@ def build_generate_fn_paged(config: LlamaConfig, gen: GenerationConfig,
                            axis=0)
             cos1, sin1 = _rope_at(cos, sin, pos)
             h = emb
-            kps, vps = [], []
             for i in range(L):
                 w = _layer_weights(state, i)
-                h, kp_, vp_ = _decode_layer_paged(
-                    w, h, kpool[i], vpool[i], table, cos1, sin1, pos,
-                    config)
-                kps.append(kp_)
-                vps.append(vp_)
-            kpool = jnp.stack(kps)
-            vpool = jnp.stack(vps)
+                h, kpool, vpool = _decode_layer_paged(
+                    w, h, kpool, vpool, table, cos1, sin1, pos, config,
+                    li=i)
             h = _rms(h[:, None], state["llama.norm.weight"],
                      config.rms_norm_eps)[:, 0]
             nxt = _sample(logits_of(h), key_t, gen)

@@ -26,9 +26,14 @@ pages — table padding (the shared DUMP page) is never fetched.  The
 arithmetic takes the whole block for all heads at once (one batched
 ``dot_general`` over the KV heads) with a float32 online softmax.
 
-Layout: pool [num_pages, kvH, page_size, D] (trailing dims tile), table
-[B, max_pages] int32, lens [B] = tokens visible per sequence.
-Inference-only (no VJP).
+Layout: pools [L, num_pages, kvH, page_size, D] (trailing dims tile):
+every layer's pages in one array, which the callers pass WHOLE with the
+layer to read — a ``kpool[layer]`` that feeds a Pallas call would be
+copied out first (the kernel takes a whole HBM operand), 67 MB a layer a
+pool at the serving cell's shape.  The layer rides scalar prefetch after
+the table and the lengths (the MLA kernel's order) and the page copies
+read ``pool[layer, page]``.  Table [B, max_pages] int32, lens [B] =
+tokens visible per sequence.  Inference-only (no VJP).
 """
 from __future__ import annotations
 
@@ -79,16 +84,22 @@ def select_paged_attention(tp_axis: str | None = None):
     if tp_axis is None:
         return base
 
-    def head_parallel(q, kpool, vpool, table, lens):
-        nh_l, kvh_l = q.shape[1], kpool.shape[1]
-        if kvh_l == 0 or nh_l % kvh_l:
-            raise ValueError(
-                f"head-parallel paged attention: local q heads {nh_l} "
-                f"do not group onto local KV heads {kvh_l} — the tp "
-                "size must divide both head counts")
-        return base(q, kpool, vpool, table, lens)
+    def head_parallel(q, kpool, vpool, layer, table, lens):
+        _check_local_heads(q, kpool)
+        return base(q, kpool, vpool, layer, table, lens)
 
     return head_parallel
+
+
+def _check_local_heads(q, kpool):
+    """Inside a ``shard_map`` over the KV-head axis: this shard's q
+    heads must still group onto its KV heads."""
+    nh_l, kvh_l = q.shape[1], kpool.shape[2]
+    if kvh_l == 0 or nh_l % kvh_l:
+        raise ValueError(
+            f"head-parallel paged attention: local q heads {nh_l} "
+            f"do not group onto local KV heads {kvh_l} — the tp "
+            "size must divide both head counts")
 
 
 def pages_per_block(page_size: int, max_pages: int) -> int:
@@ -99,13 +110,14 @@ def pages_per_block(page_size: int, max_pages: int) -> int:
     return max(1, min(BLOCK_TOKENS // int(page_size), int(max_pages)))
 
 
-def _paged_kernel(table_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
-                  kbuf, vbuf, sems, side_ref, acc_ref, m_ref, l_ref, *,
-                  page_size, blk, max_pages, sm_scale):
+def _paged_kernel(table_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                  o_ref, kbuf, vbuf, sems, side_ref, acc_ref, m_ref, l_ref,
+                  *, page_size, blk, max_pages, sm_scale):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, j = pl.program_id(0), pl.program_id(1)
+    layer = layer_ref[0]
     tokens = blk * page_size                        # a block's tokens
 
     def visible(b_):
@@ -124,10 +136,10 @@ def _paged_kernel(table_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
     def page_copies(side, p, page):
         # one page = all its KV heads, contiguous in the pool
         rows = pl.ds(pl.multiple_of(p * page_size, page_size), page_size)
-        return (pltpu.make_async_copy(k_hbm.at[page],
+        return (pltpu.make_async_copy(k_hbm.at[layer, page],
                                       kbuf.at[side, :, rows, :],
                                       sems.at[0, side]),
-                pltpu.make_async_copy(v_hbm.at[page],
+                pltpu.make_async_copy(v_hbm.at[layer, page],
                                       vbuf.at[side, :, rows, :],
                                       sems.at[1, side]))
 
@@ -208,33 +220,35 @@ def _paged_kernel(table_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
         o_ref[...] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
-def paged_attention(q, kpool, vpool, table, lens):
-    """q [B, nh, D]; pools [P, kvH, page_size, D]; table [B, max_pages]
-    int32 page ids (padding = a dump page id, as PagedPool builds it —
-    never a real page); lens [B] visible tokens.  Returns [B, nh, D]."""
+def paged_attention(q, kpool, vpool, layer, table, lens):
+    """q [B, nh, D]; pools [L, P, kvH, page_size, D], passed whole;
+    ``layer`` the pools' layer to read (a Python int or a traced
+    scalar); table [B, max_pages] int32 page ids (padding = a dump page
+    id, as PagedPool builds it — never a real page); lens [B] visible
+    tokens.  Returns [B, nh, D]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, nh, d = q.shape
-    kvh, page_size = kpool.shape[1], kpool.shape[2]
+    kvh, page_size = kpool.shape[2], kpool.shape[3]
     rep = nh // kvh
     max_pages = table.shape[1]
     blk = pages_per_block(page_size, max_pages)
     qg = q.reshape(b, kvh, rep, d)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, -(-max_pages // blk)),
         in_specs=[
             pl.BlockSpec((None, kvh, rep, d),
-                         lambda b_, j, tbl, ln: (b_, 0, 0, 0)),
+                         lambda b_, j, tbl, ln, ly: (b_, 0, 0, 0)),
             # the pools stay in HBM: the kernel copies the pages the
             # scalar-prefetched table names
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((None, kvh, rep, d),
-                               lambda b_, j, tbl, ln: (b_, 0, 0, 0)),
+                               lambda b_, j, tbl, ln, ly: (b_, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, kvh, blk * page_size, d), kpool.dtype),
             pltpu.VMEM((2, kvh, blk * page_size, d), vpool.dtype),
@@ -254,8 +268,8 @@ def paged_attention(q, kpool, vpool, table, lens):
             out_shape=jax.ShapeDtypeStruct((b, kvh, rep, d), q.dtype),
             interpret=_INTERPRET,
             name="paged_attention",
-        )(table.astype(jnp.int32), lens.astype(jnp.int32), qg, kpool,
-          vpool)
+        )(table.astype(jnp.int32), lens.astype(jnp.int32),
+          jnp.asarray(layer, jnp.int32).reshape(1), qg, kpool, vpool)
     return out.reshape(b, nh, d)
 
 
@@ -272,16 +286,16 @@ def gather_kv_pages(pool, table):
     return g.reshape(table.shape[:-1] + (table.shape[-1] * ps, kvh, d))
 
 
-def paged_attention_xla(q, kpool, vpool, table, lens):
-    """Dense-gather reference (identical numerics): materializes each
-    sequence's pages — O(B * max_pages * page_size) HBM — used off-TPU
-    and by the parity tests."""
+def paged_attention_xla(q, kpool, vpool, layer, table, lens):
+    """Dense-gather reference (identical numerics; the kernel's
+    arguments): materializes each sequence's pages of ``layer`` —
+    O(B * max_pages * page_size) HBM — used off-TPU and by the parity
+    tests.  The slice of the pool fuses into the gather."""
     b, nh, d = q.shape
-    kvh, ps = kpool.shape[1], kpool.shape[2]
-    rep = nh // kvh
+    rep = nh // kpool.shape[2]
     # [B, W*ps, kvh, D] -> [B, kvh, W*ps, D]
-    kb = gather_kv_pages(kpool, table).transpose(0, 2, 1, 3)
-    vb = gather_kv_pages(vpool, table).transpose(0, 2, 1, 3)
+    kb = gather_kv_pages(kpool[layer], table).transpose(0, 2, 1, 3)
+    vb = gather_kv_pages(vpool[layer], table).transpose(0, 2, 1, 3)
     kq = jnp.repeat(kb, rep, axis=1)
     vq = jnp.repeat(vb, rep, axis=1)
     logits = jnp.einsum("bhd,bhtd->bht", q, kq,
@@ -331,28 +345,24 @@ def gather_kv_pages_quant(pool, scale, table, dtype=jnp.float32):
     return (g * s[..., None]).astype(dtype)
 
 
-def paged_attention_quant(q, kpool, vpool, kscale, vscale, table, lens,
-                          tp_axis=None):
-    """Paged attention over int8 KV pools with per-(page-row, head) f32
-    scales: the dense-gather formulation of :func:`paged_attention_xla`
-    with dequantization fused into the page gather.  ``tp_axis`` marks
-    a head-parallel caller inside a ``shard_map`` (pools sharded on the
-    KV-head axis); like the dense chooser it only validates the local
-    head grouping — attention itself needs no collective."""
+def paged_attention_quant(q, kpool, vpool, kscale, vscale, layer, table,
+                          lens, tp_axis=None):
+    """Paged attention over int8 KV pools [L, P, kvH, page_size, D] with
+    per-(page-row, head) f32 scales [L, P, kvH, page_size], all passed
+    whole with the ``layer`` to read: the dense-gather formulation of
+    :func:`paged_attention_xla` with dequantization fused into the page
+    gather.  ``tp_axis`` marks a head-parallel caller inside a
+    ``shard_map`` (pools sharded on the KV-head axis); like the dense
+    chooser it only validates the local head grouping — attention
+    itself needs no collective."""
     if tp_axis is not None:
-        nh_l, kvh_l = q.shape[1], kpool.shape[1]
-        if kvh_l == 0 or nh_l % kvh_l:
-            raise ValueError(
-                f"head-parallel paged attention: local q heads {nh_l} "
-                f"do not group onto local KV heads {kvh_l} — the tp "
-                "size must divide both head counts")
+        _check_local_heads(q, kpool)
     b, nh, d = q.shape
-    kvh = kpool.shape[1]
-    rep = nh // kvh
+    rep = nh // kpool.shape[2]
     # [B, W*ps, kvh, D] -> [B, kvh, W*ps, D], dequantized at the gather
-    kb = gather_kv_pages_quant(kpool, kscale, table,
+    kb = gather_kv_pages_quant(kpool[layer], kscale[layer], table,
                                q.dtype).transpose(0, 2, 1, 3)
-    vb = gather_kv_pages_quant(vpool, vscale, table,
+    vb = gather_kv_pages_quant(vpool[layer], vscale[layer], table,
                                q.dtype).transpose(0, 2, 1, 3)
     kq = jnp.repeat(kb, rep, axis=1)
     vq = jnp.repeat(vb, rep, axis=1)

@@ -21,6 +21,14 @@ states for ``tp>1`` up front), but every matmul routes through
 path, and plain arrays lower to the identical ``@`` the bodies always
 used — the dense jaxpr is unchanged.
 
+Pool shapes.  The decode bodies (``decode_layer_paged_tp``,
+``decode_layer_paged_quant``) take every layer's pools whole,
+``[L, P, kvH/tp, ps, D]`` (scales ``[L, P, kvH/tp, ps]``), with their
+layer ``li``: the row scatter and the attention index the layer, and the
+bodies return the same whole arrays, so a donated pool is updated where
+it lies.  The cached-prefill bodies only read the resident prefix and
+take one layer's ``[P, kvH/tp, ps, D]``; their program writes the pool.
+
 The ``*_quant`` bodies are the int8-KV-page mirrors: pools are int8
 with per-(page-row, head) f32 scale arrays, new KV quantizes on write
 inside the same traced step, and attention dequantizes fused into the
@@ -79,14 +87,14 @@ def _ffn_tp(w, h, axis, lora=(), aidx=None, li=0):
 
 
 def decode_layer_paged_tp(w, x, kpool, vpool, table, cos1, sin1, pos,
-                          cfg, axis, lora=(), aidx=None, li=0):
-    """Per-shard paged decode layer: ``x`` [B, H] replicated, pools
-    [P, kvH/tp, ps, D] local, ``table``/``pos`` replicated.  Returns
-    (out replicated, kpool, vpool local) — mirror of
-    ``_decode_layer_paged`` with the o/down all-reduces."""
+                          cfg, axis, lora=(), aidx=None, *, li):
+    """Per-shard paged decode layer ``li``: ``x`` [B, H] replicated,
+    pools [L, P, kvH/tp, ps, D] local and whole, ``table``/``pos``
+    replicated.  Returns (out replicated, kpool, vpool local, whole) —
+    mirror of ``_decode_layer_paged`` with the o/down all-reduces."""
     b = x.shape[0]
     hd = cfg.head_dim
-    ps = kpool.shape[2]
+    ps = kpool.shape[3]
     with jax.named_scope("attn.qkv"):
         h = _rms(x[:, None], w["ln1"], cfg.rms_norm_eps)[:, 0]
         qp, kp, vp, nh_l, kvh_l = _local_qkv(w, h, hd, lora, aidx, li)
@@ -101,13 +109,13 @@ def decode_layer_paged_tp(w, x, kpool, vpool, table, cos1, sin1, pos,
     with jax.named_scope("kv.write"):
         page = jnp.take_along_axis(table, (pos // ps)[:, None], axis=1)[:, 0]
         off = pos % ps
-        heads = jnp.arange(kvh_l)
-        kpool = kpool.at[page[:, None], heads[None, :], off[:, None]].set(k)
-        vpool = vpool.at[page[:, None], heads[None, :], off[:, None]].set(v)
+        idx = (li, page[:, None], jnp.arange(kvh_l)[None, :], off[:, None])
+        kpool = kpool.at[idx].set(k)
+        vpool = vpool.at[idx].set(v)
 
     with jax.named_scope("attn.decode"):
         attn = select_paged_attention(tp_axis=axis)(
-            q, kpool, vpool, table, pos + 1).reshape(b, nh_l * hd)
+            q, kpool, vpool, li, table, pos + 1).reshape(b, nh_l * hd)
     with jax.named_scope("attn.out"):
         part = _mm(attn, w["o"])
         if lora:          # o's A is row-sharded: partial delta, same psum
@@ -223,16 +231,17 @@ def _ffn_quant(w, h, axis, lora=(), aidx=None, li=0):
 
 def decode_layer_paged_quant(w, x, kpool, vpool, kscale, vscale, table,
                              cos1, sin1, pos, cfg, axis=None, lora=(),
-                             aidx=None, li=0):
-    """Paged decode layer over int8 KV pools: quantize this token's
-    k/v rows on write (per-(token, head) scale into the scale pools —
-    same traced step, no extra host sync), attend through the
+                             aidx=None, *, li):
+    """Paged decode layer ``li`` over int8 KV pools [L, P, kvH, ps, D]
+    and their scale pools [L, P, kvH, ps], all whole: quantize this
+    token's k/v rows on write (per-(token, head) scale into the scale
+    pools — same traced step, no extra host sync), attend through the
     dequantizing gather.  ``axis=None`` is the tp=1 runner; an axis
     name runs the same body per-shard with the o/down all-reduces.
-    Returns (out, kpool, vpool, kscale, vscale)."""
+    Returns (out, kpool, vpool, kscale, vscale), the pools whole."""
     b = x.shape[0]
     hd = cfg.head_dim
-    ps = kpool.shape[2]
+    ps = kpool.shape[3]
     with jax.named_scope("attn.qkv"):
         h = _rms(x[:, None], w["ln1"], cfg.rms_norm_eps)[:, 0]
         qp, kp, vp, nh_l, kvh_l = _proj_qkv(w, h, cfg, axis, lora, aidx, li)
@@ -247,10 +256,9 @@ def decode_layer_paged_quant(w, x, kpool, vpool, kscale, vscale, table,
     with jax.named_scope("kv.write"):
         page = jnp.take_along_axis(table, (pos // ps)[:, None], axis=1)[:, 0]
         off = pos % ps
-        heads = jnp.arange(kvh_l)
         qk, sk = quantize_kv_rows(k)
         qv, sv = quantize_kv_rows(v)
-        idx = (page[:, None], heads[None, :], off[:, None])
+        idx = (li, page[:, None], jnp.arange(kvh_l)[None, :], off[:, None])
         kpool = kpool.at[idx].set(qk)
         vpool = vpool.at[idx].set(qv)
         kscale = kscale.at[idx].set(sk)
@@ -258,7 +266,7 @@ def decode_layer_paged_quant(w, x, kpool, vpool, kscale, vscale, table,
 
     with jax.named_scope("attn.decode"):
         attn = paged_attention_quant(
-            q, kpool, vpool, kscale, vscale, table, pos + 1,
+            q, kpool, vpool, kscale, vscale, li, table, pos + 1,
             tp_axis=axis).reshape(b, nh_l * hd)
     with jax.named_scope("attn.out"):
         part = _mm(attn, w["o"])

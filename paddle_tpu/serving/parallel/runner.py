@@ -35,6 +35,14 @@ The engine's serving invariants carry over unchanged: slot occupancy /
 positions / tables are data (ONE decode trace per engine lifetime —
 ``decode_traces`` counts them), the decode state is donated through the
 step, and admissions/evictions patch single slot rows in place.
+
+The pools go through every program WHOLE: a layer body takes
+``[L, pages+1, kvh, page_size, hd]`` and its layer's index, scatters the
+step's rows at ``[li, page, head, off]``, hands the same array and ``li``
+to the paged kernel and returns it.  A layer sliced out for the kernel or
+layers stacked back would each copy the pool; as it is the donated pools
+are updated where they lie and the decode and verify programs declare no
+pool-sized temporary (``tests/test_chip_compile.py`` holds them to it).
 """
 from __future__ import annotations
 
@@ -567,28 +575,17 @@ class ModelRunner:
                                axis=0)
                 cos1, sin1 = _rope_at(cos, sin, posc)
             h = emb
-            kps, vps, kss, vss = [], [], [], []
             for i in range(L):
                 w = _layer_weights(state, i)
                 if kv_quant:
-                    h, kp_, vp_, ks_, vs_ = decode_layer_paged_quant(
-                        w, h, kpool[i], vpool[i], kscale[i], vscale[i],
-                        table, cos1, sin1, posc, cfg, None, lora, aidx,
-                        i)
-                    kss.append(ks_)
-                    vss.append(vs_)
+                    (h, kpool, vpool, kscale,
+                     vscale) = decode_layer_paged_quant(
+                        w, h, kpool, vpool, kscale, vscale, table, cos1,
+                        sin1, posc, cfg, None, lora, aidx, li=i)
                 else:
-                    h, kp_, vp_ = _decode_layer_paged(
-                        w, h, kpool[i], vpool[i], table, cos1, sin1,
-                        posc, cfg, lora, aidx, i)
-                kps.append(kp_)
-                vps.append(vp_)
-            with jax.named_scope("kv.write"):
-                kpool = jnp.stack(kps)
-                vpool = jnp.stack(vps)
-                if kv_quant:
-                    kscale = jnp.stack(kss)
-                    vscale = jnp.stack(vss)
+                    h, kpool, vpool = _decode_layer_paged(
+                        w, h, kpool, vpool, table, cos1, sin1, posc,
+                        cfg, lora, aidx, li=i)
             with jax.named_scope("head"):
                 h = _rms(h[:, None], state["llama.norm.weight"],
                          cfg.rms_norm_eps)[:, 0]
@@ -630,28 +627,17 @@ class ModelRunner:
                                axis=0)
                 cos1, sin1 = _rope_at(cos, sin, posc)
             h = emb
-            kps, vps, kss, vss = [], [], [], []
             for i in range(L):
                 w = _layer_weights(state, i)
                 if kv_quant:
-                    h, kp_, vp_, ks_, vs_ = decode_layer_paged_quant(
-                        w, h, kpool[i], vpool[i], kscale[i], vscale[i],
-                        table, cos1, sin1, posc, cfg, TP_AXIS, lora,
-                        aidx, i)
-                    kss.append(ks_)
-                    vss.append(vs_)
+                    (h, kpool, vpool, kscale,
+                     vscale) = decode_layer_paged_quant(
+                        w, h, kpool, vpool, kscale, vscale, table, cos1,
+                        sin1, posc, cfg, TP_AXIS, lora, aidx, li=i)
                 else:
-                    h, kp_, vp_ = decode_layer_paged_tp(
-                        w, h, kpool[i], vpool[i], table, cos1, sin1,
-                        posc, cfg, TP_AXIS, lora, aidx, i)
-                kps.append(kp_)
-                vps.append(vp_)
-            with jax.named_scope("kv.write"):
-                kpool = jnp.stack(kps)
-                vpool = jnp.stack(vps)
-                if kv_quant:
-                    kscale = jnp.stack(kss)
-                    vscale = jnp.stack(vss)
+                    h, kpool, vpool = decode_layer_paged_tp(
+                        w, h, kpool, vpool, table, cos1, sin1, posc,
+                        cfg, TP_AXIS, lora, aidx, li=i)
             with jax.named_scope("head"):
                 h = _rms(h[:, None], state["llama.norm.weight"],
                          cfg.rms_norm_eps)[:, 0]
@@ -746,32 +732,22 @@ class ModelRunner:
                                axis=0)
                 cos1, sin1 = _rope_at(cos, sin, posc)
             h = emb
-            kps, vps, kss, vss = [], [], [], []
             for i in range(L):
                 w = _layer_weights(state, i)
                 if kv_quant:
-                    h, kp_, vp_, ks_, vs_ = decode_layer_paged_quant(
-                        w, h, kpool[i], vpool[i], kscale[i], vscale[i],
-                        table_f, cos1, sin1, posc, cfg,
-                        TP_AXIS if tp else None, lora, aidx_f, i)
-                    kss.append(ks_)
-                    vss.append(vs_)
+                    (h, kpool, vpool, kscale,
+                     vscale) = decode_layer_paged_quant(
+                        w, h, kpool, vpool, kscale, vscale, table_f,
+                        cos1, sin1, posc, cfg, TP_AXIS if tp else None,
+                        lora, aidx_f, li=i)
                 elif tp:
-                    h, kp_, vp_ = decode_layer_paged_tp(
-                        w, h, kpool[i], vpool[i], table_f, cos1, sin1,
-                        posc, cfg, TP_AXIS, lora, aidx_f, i)
+                    h, kpool, vpool = decode_layer_paged_tp(
+                        w, h, kpool, vpool, table_f, cos1, sin1, posc,
+                        cfg, TP_AXIS, lora, aidx_f, li=i)
                 else:
-                    h, kp_, vp_ = _decode_layer_paged(
-                        w, h, kpool[i], vpool[i], table_f, cos1, sin1,
-                        posc, cfg, lora, aidx_f, i)
-                kps.append(kp_)
-                vps.append(vp_)
-            with jax.named_scope("kv.write"):
-                kpool = jnp.stack(kps)
-                vpool = jnp.stack(vps)
-                if kv_quant:
-                    kscale = jnp.stack(kss)
-                    vscale = jnp.stack(vss)
+                    h, kpool, vpool = _decode_layer_paged(
+                        w, h, kpool, vpool, table_f, cos1, sin1, posc,
+                        cfg, lora, aidx_f, li=i)
             with jax.named_scope("head"):
                 h = _rms(h[:, None], state["llama.norm.weight"],
                          cfg.rms_norm_eps)[:, 0]

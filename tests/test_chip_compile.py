@@ -73,6 +73,18 @@ def compiled_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def event_names(compiled) -> list[str]:
+    """The compiled program's instructions as the v5e's trace names
+    their events: the whole line from its ``%``, operands with their
+    shapes (``as_text()`` leaves those out)."""
+    from jax._src.lib import _jax
+    opts = _jax.HloPrintOptions()
+    opts.print_operand_shape = True
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+    return [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()
+            if ln.lstrip().startswith(("%", "ROOT %"))]
+
+
 def compile_kernels(fn, *args) -> int:
     """The number of Pallas kernels in ``fn`` compiled for the described
     chip."""
@@ -87,11 +99,14 @@ def compile_kernels(fn, *args) -> int:
     (4, KVH, 128, 5),       # the one-shot generate's pool: page 128
 ], ids=["slots8", "cell", "tp-local", "one-shot"])
 def test_paged_attention(sds, slots, kvh, ps, width):
-    pool = sds((slots * width + 1, kvh, ps, HD))
-    text = compiled_text(
-        PA.paged_attention, sds((slots, 4 * kvh, HD)), pool, pool,
-        sds((slots, width), jnp.int32), sds((slots,), jnp.int32))
+    pool = sds((2, slots * width + 1, kvh, ps, HD))     # two layers
+    compiled = jax.jit(
+        lambda q, k, v, t, n: PA.paged_attention(q, k, v, 1, t, n)).lower(
+        sds((slots, 4 * kvh, HD)), pool, pool,
+        sds((slots, width), jnp.int32), sds((slots,), jnp.int32)).compile()
+    text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert len(_paged_events(event_names(compiled))) == 1
     # the benchmark's roofline finds the kernel's events by the table
     # [slots, width] and the lengths [slots] as the call's first two
     # operands, and its breakdown names them by the stem
@@ -112,11 +127,133 @@ def _metric_events(name):
         return json.load(f)["args"]["events"]
 
 
+def _paged_events(names):
+    """The events the paged kernel's roofline would time."""
+    patterns = _metric_events("paged_attention_roofline.serve")
+    return [n for n in names if any(re.search(p, n) for p in patterns)]
+
+
 def _hlo_lines(text, stem):
     """The compiled program's instructions called ``stem``, as the v5e's
     trace names their events: the line from its ``%``."""
     lines = [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()]
     return [ln for ln in lines if ln.startswith("%" + stem)]
+
+
+def _cell_runner(monkeypatch, *, spec_k=0, kv_quant=False):
+    """A ModelRunner at the Mistral cell's widths and engine sizes, cut
+    to two layers, that holds what ``_build_step`` / ``_build_verify``
+    read and nothing on any device; and the shapes of its state."""
+    import json
+    import sys
+    from paddle_tpu.models.llama import LlamaConfig
+    from paddle_tpu.serving.parallel.runner import ModelRunner
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.lib.state import decoder_shapes
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "mistral-7b-v0.3-l16.json")) as f:
+        cell = json.load(f)
+    m, eng = dict(cell["model"], num_hidden_layers=2), cell["engine"]
+    assert (m["num_attention_heads"], m["num_key_value_heads"],
+            m["head_dim"]) == (NH, KVH, HD)
+    # the kernel gate asks for the backend; the described chip is a TPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    run = object.__new__(ModelRunner)
+    run.config = LlamaConfig(
+        vocab_size=m["vocab_size"], hidden_size=m["hidden_size"],
+        intermediate_size=m["intermediate_size"],
+        num_hidden_layers=m["num_hidden_layers"],
+        num_attention_heads=NH, num_key_value_heads=KVH,
+        rms_norm_eps=m["rms_norm_eps"], rope_theta=m["rope_theta"],
+        dtype=m["torch_dtype"])
+    run.tp, run.latent, run.emit_logits = 1, False, False
+    run.spec_k, run.kv_quant = spec_k, kv_quant
+    run.max_slots, run.page_size = eng["max_slots"], eng["page_size"]
+    run.table_width = eng["max_model_len"] // run.page_size
+    run.num_pages = run.max_slots * run.table_width     # + the dump page
+    run._rope_len = eng["max_model_len"]
+    run.decode_traces = run.verify_traces = 0
+    return run, {k: shape for k, (shape, _) in decoder_shapes(m).items()}
+
+
+def _compile_decode_program(sds, run, shapes):
+    """The runner's own jitted decode step (``spec_k`` 0) or verify
+    program, donated as the runner donates, lowered from shapes."""
+    slots, k = run.max_slots, run.spec_k
+    state = {name: sds(shape) for name, shape in shapes.items()}
+    pool_shape = _pool_shape(run)
+    pool = sds(pool_shape, jnp.int8 if run.kv_quant else jnp.bfloat16)
+    scale = sds(pool_shape[:-1], jnp.float32) if run.kv_quant else ()
+
+    def i32(*shape):
+        return sds(shape, jnp.int32)
+    rope = sds((run._rope_len, HD), jnp.float32)
+    head = (state, pool, pool, scale, scale, i32(slots, run.table_width),
+            i32(slots), i32(slots), i32(slots))
+    if k == 0:
+        return run._make_step_fn().lower(
+            *head, i32(1, slots), i32(), rope, rope, (), (), ()).compile()
+    return run._make_verify_fn().lower(
+        *head, i32(1, slots, k + 1), i32(), i32(slots, k), i32(slots),
+        rope, rope, (), ()).compile()
+
+
+def _pool_shape(run):
+    return (run.config.num_hidden_layers, run.num_pages + 1, KVH,
+            run.page_size, HD)
+
+
+def _pool_sized(names, run):
+    """(stem, opcode) of every instruction whose result is a whole pool
+    or one layer's slice of it."""
+    layers, *layer = _pool_shape(run)
+    layer = ",".join(map(str, layer))
+    found = []
+    for n in names:
+        m = re.match(rf"%(\S+) = \w+\[(?:{layers},)?{layer}\]\S* "
+                     r"([\w-]+)\(", n)
+        if m:
+            found.append((m.group(1), m.group(2)))
+    return found
+
+
+@pytest.mark.parametrize("spec_k", [0, 3], ids=["decode_step", "verify_step"])
+def test_decode_programs_update_the_pools_in_place(sds, monkeypatch, spec_k):
+    """The pools go through the step whole: with both donated, the
+    compiled program declares no pool-sized temporary (two layers' pools
+    are 134 MB each), copies no pool and no layer of one, and holds one
+    paged kernel a layer where the roofline's pattern finds it."""
+    run, shapes = _cell_runner(monkeypatch, spec_k=spec_k)
+    compiled = _compile_decode_program(sds, run, shapes)
+    assert compiled.memory_analysis().temp_size_in_bytes < (64 << 20)
+    names = event_names(compiled)
+    assert len(_paged_events(names)) == run.config.num_hidden_layers
+    pool_sized = _pool_sized(names, run)
+    assert pool_sized                       # the in-place row scatters
+    for stem, opcode in pool_sized:
+        for word in ("copy", "dynamic-update-slice", "slice"):
+            assert word not in opcode and word not in stem, (stem, opcode)
+
+
+def test_int8_pages_decode_step_reports_its_temporaries(sds, monkeypatch,
+                                                        record_property):
+    """No Pallas call pins the pool's layout where the pages are int8
+    (the step attends through the dequantizing gather): what the
+    compiler declares there is reported, and held to nothing but that
+    the int8 pools themselves are not copied."""
+    run, shapes = _cell_runner(monkeypatch, kv_quant=True)
+    compiled = _compile_decode_program(sds, run, shapes)
+    names = event_names(compiled)
+    assert not _paged_events(names)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    record_property("temp_size_in_bytes", temp)
+    print(f"int8 pages, 2 layers: temp_size_in_bytes {temp}")
+    pool_sized = _pool_sized(names, run)
+    assert pool_sized                       # the in-place row scatters
+    for stem, opcode in pool_sized:
+        assert "copy" not in opcode and "copy" not in stem, (stem, opcode)
 
 
 def test_mla_paged_attention_at_the_cells_shapes(sds):

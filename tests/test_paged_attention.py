@@ -31,25 +31,48 @@ def test_paged_pool_min_table_width():
     assert pool.table[0].tolist() == [0, 1, 1, 1]
 
 
-def test_paged_kernel_matches_gather_reference():
-    """Interpret-mode kernel vs the dense-gather formulation.  Matmul
-    precision pinned: on TPU the f32 dot default is a bf16-pass MXU
-    scheme whose drift exceeds the parity tolerance."""
+LAYERS = 3
+
+
+def _in_layer(pool, layer):
+    """``pool`` [P, kvH, ps, D] as layer ``layer`` of a pool of
+    ``LAYERS`` whose other layers are NaN: a read of the wrong layer
+    poisons the output."""
+    whole = np.full((LAYERS,) + pool.shape, np.nan, pool.dtype)
+    whole[layer] = pool
+    return jnp.asarray(whole)
+
+
+@pytest.mark.parametrize("layer,traced", [(0, False), (2, False),
+                                          (2, True)],
+                         ids=["layer0", "layer2", "layer2-traced"])
+def test_paged_kernel_matches_gather_reference(layer, traced):
+    """Interpret-mode kernel vs the dense-gather formulation, on one
+    layer of a whole pool.  Matmul precision pinned: on TPU the f32 dot
+    default is a bf16-pass MXU scheme whose drift exceeds the parity
+    tolerance."""
     PA._INTERPRET, saved = True, PA._INTERPRET
     try:
         with jax.default_matmul_precision("highest"):
             rng = np.random.RandomState(0)
             B, nh, kvh, D, ps, P, M = 3, 8, 2, 64, 128, 7, 3
             q = jnp.asarray(rng.randn(B, nh, D).astype(np.float32))
-            kpool = jnp.asarray(
-                rng.randn(P, kvh, ps, D).astype(np.float32))
-            vpool = jnp.asarray(
-                rng.randn(P, kvh, ps, D).astype(np.float32))
+            kpool = _in_layer(
+                rng.randn(P, kvh, ps, D).astype(np.float32), layer)
+            vpool = _in_layer(
+                rng.randn(P, kvh, ps, D).astype(np.float32), layer)
             table = jnp.asarray(
                 np.array([[0, 1, 2], [3, 6, 6], [4, 5, 6]], np.int32))
             lens = jnp.asarray(np.array([300, 77, 180], np.int32))
-            out_k = PA.paged_attention(q, kpool, vpool, table, lens)
-            out_x = PA.paged_attention_xla(q, kpool, vpool, table, lens)
+            if traced:      # the layer as data: one program, any layer
+                out_k = jax.jit(PA.paged_attention)(
+                    q, kpool, vpool, jnp.int32(layer), table, lens)
+            else:
+                out_k = PA.paged_attention(q, kpool, vpool, layer, table,
+                                           lens)
+            out_x = PA.paged_attention_xla(q, kpool, vpool, layer, table,
+                                           lens)
+            assert np.isfinite(np.asarray(out_k)).all()
             np.testing.assert_allclose(np.asarray(out_k),
                                        np.asarray(out_x),
                                        atol=1e-4, rtol=1e-4)
@@ -67,15 +90,17 @@ _GEOMETRY = {
 }
 
 
+@pytest.mark.parametrize("layer", [0, 2])
 @pytest.mark.parametrize("order", ["rising", "falling", "empty-first"])
 @pytest.mark.parametrize("geometry", list(_GEOMETRY))
-def test_paged_kernel_blocks_match_reference(geometry, order):
+def test_paged_kernel_blocks_match_reference(geometry, order, layer):
     """The block-of-pages kernel against the dense gather, under the TPU
     interpreter with uninitialised scratch reading NaN: contexts of 1, a
     block exactly, a block + 1, the whole table and nothing at all;
-    pages scattered over the pool; NaN in the dump page and in every
-    page no row names.  A stale buffer tail, a copy past the context or
-    a read past the table's row would show as NaN or as a difference."""
+    pages scattered over the pool; NaN in the dump page, in every page
+    no row names and in every other layer of the pool.  A stale buffer
+    tail, a copy past the context, a read past the table's row or of
+    another layer would show as NaN or as a difference."""
     from jax.experimental.pallas import tpu as pltpu
 
     ps, kvh, beyond = _GEOMETRY[geometry]
@@ -106,8 +131,8 @@ def test_paged_kernel_blocks_match_reference(geometry, order):
     kpool[unnamed] = np.nan
     vpool[unnamed] = np.nan
     q = jnp.asarray(rng.randn(b, kvh * rep, d).astype(np.float32))
-    args = (q, jnp.asarray(kpool), jnp.asarray(vpool), jnp.asarray(table),
-            jnp.asarray(np.array(lens, np.int32)))
+    args = (q, _in_layer(kpool, layer), _in_layer(vpool, layer), layer,
+            jnp.asarray(table), jnp.asarray(np.array(lens, np.int32)))
     PA._INTERPRET, saved = pltpu.InterpretParams(
         uninitialized_memory="nan"), PA._INTERPRET
     try:
@@ -121,7 +146,8 @@ def test_paged_kernel_blocks_match_reference(geometry, order):
     vpool[unnamed] = 0.0
     with jax.default_matmul_precision("highest"):
         out_x = np.asarray(PA.paged_attention_xla(
-            q, jnp.asarray(kpool), jnp.asarray(vpool), *args[3:]))
+            q, _in_layer(kpool, layer), _in_layer(vpool, layer),
+            *args[3:]))
     assert np.isfinite(out_k).all()
     for i, n in enumerate(lens):
         if n == 0:
@@ -175,14 +201,51 @@ def test_paged_generate_page_boundary_crossing():
     assert np.array_equal(d, p)
 
 
+def test_decode_step_writes_its_rows_and_nothing_else():
+    """The pools go through the decode step whole: after one step every
+    element of both pools outside the rows the step wrote — one row a
+    slot a layer, at ``[layer, table[b, pos // ps], :, pos % ps]`` — is
+    bit-identical to before, and every decoding slot's row of every
+    layer is new."""
+    from paddle_tpu.serving import GenerationConfig, create_engine
+
+    eng = create_engine(_tiny_model(), max_slots=3, page_size=8,
+                        max_model_len=64)
+    rng = np.random.RandomState(5)
+    for n in (13, 24):      # mid-page and at a page's first row
+        eng.submit(rng.randint(1, 256, (n,)).astype(np.int32),
+                   GenerationConfig(max_new_tokens=8))
+    for _ in range(3):      # both prefilled, a decode step or two taken
+        eng.step()
+    run = eng.runner
+    active = np.asarray(run._active_dev).astype(bool)
+    assert active.tolist() == [True, True, False]
+    pos = np.asarray(run._pos_dev)
+    table = np.asarray(run._table_dev)
+    before = [np.asarray(run.kpool).copy(), np.asarray(run.vpool).copy()]
+    run.decode_step()
+    after = [np.asarray(run.kpool), np.asarray(run.vpool)]
+
+    ps = run.page_size
+    page = table[np.arange(len(pos)), pos // ps]
+    assert page[2] == run.dump_page             # the idle slot's row
+    written = np.zeros(before[0].shape[:2] + (ps,), bool)   # [L, P, ps]
+    written[:, page, pos % ps] = True
+    for was, now in zip(before, after):
+        assert was.shape[0] == 2 and now.shape == was.shape
+        same = (was == now).all(axis=(2, 4))                # [L, P, ps]
+        assert same[~written].all()
+        assert not same[:, page[active], (pos % ps)[active]].any()
+
+
 def test_paged_kernel_tpu_parity():
     if jax.default_backend() in ("cpu",):   # asked in the body, not at
         pytest.skip("needs TPU for the pallas kernel")      # import
     rng = np.random.RandomState(0)
     B, nh, kvh, D, ps, P, M = 4, 16, 4, 128, 128, 19, 5
     q = jnp.asarray(rng.randn(B, nh, D), jnp.bfloat16)
-    kpool = jnp.asarray(rng.randn(P, kvh, ps, D), jnp.bfloat16)
-    vpool = jnp.asarray(rng.randn(P, kvh, ps, D), jnp.bfloat16)
+    kpool = jnp.asarray(rng.randn(2, P, kvh, ps, D), jnp.bfloat16)
+    vpool = jnp.asarray(rng.randn(2, P, kvh, ps, D), jnp.bfloat16)
     tb = np.full((B, M), 18, np.int32)
     tb[0, :5] = [0, 1, 2, 3, 4]
     tb[1, :2] = [5, 6]
@@ -190,8 +253,8 @@ def test_paged_kernel_tpu_parity():
     tb[3, :1] = [11]
     table = jnp.asarray(tb)
     lens = jnp.asarray(np.array([600, 200, 450, 77], np.int32))
-    out_k = jax.jit(PA.paged_attention)(q, kpool, vpool, table, lens)
-    out_x = PA.paged_attention_xla(q, kpool, vpool, table, lens)
+    out_k = jax.jit(PA.paged_attention)(q, kpool, vpool, 1, table, lens)
+    out_x = PA.paged_attention_xla(q, kpool, vpool, 1, table, lens)
     np.testing.assert_allclose(
         np.asarray(out_k, np.float32), np.asarray(out_x, np.float32),
         atol=3e-2, rtol=3e-2)
