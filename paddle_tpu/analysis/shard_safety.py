@@ -14,7 +14,7 @@ each call binds (literal ``axis_names={...}`` or the literal axis tuple
 of the ``Mesh`` the ``mesh=`` argument refers to), and marks those
 bodies — plus same-module helpers they call — as mapped.  Only *string
 literal* axis arguments are judged: the repo's helper convention passes
-the axis as a parameter (``def _ffn_tp(w, h, axis): ... psum(part,
+the axis as a parameter (``def _psum(part, axis): ... psum(part,
 axis)``), which is deliberate indirection the caller owns, so
 parameter/closure axes are never flagged.
 

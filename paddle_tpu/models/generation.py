@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.pallas.lora_matmul import lora_delta
+from ..ops.pallas.paged_attention import PagedKV
 from .llama import LlamaConfig, _rope_tables, _rotate_half
 from .llama_hybrid import _rms
 
@@ -150,27 +152,53 @@ def _layer_weights(state, i):
     return w
 
 
-def _qkv_proj(w, h, nh, kvh, hd, lora=(), aidx=None, li=0):
-    """(q, k, v) projections — one fused GEMV when the quantized state
-    provides it, three matmuls otherwise.  A non-empty ``lora`` bank
-    adds each slot's rank-r adapter delta on top (``aidx`` indexes the
-    bank per row; ``lora=()`` is the dense path, byte-identical jaxpr
-    — zero extra pytree leaves, no traced ops)."""
+def _qkv_proj(w, h, cfg, lora=(), aidx=None, li=0):
+    """(q, k, v) projections, flat — one fused GEMV when the quantized
+    state provides it (split by the config's head counts: one chip
+    only, the mesh runner refuses fused states), three matmuls
+    otherwise, whose widths are this shard's own (callers read their
+    head counts off them: ``q.shape[-1] // head_dim``).  A non-empty
+    ``lora`` bank adds each slot's rank-r adapter delta on top (``aidx``
+    indexes the bank per row; ``lora=()`` is the dense path,
+    byte-identical jaxpr — zero extra pytree leaves, no traced ops); the
+    bank's B for q/k/v is column-sharded like the base weights, so the
+    deltas land on this shard's own heads."""
     if "qkv" in w:
+        nq = cfg.num_attention_heads * cfg.head_dim
+        nkv = cfg.num_key_value_heads * cfg.head_dim
         qkv = _mm(h, w["qkv"])
-        q, k, v = (qkv[..., :nh * hd], qkv[..., nh * hd:(nh + kvh) * hd],
-                   qkv[..., (nh + kvh) * hd:])
+        q, k, v = qkv[..., :nq], qkv[..., nq:nq + nkv], qkv[..., nq + nkv:]
     else:
         q, k, v = _mm(h, w["q"]), _mm(h, w["k"]), _mm(h, w["v"])
     if lora:
-        from ..ops.pallas.lora_matmul import lora_delta
         q = q + lora_delta(lora, "q", li, h, aidx)
         k = k + lora_delta(lora, "k", li, h, aidx)
         v = v + lora_delta(lora, "v", li, h, aidx)
     return q, k, v
 
 
-def _ffn(w, h, lora=(), aidx=None, li=0):
+def _psum(part, axis):
+    """A row-sharded projection's partial product, summed over the mesh
+    axis ``axis``; the whole product itself on one chip (``None``)."""
+    return part if axis is None else jax.lax.psum(part, axis)
+
+
+def _out_proj(w, attn, axis, lora, aidx, li):
+    """``o`` on the heads' outputs.  The adapter's A for ``o`` is
+    row-sharded like the base weight, so its partial delta joins the
+    same all-reduce."""
+    o = _mm(attn, w["o"])
+    if lora:
+        o = o + lora_delta(lora, "o", li, attn, aidx)
+    return _psum(o, axis)
+
+
+def _ffn(w, h, axis=None, lora=(), aidx=None, li=0):
+    """SwiGLU: column-sharded gate/up (one fused product where the
+    state has it), row-sharded down.  The down adapter's A is
+    row-sharded like the base weight, so its partial delta joins the
+    SAME all-reduce (contraction splits linearly) — LoRA adds zero
+    collectives."""
     if "gateup" in w:
         gu = _mm(h, w["gateup"])
         half = gu.shape[-1] // 2
@@ -178,14 +206,13 @@ def _ffn(w, h, lora=(), aidx=None, li=0):
     else:
         g, u = _mm(h, w["gate"]), _mm(h, w["up"])
     if lora:
-        from ..ops.pallas.lora_matmul import lora_delta
         g = g + lora_delta(lora, "gate", li, h, aidx)
         u = u + lora_delta(lora, "up", li, h, aidx)
     act = jax.nn.silu(g) * u
     out = _mm(act, w["down"])
     if lora:
         out = out + lora_delta(lora, "down", li, act, aidx)
-    return out
+    return _psum(out, axis)
 
 
 def _rope_at(cos, sin, pos):
@@ -193,19 +220,47 @@ def _rope_at(cos, sin, pos):
     return jnp.take(cos, pos, axis=0), jnp.take(sin, pos, axis=0)
 
 
-# ---------------------------------------------------------------- prefill
-def _prefill_layer(w, x, cos, sin, mask, cfg: LlamaConfig, lora=(),
-                   aidx=None, li=0):
-    """x: [B, S, H]; returns (out, k_cache, v_cache [B, S, kvH, D])."""
+# ------------------------------------------------------- the layer bodies
+# One Llama-family decoder layer, written twice: over a prompt
+# (``prefill_layer``) and over one token a slot against the paged cache
+# (``decode_layer``).  Both serve one chip (``axis=None``) and a shard
+# of a tensor-parallel mesh (``axis`` the mesh axis name, inside a
+# ``shard_map``):
+#
+#   * q/k/v, gate and up are column-sharded — each device projects its
+#     own ``nh/tp`` query heads, ``kvh/tp`` KV heads and ``I/tp`` FFN
+#     columns, so local head counts come from the projected widths;
+#   * attention is head-parallel (each head's softmax sees its whole
+#     sequence locally — the pools are sharded on the head axis, not
+#     the token axis), so no collective runs inside attention;
+#   * o and down are row-sharded; their partial products are the ONLY
+#     two all-reduce points per layer (``psum`` over ``axis``), exactly
+#     where Megatron-style TP places them.
+#
+# Every matmul routes through ``_mm``: ``QuantizedWeight`` leaves (int8 /
+# int4 + per-output-channel scale) take the weight-only matmul, plain
+# arrays the ``@`` they always did.  The scopes (``attn.qkv``,
+# ``kv.write``, ``attn.decode`` / ``attn.prefill``, ``attn.out``,
+# ``mlp``) are what the benchmark's breakdown names device time by.
+def prefill_layer(w, x, cos, sin, mask, cfg: LlamaConfig, *, li,
+                  axis=None, lora=(), aidx=None, prefix=None):
+    """x: [B, S, H]; cos/sin [S, D] at the rows' positions.  Returns
+    (out, k, v [B, S, kvH, D]) — the caller owns the cache and writes
+    k/v (keys already rotary-encoded, as every reader expects them).
+
+    Without ``prefix`` the rows are a whole prompt: ``mask`` [B, S] is
+    its key padding and attention is causal.  ``prefix=(kpre, vpre)``
+    [B, Tpre, kvH, D] is a resident prefix already gathered from the
+    cache for this layer: the rows attend it ahead of their own keys and
+    ``mask`` [1, 1, S, Tpre + S] says everything (no causal flag)."""
     b, s, _ = x.shape
-    nh, kvh, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                   cfg.head_dim)
+    hd = cfg.head_dim
     with jax.named_scope("attn.qkv"):
         h = _rms(x, w["ln1"], cfg.rms_norm_eps)
-        qp, kp, vp = _qkv_proj(w, h, nh, kvh, hd, lora, aidx, li)
-        q = qp.reshape(b, s, nh, hd)
-        k = kp.reshape(b, s, kvh, hd)
-        v = vp.reshape(b, s, kvh, hd)
+        qp, kp, vp = _qkv_proj(w, h, cfg, lora, aidx, li)
+        q = qp.reshape(b, s, -1, hd)
+        k = kp.reshape(b, s, -1, hd)
+        v = vp.reshape(b, s, -1, hd)
         cos_c = cos[None, :, None, :].astype(q.dtype)
         sin_c = sin[None, :, None, :].astype(q.dtype)
         q = q * cos_c + _rotate_half(q) * sin_c
@@ -215,31 +270,73 @@ def _prefill_layer(w, x, cos, sin, mask, cfg: LlamaConfig, lora=(),
         # flash path: causal + key-padding mask, GQA in-kernel, O(S) memory
         # (the naive [B,H,S,S] fp32 logits OOM long-prompt prefill)
         from ..ops.pallas.flash_attention import sdpa
-        attn = sdpa(q, k, v, attn_mask=mask[:, None, None, :],
-                    is_causal=True).reshape(b, s, nh * hd)
+        if prefix is None:
+            attn = sdpa(q, k, v, attn_mask=mask[:, None, None, :],
+                        is_causal=True)
+        else:
+            kcat = jnp.concatenate([prefix[0].astype(k.dtype), k], axis=1)
+            vcat = jnp.concatenate([prefix[1].astype(v.dtype), v], axis=1)
+            attn = sdpa(q, kcat, vcat, attn_mask=mask, is_causal=False)
+        attn = attn.reshape(b, s, qp.shape[-1])
     with jax.named_scope("attn.out"):
-        o = _mm(attn, w["o"])
-        if lora:
-            from ..ops.pallas.lora_matmul import lora_delta
-            o = o + lora_delta(lora, "o", li, attn, aidx)
-        x = x + o
+        x = x + _out_proj(w, attn, axis, lora, aidx, li)
     with jax.named_scope("mlp"):
         h = _rms(x, w["ln2"], cfg.rms_norm_eps)
-        return (x + _ffn(w, h, lora, aidx, li), k, v)
+        return (x + _ffn(w, h, axis, lora, aidx, li), k, v)
 
 
-# ------------------------------------------------------------ decode step
+def decode_layer(w, x, cache, table, cos1, sin1, pos, cfg: LlamaConfig, *,
+                 li, axis=None, lora=(), aidx=None):
+    """Paged-cache decode layer ``li``: x [B, H] one token a row;
+    ``cache`` a :class:`~paddle_tpu.ops.pallas.paged_attention.PagedKV`,
+    every layer's pools, passed whole and returned whole — the row
+    write and the attention both take the layer as an index, so donated
+    pools are updated where they lie.  table [B, max_pages]; pos [B] is
+    the CURRENT token's position.  The write targets page
+    table[b, pos // ps] slot pos % ps — always a real reserved page;
+    reads go through the cache (reference
+    block_multi_head_attention_kernel.cu).  Returns (out, cache)."""
+    b = x.shape[0]
+    hd = cfg.head_dim
+    ps = cache.k.shape[3]
+    with jax.named_scope("attn.qkv"):
+        h = _rms(x[:, None], w["ln1"], cfg.rms_norm_eps)[:, 0]
+        qp, kp, vp = _qkv_proj(w, h, cfg, lora, aidx, li)
+        q = qp.reshape(b, -1, hd)
+        k = kp.reshape(b, -1, hd)
+        v = vp.reshape(b, -1, hd)
+        cos_c = cos1[:, None, :].astype(q.dtype)
+        sin_c = sin1[:, None, :].astype(q.dtype)
+        q = q * cos_c + _rotate_half(q) * sin_c
+        k = k * cos_c + _rotate_half(k) * sin_c
+
+    with jax.named_scope("kv.write"):
+        page = jnp.take_along_axis(table, (pos // ps)[:, None], axis=1)[:, 0]
+        cache = cache.write(li, page, pos % ps, k, v)
+
+    with jax.named_scope("attn.decode"):
+        attn = cache.attend(q, li, table, pos + 1, axis).reshape(
+            b, qp.shape[-1])
+    with jax.named_scope("attn.out"):
+        x = x + _out_proj(w, attn, axis, lora, aidx, li)
+    with jax.named_scope("mlp"):
+        h = _rms(x[:, None], w["ln2"], cfg.rms_norm_eps)[:, 0]
+        return x + _ffn(w, h, axis, lora, aidx, li), cache
+
+
+# ------------------------------------------- decode step, contiguous cache
 def _decode_layer(w, x, kcache, vcache, cos1, sin1, pos, cfg: LlamaConfig):
     """x: [B, H] one token; kcache/vcache: [B, kvH, T, D] (kv-head-major,
-    the decode kernel's tiling-friendly layout); pos: [B]."""
+    the decode kernel's tiling-friendly layout); pos: [B].  The cache of
+    ``generate``'s dense and beam programs, and the reference the paged
+    path is compared with."""
     b = x.shape[0]
-    nh, kvh, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                   cfg.head_dim)
+    hd = cfg.head_dim
     h = _rms(x[:, None], w["ln1"], cfg.rms_norm_eps)[:, 0]
-    qp, kp, vp = _qkv_proj(w, h, nh, kvh, hd)
-    q = qp.reshape(b, nh, hd)
-    k = kp.reshape(b, kvh, hd)
-    v = vp.reshape(b, kvh, hd)
+    qp, kp, vp = _qkv_proj(w, h, cfg)
+    q = qp.reshape(b, -1, hd)
+    k = kp.reshape(b, -1, hd)
+    v = vp.reshape(b, -1, hd)
     cos_c = cos1[:, None, :].astype(q.dtype)
     sin_c = sin1[:, None, :].astype(q.dtype)
     q = q * cos_c + _rotate_half(q) * sin_c
@@ -257,67 +354,10 @@ def _decode_layer(w, x, kcache, vcache, cos1, sin1, pos, cfg: LlamaConfig):
     # blockwise cache attention kernel (ops/pallas/decode_attention.py);
     # transparently falls back to the einsum path off-TPU
     from ..ops.pallas.decode_attention import decode_attention
-    attn = decode_attention(q, kcache, vcache, pos).reshape(b, nh * hd)
+    attn = decode_attention(q, kcache, vcache, pos).reshape(b, qp.shape[-1])
     x = x + _mm(attn, w["o"])
     h = _rms(x[:, None], w["ln2"], cfg.rms_norm_eps)[:, 0]
     return (x + _ffn(w, h), kcache, vcache)
-
-
-# ------------------------------------------------------- paged decode step
-def _decode_layer_paged(w, x, kpool, vpool, table, cos1, sin1, pos,
-                        cfg: LlamaConfig, lora=(), aidx=None, *, li):
-    """Paged-cache decode layer ``li``: pools [L, P, kvH, ps, D],
-    every layer's, passed whole and returned whole — the row scatter
-    and the kernel both take the layer as an index, so a donated pool
-    is updated where it lies.  table [B, max_pages]; pos [B] is the
-    CURRENT token's position.  The write targets page
-    table[b, pos // ps] slot pos % ps — always a real reserved page;
-    reads go through the paged kernel (reference
-    block_multi_head_attention_kernel.cu)."""
-    b = x.shape[0]
-    nh, kvh, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                   cfg.head_dim)
-    ps = kpool.shape[3]
-    with jax.named_scope("attn.qkv"):
-        h = _rms(x[:, None], w["ln1"], cfg.rms_norm_eps)[:, 0]
-        qp, kp, vp = _qkv_proj(w, h, nh, kvh, hd, lora, aidx, li)
-        q = qp.reshape(b, nh, hd)
-        k = kp.reshape(b, kvh, hd)
-        v = vp.reshape(b, kvh, hd)
-        cos_c = cos1[:, None, :].astype(q.dtype)
-        sin_c = sin1[:, None, :].astype(q.dtype)
-        q = q * cos_c + _rotate_half(q) * sin_c
-        k = k * cos_c + _rotate_half(k) * sin_c
-
-    with jax.named_scope("kv.write"):
-        page = jnp.take_along_axis(table, (pos // ps)[:, None], axis=1)[:, 0]
-        off = pos % ps
-        idx = (li, page[:, None], jnp.arange(kvh)[None, :], off[:, None])
-        kpool = kpool.at[idx].set(k)
-        vpool = vpool.at[idx].set(v)
-
-    with jax.named_scope("attn.decode"):
-        from ..ops.pallas.paged_attention import select_paged_attention
-        attn = select_paged_attention()(
-            q, kpool, vpool, li, table, pos + 1).reshape(b, nh * hd)
-    with jax.named_scope("attn.out"):
-        o = _mm(attn, w["o"])
-        if lora:
-            from ..ops.pallas.lora_matmul import lora_delta
-            o = o + lora_delta(lora, "o", li, attn, aidx)
-        x = x + o
-    with jax.named_scope("mlp"):
-        h = _rms(x[:, None], w["ln2"], cfg.rms_norm_eps)[:, 0]
-        g = _mm(h, w["gate"])
-        u = _mm(h, w["up"])
-        if lora:
-            g = g + lora_delta(lora, "gate", li, h, aidx)
-            u = u + lora_delta(lora, "up", li, h, aidx)
-        act = jax.nn.silu(g) * u
-        d = _mm(act, w["down"])
-        if lora:
-            d = d + lora_delta(lora, "down", li, act, aidx)
-        return (x + d, kpool, vpool)
 
 
 # --------------------------------------------------------------- sampling
@@ -375,8 +415,8 @@ def build_generate_fn_paged(config: LlamaConfig, gen: GenerationConfig,
         spad = prompt_pages * ps - prompt_len
         for i in range(L):
             w = _layer_weights(state, i)
-            x, k, v = _prefill_layer(w, x, cos[:prompt_len],
-                                     sin[:prompt_len], pmask, config)
+            x, k, v = prefill_layer(w, x, cos[:prompt_len],
+                                    sin[:prompt_len], pmask, config, li=i)
             kp = jnp.pad(k, ((0, 0), (0, spad), (0, 0), (0, 0)))
             vp = jnp.pad(v, ((0, 0), (0, spad), (0, 0), (0, 0)))
             for p in range(prompt_pages):
@@ -404,28 +444,27 @@ def build_generate_fn_paged(config: LlamaConfig, gen: GenerationConfig,
             done = done | (tok == gen.eos_token_id)
 
         def step(carry, key_t):
-            tok, pos, kpool, vpool, done = carry
+            tok, pos, cache, done = carry
             emb = jnp.take(state["llama.embed_tokens.weight"], tok,
                            axis=0)
             cos1, sin1 = _rope_at(cos, sin, pos)
             h = emb
             for i in range(L):
                 w = _layer_weights(state, i)
-                h, kpool, vpool = _decode_layer_paged(
-                    w, h, kpool, vpool, table, cos1, sin1, pos, config,
-                    li=i)
+                h, cache = decode_layer(w, h, cache, table, cos1, sin1,
+                                        pos, config, li=i)
             h = _rms(h[:, None], state["llama.norm.weight"],
                      config.rms_norm_eps)[:, 0]
             nxt = _sample(logits_of(h), key_t, gen)
             if gen.eos_token_id is not None:
                 nxt = jnp.where(done, gen.pad_token_id, nxt)
                 done = done | (nxt == gen.eos_token_id)
-            return (nxt, pos + 1, kpool, vpool, done), tok
+            return (nxt, pos + 1, cache, done), tok
 
         keys = jax.random.split(key, gen.max_new_tokens)
-        (tok, _, _, _, _), toks = jax.lax.scan(
+        (tok, _, _, _), toks = jax.lax.scan(
             step, (tok.astype(ids.dtype), lengths.astype(jnp.int32),
-                   kpool, vpool, done), keys)
+                   PagedKV(kpool, vpool), done), keys)
         return jnp.concatenate([ids, toks.T.astype(ids.dtype)], axis=1)
 
     return jax.jit(run)
@@ -453,8 +492,8 @@ def _prefill_prompt(state, ids, lengths, cos, sin, config, prompt_len, T):
     kcaches, vcaches = [], []
     for i in range(L):
         w = _layer_weights(state, i)
-        x, k, v = _prefill_layer(w, x, cos[:prompt_len],
-                                 sin[:prompt_len], pmask, config)
+        x, k, v = prefill_layer(w, x, cos[:prompt_len],
+                                sin[:prompt_len], pmask, config, li=i)
         # kv-head-major cache layout [B, kvH, T, D]
         pad = ((0, 0), (0, 0), (0, T - prompt_len), (0, 0))
         kcaches.append(jnp.pad(k.swapaxes(1, 2), pad))

@@ -38,6 +38,7 @@ tokens visible per sequence.  Inference-only (no VJP).
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -45,7 +46,7 @@ import numpy as np
 
 from .flash_attention import NUM_LANES
 
-__all__ = ["paged_attention", "pages_per_block", "PagedPool",
+__all__ = ["paged_attention", "pages_per_block", "PagedPool", "PagedKV",
            "select_paged_attention",
            "gather_kv_pages", "quantize_kv_rows", "gather_scale_pages",
            "gather_kv_pages_quant", "paged_attention_quant"]
@@ -373,6 +374,84 @@ def paged_attention_quant(q, kpool, vpool, kscale, vscale, layer, table,
     logits = jnp.where(valid, logits, -jnp.inf)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bht,bhtd->bhd", probs, vq)
+
+
+class PagedKV(NamedTuple):
+    """A program's K/V pools as one value that knows its page format.
+
+    ``k``/``v`` [L, P, kvH, page_size, D], every layer's, whole.  Plain
+    pages hold the model's dtype and ``kscale``/``vscale`` are the empty
+    tuple (no leaves: a program that carries this value has the plain
+    program's arguments exactly); int8 pages come with f32 scale pools
+    [L, P, kvH, page_size], one scale a (token, head).  A layer body
+    writes, attends and gathers through the methods and knows neither
+    format; each returns or reads the pools whole, so donated pools are
+    updated where they lie."""
+    k: jax.Array
+    v: jax.Array
+    kscale: jax.Array | tuple = ()
+    vscale: jax.Array | tuple = ()
+
+    @property
+    def quantized(self) -> bool:
+        return not isinstance(self.kscale, tuple)
+
+    def write(self, layer, page, off, k, v):
+        """Rows ``k``/``v`` [N, kvH, D] to ``[layer, page[n], head,
+        off[n]]`` (``page``/``off`` [N]), quantized on the way where the
+        pages are int8 — in the same traced step, no host sync."""
+        if self.quantized:
+            k, sk = quantize_kv_rows(k)
+            v, sv = quantize_kv_rows(v)
+        idx = (layer, page[:, None], jnp.arange(k.shape[1])[None, :],
+               off[:, None])
+        kp, vp = self.k.at[idx].set(k), self.v.at[idx].set(v)
+        if not self.quantized:
+            return PagedKV(kp, vp)
+        return PagedKV(kp, vp, self.kscale.at[idx].set(sk),
+                       self.vscale.at[idx].set(sv))
+
+    def write_pages(self, layer, pages, k, v):
+        """One prompt's rows ``k``/``v`` [1, n * page_size, kvH, D] as
+        whole pages ``pages`` [n] of ``layer``, a scatter a page."""
+        ps = self.k.shape[3]
+        kp, vp, ks, vs = self
+        if self.quantized:
+            k, sk = quantize_kv_rows(k)
+            v, sv = quantize_kv_rows(v)
+        for p in range(pages.shape[0]):
+            sl = slice(p * ps, (p + 1) * ps)
+            rows_k = k[0, sl].swapaxes(0, 1)
+            rows_v = v[0, sl].swapaxes(0, 1)
+            kp = kp.at[layer, pages[p]].set(rows_k.astype(kp.dtype))
+            vp = vp.at[layer, pages[p]].set(rows_v.astype(vp.dtype))
+            if self.quantized:
+                ks = ks.at[layer, pages[p]].set(sk[0, sl].swapaxes(0, 1))
+                vs = vs.at[layer, pages[p]].set(sv[0, sl].swapaxes(0, 1))
+        return PagedKV(kp, vp, ks, vs)
+
+    def attend(self, q, layer, table, lens, axis=None):
+        """Decode attention of ``q`` [B, nh, D] over ``layer``'s pages:
+        the paged kernel (plain pages; its XLA twin off the TPU) or the
+        dequantizing gather.  ``axis`` names the mesh axis of a
+        head-parallel caller."""
+        if self.quantized:
+            return paged_attention_quant(q, *self, layer, table, lens,
+                                         tp_axis=axis)
+        return select_paged_attention(tp_axis=axis)(
+            q, self.k, self.v, layer, table, lens)
+
+    def gather(self, layer, row, dtype):
+        """The pages of table row ``row`` [W] in ``layer``, token-major:
+        (k, v), each [W * page_size, kvH, D], int8 pages dequantized to
+        ``dtype``."""
+        if self.quantized:
+            return (gather_kv_pages_quant(self.k[layer],
+                                          self.kscale[layer], row, dtype),
+                    gather_kv_pages_quant(self.v[layer],
+                                          self.vscale[layer], row, dtype))
+        return (gather_kv_pages(self.k[layer], row),
+                gather_kv_pages(self.v[layer], row))
 
 
 class PagedPool:

@@ -8,11 +8,11 @@ the reference block_multi_head_attention serving path):
     slots and one shared page pool.  Slot occupancy, positions, and
     block tables are *data* (int32 arrays), never shapes — admitting or
     evicting a request between steps re-traces nothing.  The step
-    reuses ``_decode_layer_paged`` from ``models/generation.py``
-    verbatim, so engine numerics match the one-shot
-    ``build_generate_fn_paged`` token for token under greedy decoding.
+    runs ``decode_layer`` of ``models/generation.py``, the layer body
+    of the one-shot ``build_generate_fn_paged``, so engine numerics
+    match it token for token under greedy decoding.
   * prefill-on-admit: an admitted request's prompt runs through
-    ``_prefill_layer`` (padded to a page-multiple bucket; one trace per
+    ``prefill_layer`` (padded to a page-multiple bucket; one trace per
     bucket) and pages its KV straight into the shared pool; the token
     sampled from the prompt's last logits is the request's first output
     (its TTFT mark).  With ``enable_prefix_cache=True`` the admission

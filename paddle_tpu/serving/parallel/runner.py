@@ -12,9 +12,8 @@ lifecycle.
 Two construction modes, selected by ``tp``:
 
 ``tp == 1``
-    The exact single-chip programs the engine owned before the runner
-    seam existed — no mesh, no ``shard_map``, no ``device_put`` — so
-    ``mesh_shape=(1,)`` reduces bit-for-bit to the previous behavior.
+    The programs jitted as they are — no mesh, no ``shard_map``, no
+    ``device_put``.
 
 A model description whose ``family`` is ``"deepseek_v3"`` keeps the
 seam and changes the cache: one pool of latent rows, and the programs of
@@ -28,8 +27,17 @@ seam and changes the cache: one pool of latent rows, and the programs of
     (``[L, pages+1, kvh/tp, page_size, hd]`` per device) so the
     BlockManager's page table stays host-side and mesh-agnostic.  All
     four jit families run as ``shard_map`` computations whose only
-    collectives are the attention-output and FFN-down ``psum``s
-    (see ``layers.py``).
+    collectives are the attention-output and FFN-down ``psum``s.
+
+Both modes build ONE body a program (``_build_step(axis)``,
+``_build_verify(axis)``, ``_prefill_fn``, ``_prefill_cached_fn``) around
+the two layer bodies of ``models/generation.py``, ``decode_layer`` and
+``prefill_layer``, which take the mesh axis (``None`` on one chip) and
+say where the two all-reduces sit.  Plain or int8 pages is the cache
+value's business: a program makes one ``PagedKV``
+(``ops/pallas/paged_attention.py``) of its four pool arguments, the
+bodies write, attend and gather through it, and the program returns its
+four members.
 
 The engine's serving invariants carry over unchanged: slot occupancy /
 positions / tables are data (ONE decode trace per engine lifetime —
@@ -37,12 +45,13 @@ positions / tables are data (ONE decode trace per engine lifetime —
 step, and admissions/evictions patch single slot rows in place.
 
 The pools go through every program WHOLE: a layer body takes
-``[L, pages+1, kvh, page_size, hd]`` and its layer's index, scatters the
-step's rows at ``[li, page, head, off]``, hands the same array and ``li``
-to the paged kernel and returns it.  A layer sliced out for the kernel or
-layers stacked back would each copy the pool; as it is the donated pools
-are updated where they lie and the decode and verify programs declare no
-pool-sized temporary (``tests/test_chip_compile.py`` holds them to it).
+``[L, pages+1, kvh, page_size, hd]`` and its layer's index, the cache
+scatters the step's rows at ``[li, page, head, off]``, hands the same
+array and ``li`` to the paged kernel and returns it.  A layer sliced out
+for the kernel or layers stacked back would each copy the pool; as it is
+the donated pools are updated where they lie and the decode and verify
+programs declare no pool-sized temporary (``tests/test_chip_compile.py``
+holds them to it).
 """
 from __future__ import annotations
 
@@ -54,18 +63,13 @@ import numpy as np
 
 from ... import observability as _obs
 from ...observability.resources import record_compile, resource_tracker
-from ...models.generation import (_decode_layer_paged, _ffn,
-                                  _layer_weights, _mm, _prefill_layer,
-                                  _qkv_proj, _rope_at)
-from ...models.llama import _rope_tables, _rotate_half
+from ...models.generation import (_layer_weights, _mm, _rope_at,
+                                  decode_layer, prefill_layer)
+from ...models.llama import _rope_tables
 from ...models.llama_hybrid import _rms
-from ...ops.pallas.paged_attention import (gather_kv_pages,
-                                           quantize_kv_rows)
+from ...ops.pallas.paged_attention import PagedKV
 from ...ops.pallas.quant_matmul import QuantizedWeight
 from . import latent
-from .layers import (decode_layer_paged_quant, decode_layer_paged_tp,
-                     prefill_layer_cached_quant, prefill_layer_cached_tp,
-                     prefill_layer_tp)
 from .mesh import TP_AXIS, mesh_devices, validate_tp
 
 __all__ = ["ModelRunner"]
@@ -141,7 +145,8 @@ class ModelRunner:
         # no-adapter row 0, one static rank axis.  lora_slots == 0 is
         # the off mode: the bank and the per-slot index vector are
         # empty tuples — zero pytree leaves in every jitted signature,
-        # so the dense jaxprs stay byte-identical (the kv_quant trick).
+        # and the bodies trace no adapter op (as the scale pools of
+        # plain pages: see "jitted bodies" below).
         self.lora_slots = int(lora_slots)
         self.lora_rank = int(lora_rank)
         if self.lora_slots and self.lora_rank < 1:
@@ -177,9 +182,9 @@ class ModelRunner:
             cos, sin = _rope_tables(self._rope_len, hd, config.rope_theta)
         # int8 KV page mode: pools store int8, one f32 scale per
         # (layer, page row, head, slot) rides in separate scale pools.
-        # Dense mode keeps EXACTLY the old arrays — the scale members
-        # become empty tuples, which contribute zero pytree leaves to
-        # every jitted signature, so the dense jaxprs are unchanged.
+        # Plain pages have no scale pools: the members are empty
+        # tuples, zero pytree leaves in every jitted signature, and
+        # that absence is how the cache value knows its format.
         pool_dtype = jnp.int8 if self.kv_quant else dtype
         cos = cos.astype(jnp.float32)
         sin = sin.astype(jnp.float32)
@@ -520,20 +525,19 @@ class ModelRunner:
     # ------------------------------------------------------- jitted bodies
     # Every jitted signature threads (kscale, vscale) right after the
     # pools, and (lora, aidx) at the tail.  Off modes pass the empty
-    # tuples stored at construction: zero pytree leaves, so the
-    # flattened argument list — and therefore the jaxpr — is
-    # byte-identical to the pre-quant / pre-LoRA program.  The
+    # tuples stored at construction: zero pytree leaves, so an option
+    # that is off adds no argument and no op to the program.  The
     # shard_map specs use P() for those positions (a pspec broadcasts
     # over an empty subtree).
     def _make_step_fn(self):
         if self.tp == 1:
-            return jax.jit(self._build_step(),
+            return jax.jit(self._build_step(None),
                            donate_argnums=(1, 2, 3, 4, 6, 7, 9, 10, 15))
         from jax.sharding import PartitionSpec as P
         pool = self._pool_pspec
         sspec = self._scale_pspec if self.kv_quant else P()
         mapped = jax.shard_map(
-            self._build_step_tp(), mesh=self.mesh,
+            self._build_step(TP_AXIS), mesh=self.mesh,
             in_specs=(self._state_specs(), pool, pool, sspec, sspec,
                       P(), P(), P(), P(), P(), P(), P(), P(),
                       self._lora_pspecs(), P(), P()),
@@ -543,11 +547,17 @@ class ModelRunner:
         return jax.jit(mapped, donate_argnums=(1, 2, 3, 4, 6, 7, 9, 10))
 
     def _count_step_trace(self):
-        """Runs when a decode step is traced, never when it runs."""
+        """Runs when a decode step is traced, never when it runs: a
+        second count means an admission/eviction re-traced the step."""
         self.decode_traces += 1
         _M_STEP_TRACES.inc()
 
-    def _build_step(self):
+    def _build_step(self, axis):
+        """The decode step's body: on one chip (``axis=None``) the jitted
+        program, on a mesh the ``shard_map`` body.  There everything
+        except the pools is replicated; the head-parallel layers psum at
+        the o/down projections, so the post-norm logits (and therefore
+        the argmax'd next token and the ring) are device-invariant."""
         if self.latent:
             return latent.build_step(self, self._count_step_trace)
         cfg = self.config
@@ -555,16 +565,13 @@ class ModelRunner:
         emit_logits = self.emit_logits
         rope_len = self._rope_len
         wide_ring = self.spec_k > 0
-        kv_quant = self.kv_quant
-        runner = self
+        count_trace = self._count_step_trace
 
         def decode_step(state, kpool, vpool, kscale, vscale, table, pos,
                         tok, active, ring, ridx, cos, sin, lora, aidx,
                         counters):
-            # python body runs at trace time only: a second execution of
-            # this line means an admission/eviction re-traced the step
-            runner.decode_traces += 1
-            _M_STEP_TRACES.inc()
+            count_trace()
+            cache = PagedKV(kpool, vpool, kscale, vscale)
             # a finished slot keeps decoding until the next host sync
             # (deferred-sync overrun); clamp so its rope/table lookups
             # stay in range — overrun writes land in the slot's own
@@ -576,16 +583,9 @@ class ModelRunner:
                 cos1, sin1 = _rope_at(cos, sin, posc)
             h = emb
             for i in range(L):
-                w = _layer_weights(state, i)
-                if kv_quant:
-                    (h, kpool, vpool, kscale,
-                     vscale) = decode_layer_paged_quant(
-                        w, h, kpool, vpool, kscale, vscale, table, cos1,
-                        sin1, posc, cfg, None, lora, aidx, li=i)
-                else:
-                    h, kpool, vpool = _decode_layer_paged(
-                        w, h, kpool, vpool, table, cos1, sin1, posc,
-                        cfg, lora, aidx, li=i)
+                h, cache = decode_layer(
+                    _layer_weights(state, i), h, cache, table, cos1, sin1,
+                    posc, cfg, li=i, axis=axis, lora=lora, aidx=aidx)
             with jax.named_scope("head"):
                 h = _rms(h[:, None], state["llama.norm.weight"],
                          cfg.rms_norm_eps)[:, 0]
@@ -597,73 +597,21 @@ class ModelRunner:
                 ring2 = (ring.at[ridx, :, 0].set(nxt) if wide_ring
                          else ring.at[ridx].set(nxt))
                 ridx2 = (ridx + 1) % ring.shape[0]
-            return (kpool, vpool, kscale, vscale, pos2, tok2, ring2,
-                    ridx2, logits if emit_logits
-                    else jnp.zeros((), jnp.float32), counters)
-
-        return decode_step
-
-    def _build_step_tp(self):
-        """The shard_map body: same step, per-shard layers.  Everything
-        except the pools is replicated; the head-parallel layers psum at
-        the o/down projections, so the post-norm logits (and therefore
-        the argmax'd next token and the ring) are device-invariant."""
-        cfg = self.config
-        L = cfg.num_hidden_layers
-        emit_logits = self.emit_logits
-        rope_len = self._rope_len
-        wide_ring = self.spec_k > 0
-        kv_quant = self.kv_quant
-        runner = self
-
-        def decode_step(state, kpool, vpool, kscale, vscale, table, pos,
-                        tok, active, ring, ridx, cos, sin, lora, aidx,
-                        counters):
-            runner.decode_traces += 1
-            _M_STEP_TRACES.inc()
-            with jax.named_scope("embed"):
-                posc = jnp.minimum(pos, rope_len - 1)
-                emb = jnp.take(state["llama.embed_tokens.weight"], tok,
-                               axis=0)
-                cos1, sin1 = _rope_at(cos, sin, posc)
-            h = emb
-            for i in range(L):
-                w = _layer_weights(state, i)
-                if kv_quant:
-                    (h, kpool, vpool, kscale,
-                     vscale) = decode_layer_paged_quant(
-                        w, h, kpool, vpool, kscale, vscale, table, cos1,
-                        sin1, posc, cfg, TP_AXIS, lora, aidx, li=i)
-                else:
-                    h, kpool, vpool = decode_layer_paged_tp(
-                        w, h, kpool, vpool, table, cos1, sin1, posc,
-                        cfg, TP_AXIS, lora, aidx, li=i)
-            with jax.named_scope("head"):
-                h = _rms(h[:, None], state["llama.norm.weight"],
-                         cfg.rms_norm_eps)[:, 0]
-                logits = _logits_of(state, h).astype(jnp.float32)
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                act = active.astype(bool)
-                pos2 = pos + active
-                tok2 = jnp.where(act, nxt, tok)
-                ring2 = (ring.at[ridx, :, 0].set(nxt) if wide_ring
-                         else ring.at[ridx].set(nxt))
-                ridx2 = (ridx + 1) % ring.shape[0]
-            return (kpool, vpool, kscale, vscale, pos2, tok2, ring2,
-                    ridx2, logits if emit_logits
-                    else jnp.zeros((), jnp.float32), counters)
+            return (*cache, pos2, tok2, ring2, ridx2,
+                    logits if emit_logits else jnp.zeros((), jnp.float32),
+                    counters)
 
         return decode_step
 
     def _make_verify_fn(self):
         if self.tp == 1:
-            return jax.jit(self._build_verify(tp=False),
+            return jax.jit(self._build_verify(None),
                            donate_argnums=(1, 2, 3, 4, 6, 7, 9, 10))
         from jax.sharding import PartitionSpec as P
         pool = self._pool_pspec
         sspec = self._scale_pspec if self.kv_quant else P()
         mapped = jax.shard_map(
-            self._build_verify(tp=True), mesh=self.mesh,
+            self._build_verify(TP_AXIS), mesh=self.mesh,
             in_specs=(self._state_specs(), pool, pool, sspec, sspec,
                       P(), P(), P(), P(), P(), P(), P(), P(), P(),
                       P(), self._lora_pspecs(), P()),
@@ -671,7 +619,7 @@ class ModelRunner:
             check_vma=False)
         return jax.jit(mapped, donate_argnums=(1, 2, 3, 4, 6, 7, 9, 10))
 
-    def _build_verify(self, *, tp: bool):
+    def _build_verify(self, axis):
         """The speculative verify program: score ``k+1`` candidate
         positions per slot in ONE step.
 
@@ -702,7 +650,6 @@ class ModelRunner:
         rope_len = self._rope_len
         k = self.spec_k
         M = k + 1
-        kv_quant = self.kv_quant
         runner = self
 
         def verify_step(state, kpool, vpool, kscale, vscale, table, pos,
@@ -713,6 +660,7 @@ class ModelRunner:
             runner.verify_traces += 1
             _M_STEP_TRACES.inc()
             _M_VERIFY_TRACES.inc()
+            cache = PagedKV(kpool, vpool, kscale, vscale)
             with jax.named_scope("embed"):
                 S = tok.shape[0]
                 # [S, M] candidate grid: column 0 is the slot's current
@@ -733,21 +681,10 @@ class ModelRunner:
                 cos1, sin1 = _rope_at(cos, sin, posc)
             h = emb
             for i in range(L):
-                w = _layer_weights(state, i)
-                if kv_quant:
-                    (h, kpool, vpool, kscale,
-                     vscale) = decode_layer_paged_quant(
-                        w, h, kpool, vpool, kscale, vscale, table_f,
-                        cos1, sin1, posc, cfg, TP_AXIS if tp else None,
-                        lora, aidx_f, li=i)
-                elif tp:
-                    h, kpool, vpool = decode_layer_paged_tp(
-                        w, h, kpool, vpool, table_f, cos1, sin1, posc,
-                        cfg, TP_AXIS, lora, aidx_f, li=i)
-                else:
-                    h, kpool, vpool = _decode_layer_paged(
-                        w, h, kpool, vpool, table_f, cos1, sin1, posc,
-                        cfg, lora, aidx_f, li=i)
+                h, cache = decode_layer(
+                    _layer_weights(state, i), h, cache, table_f, cos1,
+                    sin1, posc, cfg, li=i, axis=axis, lora=lora,
+                    aidx=aidx_f)
             with jax.named_scope("head"):
                 h = _rms(h[:, None], state["llama.norm.weight"],
                          cfg.rms_norm_eps)[:, 0]
@@ -767,8 +704,7 @@ class ModelRunner:
                 tok2 = jnp.where(active.astype(bool), tok_new, tok)
                 ring2 = ring.at[ridx].set(y)
                 ridx2 = (ridx + 1) % ring.shape[0]
-            return (kpool, vpool, kscale, vscale, pos2, tok2, ring2,
-                    ridx2)
+            return (*cache, pos2, tok2, ring2, ridx2)
 
         return verify_step
 
@@ -807,54 +743,32 @@ class ModelRunner:
             return fn
         cfg = self.config
         L = cfg.num_hidden_layers
-        ps = self.page_size
-        n_pages = bucket // ps
         tp = self.tp
         kv_quant = self.kv_quant
+        axis = None if tp == 1 else TP_AXIS
 
         def prefill(state, ids, length, table_row, kpool, vpool,
                     kscale, vscale, cos, sin, lora, aidx):
             _M_PREFILL_TRACES.labels(str(bucket)).inc()
+            cache = PagedKV(kpool, vpool, kscale, vscale)
             with jax.named_scope("embed"):
                 x = jnp.take(state["llama.embed_tokens.weight"], ids, axis=0)
                 pmask = jnp.arange(bucket)[None, :] < length
             for i in range(L):
-                w = _layer_weights(state, i)
-                if tp == 1:
-                    x, k, v = _prefill_layer(w, x, cos[:bucket],
-                                             sin[:bucket], pmask, cfg,
-                                             lora, aidx, i)
-                else:
-                    x, k, v = prefill_layer_tp(w, x, cos[:bucket],
-                                               sin[:bucket], pmask, cfg,
-                                               TP_AXIS, lora, aidx, i)
+                x, k, v = prefill_layer(
+                    _layer_weights(state, i), x, cos[:bucket],
+                    sin[:bucket], pmask, cfg, li=i, axis=axis, lora=lora,
+                    aidx=aidx)
                 with jax.named_scope("kv.write"):
-                    if kv_quant:
-                        # quantize the whole prompt's KV once per layer,
-                        # then page the int8 rows + their scales
-                        qk, sk = quantize_kv_rows(k[0])
-                        qv, sv = quantize_kv_rows(v[0])
-                        k, v = qk[None], qv[None]
-                    for p in range(n_pages):
-                        sl = slice(p * ps, (p + 1) * ps)
-                        rows_k = k[0, sl].swapaxes(0, 1)
-                        rows_v = v[0, sl].swapaxes(0, 1)
-                        kpool = kpool.at[i, table_row[p]].set(
-                            rows_k.astype(kpool.dtype))
-                        vpool = vpool.at[i, table_row[p]].set(
-                            rows_v.astype(vpool.dtype))
-                        if kv_quant:
-                            kscale = kscale.at[i, table_row[p]].set(
-                                sk[sl].swapaxes(0, 1))
-                            vscale = vscale.at[i, table_row[p]].set(
-                                sv[sl].swapaxes(0, 1))
+                    # one scatter a page (ROADMAP S12)
+                    cache = cache.write_pages(i, table_row, k, v)
             with jax.named_scope("head"):
                 x = _rms(x, state["llama.norm.weight"], cfg.rms_norm_eps)
                 last = jnp.take_along_axis(
                     x, (length - 1)[:, None, None].astype(jnp.int32),
                     axis=1)[:, 0]
                 logits = _logits_of(state, last).astype(jnp.float32)
-            return kpool, vpool, kscale, vscale, logits
+            return (*cache, logits)
 
         # kpool/vpool donation: prefill updates the pool in place instead
         # of double-buffering the engine's whole KV footprint per admit
@@ -892,18 +806,18 @@ class ModelRunner:
             return fn
         cfg = self.config
         L = cfg.num_hidden_layers
-        kvh_l = cfg.num_key_value_heads // self.tp
         ps = self.page_size
         W = self.table_width
         dump = self.dump_page
         rope_len = self._rope_len
         tp = self.tp
-
         kv_quant = self.kv_quant
+        axis = None if tp == 1 else TP_AXIS
 
         def prefill_cached(state, ids, length, cached_len, row, kpool,
                            vpool, kscale, vscale, cos, sin, lora, aidx):
             _M_PREFILL_TRACES.labels(f"cached:{bucket}").inc()
+            cache = PagedKV(kpool, vpool, kscale, vscale)
             with jax.named_scope("embed"):
                 x = jnp.take(state["llama.embed_tokens.weight"], ids, axis=0)
                 j = jnp.arange(bucket)
@@ -923,46 +837,25 @@ class ModelRunner:
                 page_w = jnp.where(valid,
                                    row[jnp.minimum(absp // ps, W - 1)], dump)
                 off = absp % ps
-                heads = jnp.arange(kvh_l)
-                widx = (page_w[:, None], heads[None, :], off[:, None])
             for i in range(L):
-                w = _layer_weights(state, i)
-                if kv_quant:
-                    x, k, v = prefill_layer_cached_quant(
-                        w, x, kpool[i], vpool[i], kscale[i], vscale[i],
-                        row, cos_s, sin_s, mask, cfg,
-                        TP_AXIS if tp > 1 else None, lora, aidx, i)
-                    with jax.named_scope("kv.write"):
-                        qk, sk = quantize_kv_rows(k[0])
-                        qv, sv = quantize_kv_rows(v[0])
-                        kpool = kpool.at[(i,) + widx].set(qk)
-                        vpool = vpool.at[(i,) + widx].set(qv)
-                        kscale = kscale.at[(i,) + widx].set(sk)
-                        vscale = vscale.at[(i,) + widx].set(sv)
-                    continue
-                if tp == 1:
-                    with jax.named_scope("attn.prefill"):
-                        kpre = gather_kv_pages(kpool[i], row)
-                        vpre = gather_kv_pages(vpool[i], row)
-                    x, k, v = _prefill_layer_cached(
-                        w, x, kpre[None], vpre[None], cos_s, sin_s,
-                        mask, cfg, lora, aidx, i)
-                else:
-                    x, k, v = prefill_layer_cached_tp(
-                        w, x, kpool[i], vpool[i], row, cos_s, sin_s,
-                        mask, cfg, TP_AXIS, lora, aidx, i)
+                # the resident prefix (this shard's heads of it) ahead of
+                # the projections, then the suffix rows where a decode
+                # step would write them
+                with jax.named_scope("attn.prefill"):
+                    kpre, vpre = cache.gather(i, row, x.dtype)
+                x, k, v = prefill_layer(
+                    _layer_weights(state, i), x, cos_s, sin_s, mask, cfg,
+                    li=i, axis=axis, lora=lora, aidx=aidx,
+                    prefix=(kpre[None], vpre[None]))
                 with jax.named_scope("kv.write"):
-                    kpool = kpool.at[i, page_w[:, None], heads[None, :],
-                                     off[:, None]].set(k[0])
-                    vpool = vpool.at[i, page_w[:, None], heads[None, :],
-                                     off[:, None]].set(v[0])
+                    cache = cache.write(i, page_w, off, k[0], v[0])
             with jax.named_scope("head"):
                 x = _rms(x, state["llama.norm.weight"], cfg.rms_norm_eps)
                 last = jnp.take_along_axis(
                     x, (length - 1)[:, None, None].astype(jnp.int32),
                     axis=1)[:, 0]
                 logits = _logits_of(state, last).astype(jnp.float32)
-            return kpool, vpool, kscale, vscale, logits
+            return (*cache, logits)
 
         if tp == 1:
             fn = jax.jit(prefill_cached, donate_argnums=(5, 6, 7, 8))
@@ -1206,47 +1099,6 @@ class ModelRunner:
             devices.append(entry)
         return {"tp": self.tp, "axis": TP_AXIS,
                 "kv_quant": self.kv_quant, "devices": devices}
-
-
-def _prefill_layer_cached(w, x, kpre, vpre, cos_s, sin_s, mask, cfg,
-                          lora=(), aidx=None, li=0):
-    """One transformer layer of suffix prefill against a resident
-    prefix: ``x`` [1, S, H] suffix hidden, ``kpre``/``vpre``
-    [1, Tpre, kvH, D] prefix KV gathered from the pool (keys already
-    rotary-encoded at their absolute positions, exactly as prefill and
-    decode wrote them), ``mask`` [1, 1, S, Tpre+S] bool.  Returns
-    (out, k_suffix, v_suffix) — mirror of ``_prefill_layer``."""
-    b, s, _ = x.shape
-    nh, kvh, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                   cfg.head_dim)
-    with jax.named_scope("attn.qkv"):
-        h = _rms(x, w["ln1"], cfg.rms_norm_eps)
-        qp, kp, vp = _qkv_proj(w, h, nh, kvh, hd, lora, aidx, li)
-        q = qp.reshape(b, s, nh, hd)
-        k = kp.reshape(b, s, kvh, hd)
-        v = vp.reshape(b, s, kvh, hd)
-        cos_c = cos_s[None, :, None, :].astype(q.dtype)
-        sin_c = sin_s[None, :, None, :].astype(q.dtype)
-        q = q * cos_c + _rotate_half(q) * sin_c
-        k = k * cos_c + _rotate_half(k) * sin_c
-
-    with jax.named_scope("attn.prefill"):
-        from ...ops.pallas.flash_attention import sdpa
-        kcat = jnp.concatenate([kpre.astype(k.dtype), k], axis=1)
-        vcat = jnp.concatenate([vpre.astype(v.dtype), v], axis=1)
-        attn = sdpa(q, kcat, vcat, attn_mask=mask,
-                    is_causal=False).reshape(b, s, nh * hd)
-    with jax.named_scope("attn.out"):
-        o = _mm(attn, w["o"])
-        # `lora` pytree structure (empty tuple = off) is trace-time static
-        # tpu-lint: disable=jit-traced-branch
-        if lora:
-            from ...ops.pallas.lora_matmul import lora_delta
-            o = o + lora_delta(lora, "o", li, attn, aidx)
-        x = x + o
-    with jax.named_scope("mlp"):
-        h = _rms(x, w["ln2"], cfg.rms_norm_eps)
-        return (x + _ffn(w, h, lora, aidx, li), k, v)
 
 
 def _logits_of(state, h):

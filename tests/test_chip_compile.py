@@ -179,6 +179,10 @@ def _cell_runner(monkeypatch, *, spec_k=0, kv_quant=False):
 
 
 def _compile_decode_program(sds, run, shapes):
+    return _lower_decode_program(sds, run, shapes).compile()
+
+
+def _lower_decode_program(sds, run, shapes):
     """The runner's own jitted decode step (``spec_k`` 0) or verify
     program, donated as the runner donates, lowered from shapes."""
     slots, k = run.max_slots, run.spec_k
@@ -194,10 +198,10 @@ def _compile_decode_program(sds, run, shapes):
             i32(slots), i32(slots), i32(slots))
     if k == 0:
         return run._make_step_fn().lower(
-            *head, i32(1, slots), i32(), rope, rope, (), (), ()).compile()
+            *head, i32(1, slots), i32(), rope, rope, (), (), ())
     return run._make_verify_fn().lower(
         *head, i32(1, slots, k + 1), i32(), i32(slots, k), i32(slots),
-        rope, rope, (), ()).compile()
+        rope, rope, (), ())
 
 
 def _pool_shape(run):
@@ -235,6 +239,61 @@ def test_decode_programs_update_the_pools_in_place(sds, monkeypatch, spec_k):
     for stem, opcode in pool_sized:
         for word in ("copy", "dynamic-update-slice", "slice"):
             assert word not in opcode and word not in stem, (stem, opcode)
+
+
+def _scope_order(lowered, scopes):
+    """The named scopes of ``scopes`` in the order the lowered program's
+    main function enters them (a run of one scope counts once)."""
+    text = lowered.as_text(debug_info=True)
+    defs = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", text, re.M))
+
+    def stack(ref, depth=0):
+        body = defs.get(ref, "")
+        name = re.match(r'"([^"]*)"', body)
+        if name and "/" in name.group(1):
+            return name.group(1)
+        inner = re.search(r"#loc\d+", body)
+        return stack(inner.group(0), depth + 1) if inner and depth < 8 else ""
+
+    main = text[text.index("func.func public @main"):]
+    main = main[:main.index("\n  }")]
+    order = []
+    for ref in re.findall(r"loc\((#loc\d+)\)\s*$", main, re.M):
+        hit = [part for part in stack(ref).split("/") if part in scopes]
+        if hit and order[-1:] != hit[:1]:
+            order.append(hit[0])
+    return order
+
+
+@pytest.mark.parametrize("program,layer_scopes", [
+    ("decode_step", ["attn.qkv", "kv.write", "attn.decode", "attn.out",
+                     "mlp"]),
+    ("prefill", ["attn.qkv", "attn.prefill", "attn.out", "mlp",
+                 "kv.write"]),
+])
+def test_cell_programs_keep_their_kernels_and_scope_order(
+        sds, monkeypatch, program, layer_scopes):
+    """The dense tp=1 programs the Mistral cell runs: one attention
+    kernel a layer and no other, and the scopes in the order the
+    benchmark's breakdown names device time by — an edit of a layer
+    body that reorders or renames them shows here, not in a trace."""
+    run, shapes = _cell_runner(monkeypatch)
+    layers = run.config.num_hidden_layers
+    if program == "decode_step":
+        lowered = _lower_decode_program(sds, run, shapes)
+    else:
+        bucket = 256
+        pool = sds(_pool_shape(run))
+        rope = sds((run._rope_len, HD), jnp.float32)
+        run._prefill_fns = {}
+        lowered = run._prefill_fn(bucket).lower(
+            {name: sds(shape) for name, shape in shapes.items()},
+            sds((1, bucket), jnp.int32), sds((1,), jnp.int32),
+            sds((bucket // run.page_size,), jnp.int32), pool, pool, (), (),
+            rope, rope, (), ())
+    assert lowered.as_text().count("@tpu_custom_call") == layers
+    assert _scope_order(lowered, {"embed", "head", *layer_scopes}) == (
+        ["embed"] + layer_scopes * layers + ["head"])
 
 
 def test_int8_pages_decode_step_reports_its_temporaries(sds, monkeypatch,

@@ -262,10 +262,13 @@ def test_multinode_elastic_reform(tmp_path):
     master.py restart signaling)."""
     import socket
     import threading
+    from paddle_tpu.distributed.launch import NodeRendezvous
 
+    # the port that was probed free is the one the launchers bind (the
+    # rendezvous store's); the workers here never open the other two
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
-        port = s.getsockname()[1]
+        port = s.getsockname()[1] - NodeRendezvous.STORE_PORT_OFFSET
 
     script = _write(str(tmp_path), "worker.py", """
         import os, sys, time
@@ -288,14 +291,20 @@ def test_multinode_elastic_reform(tmp_path):
         + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
 
     codes = {}
+    # Both launchers live until both have returned.  The store is a
+    # thread of the host's launcher and stops with it; a second world
+    # whose workers only print is over on the host before a busy peer
+    # has read its rendezvous back (under six test workers the peer's
+    # last read found the store gone: "TCPStore.get failed").  Workers of
+    # a real job wait for each other, so its host cannot leave first.
+    launchers = [Launcher(
+        [sys.executable, script], nprocs=1, master=f"127.0.0.1:{port}",
+        log_dir=str(tmp_path / f"node{i}"), base_env=env, nnodes="2",
+        job_id="mn-elastic", max_restarts=2, elastic=True)
+        for i in range(2)]
 
     def node(i):
-        codes[i] = Launcher(
-            [sys.executable, script], nprocs=1,
-            master=f"127.0.0.1:{port}",
-            log_dir=str(tmp_path / f"node{i}"),
-            base_env=env, nnodes="2", job_id="mn-elastic",
-            max_restarts=2, elastic=True).run()
+        codes[i] = launchers[i].run()
 
     threads = [threading.Thread(target=node, args=(i,)) for i in range(2)]
     for t in threads:

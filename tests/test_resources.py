@@ -174,7 +174,7 @@ class TestBlockManagerAccounting:
         bm.allocate_seq(0, A, max_new_tokens=4)     # 4 pages, all fresh
         acc = _census_ok(bm)
         assert acc == {"live": 4, "cached": 0, "free": 4, "total": 8,
-                       "allocated_total": 4, "leak": 0}
+                       "allocated_total": 4, "host_parked": 0, "leak": 0}
         # same prompt while A is live: shares 2 chain pages, acquires 2
         bm.allocate_seq(1, A, max_new_tokens=4)
         acc = _census_ok(bm)

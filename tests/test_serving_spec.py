@@ -32,9 +32,10 @@ import jax
 
 import paddle_tpu as paddle
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
-from paddle_tpu.serving import (BlockManager, GenerationConfig,
-                                NgramProposer, RequestState, SpecStats,
-                                create_engine)
+from paddle_tpu.serving import (AdapterStore, BlockManager,
+                                GenerationConfig, NgramProposer,
+                                RequestState, SpecStats, create_engine,
+                                merge_adapter, random_adapter)
 
 
 @pytest.fixture(scope="module")
@@ -60,12 +61,30 @@ _PROMPTS = [
 _N_NEW = [12, 10, 8, 12]
 
 
+_ADAPTER, _RANK, _ALPHA = "alpha", 4, 8.0
+# which request rides the adapter when a bank is armed: a mixed batch,
+# so the no-adapter row 0 runs through the same traced programs
+_ADAPTER_OF = [_ADAPTER, None, _ADAPTER, None]
+
+
+def _options(model, pages, adapters):
+    """Engine keywords of one (pages, adapters) cell."""
+    kw = {"kv_quant": True} if pages == "int8" else {}
+    if adapters == "on":
+        store = AdapterStore(model.config, capacity=2)
+        store.register(_ADAPTER, random_adapter(model.config, _RANK,
+                                                seed=7), alpha=_ALPHA)
+        kw["lora"] = store
+    return kw
+
+
 def _run(model, **kw):
     eng = create_engine(model, max_slots=4, page_size=8,
                         max_model_len=64, **kw)
     reqs = [eng.submit(np.array(p, np.int32),
-                       GenerationConfig(max_new_tokens=n))
-            for p, n in zip(_PROMPTS, _N_NEW)]
+                       GenerationConfig(max_new_tokens=n),
+                       adapter=a if kw.get("lora") else None)
+            for p, n, a in zip(_PROMPTS, _N_NEW, _ADAPTER_OF)]
     eng.run_until_complete(max_steps=500)
     assert all(r.state == RequestState.DONE for r in reqs)
     return eng, [r.output_tokens for r in reqs]
@@ -73,29 +92,81 @@ def _run(model, **kw):
 
 @pytest.fixture(scope="module")
 def reference(spec_model):
-    """The canonical greedy outputs: spec off, cache off, per-step
-    sync, single chip.  EVERY matrix cell must reproduce these."""
-    _, ref = _run(spec_model)
-    return ref
+    """The canonical greedy outputs of one (pages, adapters) cell: spec
+    off, per-step sync, single chip.  EVERY matrix case of the cell must
+    reproduce these.  Plain pages: cache off (a cached prefix is bitwise
+    the keys the prompt would have written).  int8 pages: the case's own
+    cache setting (a cached prefix is read back dequantized, a fresh
+    prompt attends its float keys), and within the quantization
+    tolerance of the plain pages' reference.  Adapters on, plain pages:
+    the adapter's rows are those of its merged checkpoint."""
+    made = {}
+
+    def of(pages, adapters, cache):
+        key = (pages, adapters, cache and pages == "int8")
+        if key in made:
+            return made[key]
+        _, ref = _run(spec_model, enable_prefix_cache=key[2],
+                      **_options(spec_model, pages, adapters))
+        if pages == "int8":
+            plain = of("plain", adapters, False)
+            total = sum(max(len(a), len(b)) for a, b in zip(ref, plain))
+            match = sum(int(x == y) for a, b in zip(ref, plain)
+                        for x, y in zip(a, b))
+            assert match >= 0.75 * total, f"{match}/{total}"
+        elif adapters == "on":
+            from paddle_tpu.framework.tensor import Tensor
+            state = {k: (v._data if isinstance(v, Tensor) else v)
+                     for k, v in spec_model.functional_state().items()}
+            merged = merge_adapter(
+                state, spec_model.config,
+                random_adapter(spec_model.config, _RANK, seed=7),
+                alpha=_ALPHA)
+            from paddle_tpu.serving.engine import Engine
+            eng = Engine(config=spec_model.config, state=merged,
+                         max_slots=4, page_size=8, max_model_len=64)
+            for i, a in enumerate(_ADAPTER_OF):
+                if a is None:
+                    continue
+                req = eng.submit(np.array(_PROMPTS[i], np.int32),
+                                 GenerationConfig(max_new_tokens=_N_NEW[i]))
+                eng.run_until_complete(max_steps=500)
+                assert list(req.output_tokens) == list(ref[i])
+        made[key] = ref
+        return ref
+
+    return of
 
 
-@pytest.mark.parametrize("cache", [False, True])
-@pytest.mark.parametrize("sync_interval", [1, 4])
-@pytest.mark.parametrize("tp", [1, 2])
+# the cells beside (plain, off) run all four programs in one case: the
+# cache on (prefill and cached prefill), speculation (the plain step and
+# the verify step), on one chip and on the mesh
+_CASES = ([(cache, sync, tp, "plain", "off")
+           for tp in (1, 2) for sync in (1, 4) for cache in (False, True)]
+          + [(True, 4, tp, pages, adapters) for tp in (1, 2)
+             for pages, adapters in (("int8", "off"), ("plain", "on"),
+                                     ("int8", "on"))])
+
+
+@pytest.mark.parametrize("cache,sync_interval,tp,pages,adapters", _CASES)
 def test_spec_greedy_parity_matrix(spec_model, reference, cache,
-                                   sync_interval, tp):
-    """spec_k {0,2,4} x prefix-cache x sync_interval x tp: bit-identical
-    tokens, exact page accounting, and the no-retrace contract (plain
-    engines trace 1 decode program, spec engines exactly 2)."""
+                                   sync_interval, tp, pages, adapters):
+    """spec_k {0,2,4} x prefix-cache x sync_interval x tp x pages
+    (plain, int8) x adapters (off, on): bit-identical tokens, exact page
+    accounting, and the no-retrace contract (plain engines trace 1
+    decode program, spec engines exactly 2)."""
     if tp > 1 and jax.device_count() < tp:
         pytest.skip("needs multiple host-platform devices")
+    expected = reference(pages, adapters, cache)
     for spec_k in (0, 2, 4):
         eng, got = _run(spec_model, spec_k=spec_k,
                         enable_prefix_cache=cache,
-                        sync_interval=sync_interval, mesh=tp)
-        assert got == reference, (
+                        sync_interval=sync_interval, mesh=tp,
+                        **_options(spec_model, pages, adapters))
+        assert got == expected, (
             f"spec_k={spec_k} cache={cache} sync={sync_interval} "
-            f"tp={tp} diverged from the plain greedy reference")
+            f"tp={tp} pages={pages} adapters={adapters} diverged from "
+            "the plain greedy reference")
         st = eng.stats()
         if spec_k:
             assert st["decode_traces"] == 2      # plain + verify bodies
@@ -121,7 +192,8 @@ def test_spec_finish_inside_verify_row(spec_model, reference):
     accepted span reaches max_new_tokens) finishes exactly where
     sequential decode finishes, and its pages free completely."""
     eng, got = _run(spec_model, spec_k=4)
-    for r_got, r_ref, n in zip(got, reference, _N_NEW):
+    for r_got, r_ref, n in zip(got, reference("plain", "off", False),
+                               _N_NEW):
         assert len(r_got) == len(r_ref) == n
     assert eng.blocks.pool_accounting()["leak"] == 0
     assert eng.blocks.pages_in_use == 0

@@ -164,6 +164,13 @@ DIRECTIONS = {
     "attribution_conservation_max_delta": "exact",
     "exemplars_captured": "exact",
     "forensics_parity_vs_off": "exact",
+    # int8 weights and int8 KV pages: a page (and what a spill moves of
+    # it) costs (hd + 4) / (4 * hd) of the dense page's bytes, never
+    # more; greedy output stays within the quantization tolerance of
+    # the dense run (the gate pins the verdict at exactly 1)
+    "pages_per_token_x1000": "low",
+    "spill_bytes_ratio_vs_dense_x1000": "low",
+    "quant_parity_within_tol": "exact",
 }
 
 
