@@ -170,8 +170,9 @@ def create_engine(model, **kwargs):
     generation requests over a shared paged KV pool.  Key knobs:
     ``enable_prefix_cache=True`` reuses resident KV pages across
     requests with shared prompt prefixes (prefill runs only the uncached
-    suffix); ``sync_interval=N`` lets the greedy decode loop run N
-    device steps per host sync.  See
+    suffix); ``sync_interval=N`` makes the greedy decode loop fetch N
+    steps' tokens at a time (the host runs one step behind the device
+    at every N, so the device never waits for it).  See
     :func:`paddle_tpu.serving.create_engine` for the full list."""
     from ..serving import create_engine as _create
     return _create(model, **kwargs)

@@ -16,9 +16,12 @@ decode steps of ONE jitted program.
 ``enable_prefix_cache=True`` adds automatic prefix caching (vLLM-style
 hash-chained page reuse + copy-on-write tails + LRU eviction): prompts
 sharing page-aligned prefixes skip prefill for the shared part and are
-charged pages only for their uncached suffix.  ``sync_interval=N``
-batches host synchronization on the greedy path: decode state lives on
-device and the host drains a sampled-token ring once every N steps.
+charged pages only for their uncached suffix.  Decode state lives on
+device and the greedy loop's host runs one step behind it: a step is
+dispatched before the one before it is fetched, so the device never
+waits for the host's walk of the tokens.  ``sync_interval=N`` is the
+rows a fetch brings: the host drains the sampled-token ring once every
+N steps (tokens then surface in bursts of N).
 
 Under overload the stack degrades in a defined order instead of all at
 once: long prefills chunk in behind decode

@@ -23,11 +23,28 @@ the reference block_multi_head_attention serving path):
     cached-prefill jit that attends over the resident prefix KV.
   * device-resident decode state: ``table``/``pos``/``tok``, the active
     mask, and a ``[sync_interval, slots]`` sampled-token ring live on
-    device and are donated through the step — a steady-state decode
-    iteration uploads nothing and downloads nothing.  The host fetches
-    the ring once every ``sync_interval`` steps (greedy path) and the
-    ``[slots, V]`` logits only when an active request actually samples;
-    admissions and evictions patch single slot rows in place.
+    device; all but the ring are donated through the step — a
+    steady-state decode iteration uploads nothing.  The step feeds its
+    own argmax forward, so the device never needs the host to go on,
+    and the host runs ONE STEP BEHIND it: step n+1 is dispatched first,
+    and only then is step n's ring (kept, its copy started at its own
+    dispatch) fetched and walked — tokens handed to callers, finishes,
+    parked slots, the next scheduling pass all happen while the device
+    computes.  ``sync_interval`` is the rows a fetch brings (greedy
+    path); the ``[slots, V]`` logits come only when an active request
+    actually samples; an admission or eviction patches its slot's row
+    in place with one program.
+  * what the lag costs and where it yields: a finish is seen one step
+    late, so the slot decodes one row too many (discarded on the host;
+    its write lands in the slot's own reserved tail or the dump page:
+    ``overrun_rows``).  A resident request that samples, or a
+    configured draft proposer, needs every token on the host before the
+    next step: the loop is then in lockstep (``overlapped_steps`` stays
+    put).  A preemption drains what is in flight first and
+    ``recover()`` drops it and replays from request state.  An
+    admission's first-token fetch still blocks; the step in flight
+    stays in flight across it and is walked after the next dispatch
+    like any other, so a closed loop keeps every slot in every step.
   * idle slots park on the dump page (table row all-dump, pos 0): their
     lockstep writes land in scratch, their outputs are discarded
     host-side — no masking inside the program.
@@ -46,8 +63,9 @@ logits, matching ``_sample``'s greedy branch exactly; stochastic
 requests draw from a per-request numpy RNG so results do not depend on
 batch composition).  Set ``emit_logits=True`` at engine construction to
 serve ``do_sample`` requests — any active sampling request forces a
-per-step sync (the host must feed the sampled token back before the
-next step), so ``sync_interval`` only pays off on greedy traffic.
+per-step sync in lockstep (the host must feed the sampled token back
+before the next step), so neither the lag nor ``sync_interval`` pays
+off while one is resident.
 """
 from __future__ import annotations
 
@@ -353,8 +371,12 @@ class Engine:
         # ring rows the host has not consumed yet, in decode order:
         # [(ring row, [(slot, request), ...], drafts-or-None), ...] —
         # the third element is the verify step's {slot: draft tokens}
-        # (a verify row syncs immediately, so it is always solitary)
+        # (a verify row syncs immediately, so it is always solitary).
+        # ``_pending`` is the group still filling (sync_interval rows);
+        # ``_flight`` the full group whose ring the runner holds: it is
+        # fetched and walked after the NEXT step's dispatch (_land)
         self._pending: list[tuple[int, list, dict | None]] = []
+        self._flight: list[tuple[int, list, dict | None]] | None = None
         self._last_logits = None        # device handle, fetched lazily
 
         self.decode_steps = 0       # mirror of serving_decode_steps_total
@@ -366,6 +388,11 @@ class Engine:
         # span carries them; a step never fetches them)
         self._moe_seen: dict = {}
         self.host_syncs = 0         # ring fetches (1 per sync_interval)
+        # decode steps dispatched while an earlier step's row was still
+        # unfetched (the device did not wait for the host), and rows
+        # decoded for a slot whose request had already ended
+        self.overlapped_steps = 0
+        self.overrun_rows = 0
         self.logit_fetches = 0      # [slots, V] transfers (sampling only)
         # chunked prefill: in-flight admission prefills advanced one
         # chunk per engine step — {slot: state dict} (see _begin_chunks)
@@ -572,8 +599,11 @@ class Engine:
     # -------------------------------------------------------- main loop
     def step(self) -> bool:
         """One engine iteration: evict/admit (scheduler pass), prefill
-        admissions, then one lockstep decode step over the active slots.
-        Returns whether any work happened."""
+        admissions, then one decode step over the active slots, all of
+        them in one program.  The step is dispatched first; the tokens
+        that reach callers in this iteration are those of the step
+        before it (see the module docstring).  Returns whether any work
+        happened."""
         with _obs.tracer().phase("engine.step", parent=None,
                                  step=self.progress) as st:
             now = self._clock()
@@ -605,6 +635,8 @@ class Engine:
                 # prefill work starved no resident — the stall meter
                 # restarts
                 self._prefill_since_decode = 0
+                # rows still out are of requests that have ended
+                self._settle()
             self.current_phase = "idle"
             self.progress += 1      # watchdog heartbeat
         return bool(admitted) or bool(active) or bool(advanced)
@@ -876,9 +908,16 @@ class Engine:
         cache chain when the cache is on), and park the slot.  Returns
         False — victim untouched, preemption aborted — when a page copy
         fails (the ``spill_fail`` chaos site); parked copies from the
-        aborted attempt are discarded, so the pool census stays exact."""
+        aborted attempt are discarded, so the pool census stays exact.
+        What is in flight is walked first: the victim's committed tokens
+        and the ledger must be level with the device.  Where that walk
+        saw a request end, room was made without a victim: nothing is
+        preempted and the scheduler's next pass finds the room."""
+        evictions = self.scheduler.evictions
+        self._settle()
         req = self.scheduler.slots[slot]
-        if req is None or req.state != RequestState.DECODE:
+        if (req is None or req.state != RequestState.DECODE
+                or self.scheduler.evictions != evictions):
             return False
         with _obs.tracer().phase("engine.preempt_spill",
                                  parent=req.root_span, req=req.id,
@@ -1078,11 +1117,15 @@ class Engine:
             self._decode_spec(reqs, drafts)
             return
         live, grid = self._count_paged_blocks(active)
+        # rows the host has not seen: this dispatch does not wait for them
+        overlapped = bool(self._pending) or self._flight is not None
         with self._phase("engine.decode.dispatch", "decode",
                          slots=len(active), paged_blocks_live=live,
-                         paged_blocks_grid=grid, **self._moe_seen):
+                         paged_blocks_grid=grid, overlapped=overlapped,
+                         **self._moe_seen):
             logits = self.runner.decode_step()
         self.decode_steps += 1
+        self.overlapped_steps += overlapped
         self._prefill_since_decode = 0      # gap witness: decode ran
         _M_STEPS.inc()
         self._pages_hist.observe(self.blocks.pages_in_use)
@@ -1091,12 +1134,19 @@ class Engine:
         self._pending.append((self._ring_cursor, reqs, None))
         self._ring_cursor = (self._ring_cursor + 1) % self.sync_interval
         self._last_logits = logits if self.emit_logits else None
+        # the device is busy with this step: now walk the one before it
+        self._land()
         # any active sampling request needs its token fed back before
-        # the next step, so sampling degrades to a per-step sync
-        eff = 1 if any(r.gen.do_sample for _, r in reqs) \
-            else self.sync_interval
-        if len(self._pending) >= eff:
-            self._sync()
+        # the next step, so sampling degrades to a per-step sync in
+        # lockstep; so does a proposer, which drafts from what the host
+        # has seen.  Else the group, once full, stays in flight until
+        # the next step has been dispatched
+        sampling = any(r.gen.do_sample for _, r in reqs)
+        if (len(self._pending) >= (1 if sampling else self.sync_interval)
+                or not _wanted(self._pending)):
+            self._send()
+            if sampling or self._proposer is not None:
+                self._land()
 
     def _count_paged_blocks(self, active: list[int],
                             rows: int = 1) -> tuple[int, int]:
@@ -1126,6 +1176,7 @@ class Engine:
         mirrors it needs are exact), or when an active request samples
         (greedy verification only, for now)."""
         if (self._proposer is None or self._pending
+                or self._flight is not None
                 or any(r.gen.do_sample for _, r in reqs)):
             return {}
         drafts = {}
@@ -1173,22 +1224,49 @@ class Engine:
         self._pending.append((self._ring_cursor, reqs, drafts))
         self._ring_cursor = (self._ring_cursor + 1) % self.sync_interval
         self._last_logits = None
-        self._sync()
+        self._settle()
 
-    def _sync(self):
-        """Drain the device token ring: ONE [sync_interval, slots] int32
-        transfer covers every decode step since the previous sync."""
+    def _send(self):
+        """Close the group of pending rows: the runner keeps the ring as
+        the step just dispatched leaves it and starts its copy to the
+        host; the rows are in flight until :meth:`_land`.  Rows no
+        request is waiting for (each ended before its row came: the
+        step after a burst's last finish) are dropped unfetched."""
+        rows, self._pending = self._pending, []
+        if not _wanted(rows):
+            self.overrun_rows += sum(len(e) for _, e, _ in rows)
+            return
+        self.runner.hold_ring()
+        self._flight = rows
+
+    def _land(self):
+        """Fetch the ring in flight and walk its rows: ONE
+        [sync_interval, slots] int32 transfer covers the group.  Called
+        after the next step's dispatch, so the fetch waits for a step
+        the device has (nearly) ended and the walk runs beside the one
+        it has begun; at once where the loop is in lockstep."""
+        rows, self._flight = self._flight, None
+        if rows is None:
+            return
         self.current_phase = "host_sync"
         with self._phase("engine.host_sync", "host_sync") as ph:
             ring = self.runner.fetch_ring()
-        with self._phase("engine.emit", "emit",
-                         rows=len(self._pending)) as em:
-            self._drain(ring, ph.seconds, em)
+        with self._phase("engine.emit", "emit", rows=len(rows)) as em:
+            self._drain(ring, rows, ph.seconds, em)
 
-    def _drain(self, ring, sync_s: float, em):
+    def _settle(self):
+        """Bring the host level with the device: walk what is in
+        flight, then whatever rows are pending."""
+        self._land()
+        if self._pending:
+            self._send()
+            self._land()
+
+    def _drain(self, ring, rows: list, sync_s: float, em):
         """What the host does with a fetched ring, inside the span
-        ``em``: re-derive acceptance, walk the rows, hand every token to
-        its request (``on_token`` callbacks and finishes included)."""
+        ``em``: re-derive acceptance, walk the ``rows`` it holds, hand
+        every token to its request (``on_token`` callbacks and finishes
+        included)."""
         emitted = self._emitted
         self.host_syncs += 1
         _M_HOST_SYNCS.labels("ring").inc()
@@ -1201,11 +1279,11 @@ class Engine:
         # same integer comparison the device ran, no extra transfer.
         wide = ring.ndim == 3
         accepted: dict[int, tuple[int, int]] = {}
-        for ridx, entries, drafts in self._pending:
+        for ridx, entries, drafts in rows:
             if drafts is None:
                 continue
             for slot, req in entries:
-                if req.is_finished() or req.state != RequestState.DECODE:
+                if not _decoding(req):
                     continue
                 a = 0
                 for j, d in enumerate(drafts.get(slot, ())):
@@ -1213,8 +1291,8 @@ class Engine:
                         break
                     a += 1
                 accepted[slot] = (len(drafts.get(slot, ())), a)
-        n_rows = len(self._pending)
-        # one decode step per pending row since the last sync
+        n_rows = len(rows)
+        # one decode step per row of the group
         em.set_attribute("steps", n_rows)
         if accepted:
             em.set_attribute("spec_proposed",
@@ -1231,10 +1309,9 @@ class Engine:
             # itself — overlapping requests each experience the full
             # wall interval, so per-request conservation still holds
             seen: set[int] = set()
-            for _, entries, _ in self._pending:
+            for _, entries, _ in rows:
                 for _slot, _req in entries:
-                    if (_req.id in seen or _req.is_finished()
-                            or _req.state != RequestState.DECODE
+                    if (_req.id in seen or not _decoding(_req)
                             or _req.timeline is None):
                         continue
                     seen.add(_req.id)
@@ -1245,11 +1322,13 @@ class Engine:
         # (argmax/top-k/top-p)
         with contextlib.ExitStack() as sampling:
             sample = None
-            for row_i, (ridx, entries, drafts) in enumerate(self._pending):
+            for row_i, (ridx, entries, drafts) in enumerate(rows):
                 for slot, req in entries:
-                    if (req.is_finished()
-                            or req.state != RequestState.DECODE):
-                        continue    # evicted/finished: overrun discarded
+                    if not _decoding(req):
+                        # evicted/finished, seen one step (or a group)
+                        # late: the overrun row is discarded
+                        self.overrun_rows += 1
+                        continue
                     if drafts is not None:
                         self._accept(slot, req, ring[ridx, slot],
                                      *accepted[slot], now)
@@ -1290,7 +1369,6 @@ class Engine:
                             (now - prev) / (n_rows - row_i))
                     self._tok[slot] = tok
                     self._emit(slot, req, tok, now)
-            self._pending.clear()
             if sample is not None:
                 sample.set_attribute("corrections", len(corrections))
         if corrections:
@@ -1521,6 +1599,7 @@ class Engine:
         # belong to the dead runner (the pos mirrors they would have
         # advanced are recomputed from request state below)
         self._pending.clear()
+        self._flight = None
         self._ring_cursor = 0
         self._last_logits = None
         flushed = self.blocks.flush_prefix_cache()
@@ -1623,6 +1702,8 @@ class Engine:
             "host_syncs": self.host_syncs,
             "logit_fetches": self.logit_fetches,
             "decode_steps": self.decode_steps,
+            "overlapped_steps": self.overlapped_steps,
+            "overrun_rows": self.overrun_rows,
             "paged_blocks_live": self.paged_blocks_live,
             "paged_blocks_grid": self.paged_blocks_grid,
             **self._moe_counters(),
@@ -1742,6 +1823,17 @@ class Engine:
         }
 
 
+def _decoding(req: Request) -> bool:
+    """Whether a ring row's token is still wanted by its request."""
+    return not req.is_finished() and req.state == RequestState.DECODE
+
+
+def _wanted(rows: list) -> bool:
+    """Whether any request still waits for a token of ``rows``."""
+    return any(_decoding(req) for _, entries, _ in rows
+               for _, req in entries)
+
+
 def _softmax(x):
     x = x - np.max(x[np.isfinite(x)]) if np.isfinite(x).any() else x
     e = np.exp(np.where(np.isfinite(x), x, -np.inf))
@@ -1777,10 +1869,12 @@ def create_engine(model, *, max_slots: int = 4, page_size: int = 64,
 
     ``enable_prefix_cache=True`` turns on automatic prefix caching:
     prompts sharing page-aligned prefixes reuse resident KV pages and
-    prefill only their uncached suffix.  ``sync_interval=N`` lets the
-    greedy decode loop run N device steps between host syncs (tokens
-    stream out in bursts of N — lower sync overhead, higher streaming
-    latency; sampling requests force per-step syncs regardless).
+    prefill only their uncached suffix.  ``sync_interval=N`` makes the
+    greedy decode loop fetch N steps' tokens at a time (tokens stream
+    out in bursts of N — fewer transfers, higher streaming latency;
+    sampling requests force per-step syncs regardless).  At every N the
+    host runs one step behind the device: the next step is dispatched
+    before a fetch, so the overlap does not depend on N.
 
     ``spec_k=K`` (default ``FLAGS_serving_spec_k``) turns on
     speculative decoding: a host-side prompt-lookup (n-gram) drafter

@@ -42,7 +42,11 @@ four members.
 The engine's serving invariants carry over unchanged: slot occupancy /
 positions / tables are data (ONE decode trace per engine lifetime —
 ``decode_traces`` counts them), the decode state is donated through the
-step, and admissions/evictions patch single slot rows in place.
+step, and an admission or eviction patches its slot's row in place with
+one program (:meth:`ModelRunner.push_slot`).  The sampled-token ring
+alone is NOT donated: a step returns a new ``[sync_interval, slots]``
+array, so the engine can hold one step's ring (:meth:`hold_ring`) while
+the next step runs and fetch it afterwards.
 
 The pools go through every program WHOLE: a layer body takes
 ``[L, pages+1, kvh, page_size, hd]`` and its layer's index, the cache
@@ -120,7 +124,8 @@ class ModelRunner:
 
     The engine talks to it through a narrow seam: :meth:`decode_step`,
     :meth:`prefill`, :meth:`prefill_cached`, :meth:`copy_page`,
-    :meth:`push_slot`, :meth:`fetch_ring`, :meth:`correct_tokens`.
+    :meth:`push_slot`, :meth:`hold_ring`, :meth:`fetch_ring`,
+    :meth:`correct_tokens`.
     """
 
     def __init__(self, config, state: dict, *, tp: int = 1,
@@ -282,7 +287,10 @@ class ModelRunner:
 
         self.decode_traces = 0      # python mirror of _M_STEP_TRACES
         self.verify_traces = 0      # python mirror of _M_VERIFY_TRACES
+        self.push_traces = 0        # the slot patch: one for every slot
+        self._ring_held = None      # hold_ring()'s, until fetch_ring()
         self._step_fn = self._make_step_fn()
+        self._push_fn = self._make_push_fn()
         self._verify_fn = (self._make_verify_fn() if self.spec_k
                            else None)
         self._prefill_fns: dict[int, object] = {}   # bucket -> jitted fn
@@ -532,7 +540,7 @@ class ModelRunner:
     def _make_step_fn(self):
         if self.tp == 1:
             return jax.jit(self._build_step(None),
-                           donate_argnums=(1, 2, 3, 4, 6, 7, 9, 10, 15))
+                           donate_argnums=(1, 2, 3, 4, 6, 7, 10, 15))
         from jax.sharding import PartitionSpec as P
         pool = self._pool_pspec
         sspec = self._scale_pspec if self.kv_quant else P()
@@ -544,7 +552,7 @@ class ModelRunner:
             out_specs=(pool, pool, sspec, sspec, P(), P(), P(), P(),
                        P(), P()),
             check_vma=False)
-        return jax.jit(mapped, donate_argnums=(1, 2, 3, 4, 6, 7, 9, 10))
+        return jax.jit(mapped, donate_argnums=(1, 2, 3, 4, 6, 7, 10))
 
     def _count_step_trace(self):
         """Runs when a decode step is traced, never when it runs: a
@@ -606,7 +614,7 @@ class ModelRunner:
     def _make_verify_fn(self):
         if self.tp == 1:
             return jax.jit(self._build_verify(None),
-                           donate_argnums=(1, 2, 3, 4, 6, 7, 9, 10))
+                           donate_argnums=(1, 2, 3, 4, 6, 7, 10))
         from jax.sharding import PartitionSpec as P
         pool = self._pool_pspec
         sspec = self._scale_pspec if self.kv_quant else P()
@@ -617,7 +625,7 @@ class ModelRunner:
                       P(), self._lora_pspecs(), P()),
             out_specs=(pool, pool, sspec, sspec, P(), P(), P(), P()),
             check_vma=False)
-        return jax.jit(mapped, donate_argnums=(1, 2, 3, 4, 6, 7, 9, 10))
+        return jax.jit(mapped, donate_argnums=(1, 2, 3, 4, 6, 7, 10))
 
     def _build_verify(self, axis):
         """The speculative verify program: score ``k+1`` candidate
@@ -1038,17 +1046,48 @@ class ModelRunner:
             self.kscale = kscale_p
             self.vscale = vscale_p
 
+    def _make_push_fn(self):
+        """The slot patch: ONE program for every slot and every option.
+        The host's values come as one int32 vector ``[slot, pos, tok,
+        active, adapter row, table row...]``; the slot is data, so this
+        traces once.  The five state arrays are donated (``aidx`` is the
+        empty tuple without adapters: no leaf, no op)."""
+        runner = self
+
+        def push_slot(table, pos, tok, active, aidx, packed):
+            runner.push_traces += 1     # at trace time, never at run time
+            slot = packed[0]
+            return (table.at[slot].set(packed[5:]),
+                    pos.at[slot].set(packed[1]),
+                    tok.at[slot].set(packed[2]),
+                    active.at[slot].set(packed[3]),
+                    jax.tree.map(lambda a: a.at[slot].set(packed[4]),
+                                 aidx))
+
+        kw = {}
+        if self.mesh is not None:
+            # the decode state is replicated over the mesh and stays so
+            from jax.sharding import NamedSharding, PartitionSpec
+            kw["out_shardings"] = NamedSharding(self.mesh, PartitionSpec())
+        return jax.jit(push_slot, donate_argnums=(0, 1, 2, 3, 4), **kw)
+
     def push_slot(self, slot: int, row: np.ndarray, pos: int, tok: int,
                   active: int, adapter_row: int = 0):
         """Patch ONE slot's row of the device-resident decode state
-        (admission / eviction only — never per step)."""
-        self._table_dev = self._table_dev.at[slot].set(jnp.asarray(row))
-        self._pos_dev = self._pos_dev.at[slot].set(int(pos))
-        self._tok_dev = self._tok_dev.at[slot].set(int(tok))
-        self._active_dev = self._active_dev.at[slot].set(int(active))
-        if self.lora_slots:
-            self._aidx_dev = self._aidx_dev.at[slot].set(
-                int(adapter_row))
+        (admission / eviction only — never per step): one small upload,
+        one program."""
+        packed = np.empty((5 + self.table_width,), np.int32)
+        packed[:5] = (slot, pos, tok, active, adapter_row)
+        packed[5:] = row
+        traces_before = self.push_traces
+        t0 = time.perf_counter()
+        (self._table_dev, self._pos_dev, self._tok_dev, self._active_dev,
+         self._aidx_dev) = self._push_fn(
+            self._table_dev, self._pos_dev, self._tok_dev,
+            self._active_dev, self._aidx_dev, packed)
+        if self.push_traces != traces_before:
+            record_compile("push_slot", t0,
+                           signature=f"row=[{packed.size}]")
 
     def moe_counters(self) -> dict:
         """The expert layers' counters since the runner was built, by
@@ -1058,9 +1097,22 @@ class ModelRunner:
             return {}
         return latent.counters_by_name(np.asarray(self._counters_dev))
 
+    def hold_ring(self):
+        """Keep the ring as the last step left it, for the next
+        :meth:`fetch_ring`, and start its copy to the host.  The ring is
+        not donated, so steps dispatched from here on write their rows
+        into arrays of their own: what is held is what this step's
+        group of ``sync_interval`` rows was."""
+        self._ring_held = self._ring_dev
+        self._ring_held.copy_to_host_async()
+
     def fetch_ring(self) -> np.ndarray:
-        """The host sync: ONE [sync_interval, slots] int32 transfer."""
-        return np.asarray(self._ring_dev)
+        """The host sync: ONE [sync_interval, slots] int32 transfer, of
+        the ring :meth:`hold_ring` kept (blocks until the step that
+        wrote it has ended, not until later ones have), else of the
+        ring as it stands."""
+        ring, self._ring_held = self._ring_held, None
+        return np.asarray(self._ring_dev if ring is None else ring)
 
     def correct_tokens(self, corrections: list[tuple[int, int]]):
         """Push host-side sampling picks back into the device token

@@ -5,9 +5,12 @@ any live state).  Tokens stream out as they are sampled: consumers can
 poll :attr:`output_tokens`, register an ``on_token`` callback, or pull
 from :meth:`stream` (which drives the attached engine when it runs dry,
 so a plain ``for tok in req.stream():`` serves the request end to end).
-With ``sync_interval > 1`` tokens surface in bursts of up to
-``sync_interval`` — the host only observes the device token ring at
-sync points, trading streaming latency for fewer device round-trips.
+The engine's host runs one decode step behind its device: a step's
+token reaches the request once the NEXT step has been dispatched, which
+is about when the device has made it.  With ``sync_interval > 1``
+tokens surface in bursts of up to ``sync_interval`` — the host only
+observes the device token ring at sync points, trading streaming
+latency for fewer device round-trips.
 """
 from __future__ import annotations
 

@@ -140,14 +140,13 @@ def _hlo_lines(text, stem):
     return [ln for ln in lines if ln.startswith("%" + stem)]
 
 
-def _cell_runner(monkeypatch, *, spec_k=0, kv_quant=False):
+def _cell_runner(monkeypatch, *, spec_k=0, kv_quant=False, layers=2):
     """A ModelRunner at the Mistral cell's widths and engine sizes, cut
-    to two layers, that holds what ``_build_step`` / ``_build_verify``
+    to ``layers`` layers (None: the cell's 16), that holds what ``_build_step`` / ``_build_verify``
     read and nothing on any device; and the shapes of its state."""
     import json
     import sys
     from paddle_tpu.models.llama import LlamaConfig
-    from paddle_tpu.serving.parallel.runner import ModelRunner
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if root not in sys.path:
         sys.path.insert(0, root)
@@ -155,27 +154,69 @@ def _cell_runner(monkeypatch, *, spec_k=0, kv_quant=False):
     with open(os.path.join(root, "benchmarks", "configs",
                            "mistral-7b-v0.3-l16.json")) as f:
         cell = json.load(f)
-    m, eng = dict(cell["model"], num_hidden_layers=2), cell["engine"]
+    m, eng = dict(cell["model"]), cell["engine"]
+    m["num_hidden_layers"] = layers or m["num_hidden_layers"]
     assert (m["num_attention_heads"], m["num_key_value_heads"],
             m["head_dim"]) == (NH, KVH, HD)
-    # the kernel gate asks for the backend; the described chip is a TPU
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    run = object.__new__(ModelRunner)
-    run.config = LlamaConfig(
+    run = _bare_runner(monkeypatch, LlamaConfig(
         vocab_size=m["vocab_size"], hidden_size=m["hidden_size"],
         intermediate_size=m["intermediate_size"],
         num_hidden_layers=m["num_hidden_layers"],
         num_attention_heads=NH, num_key_value_heads=KVH,
         rms_norm_eps=m["rms_norm_eps"], rope_theta=m["rope_theta"],
-        dtype=m["torch_dtype"])
-    run.tp, run.latent, run.emit_logits = 1, False, False
+        dtype=m["torch_dtype"]), eng, spec_k=spec_k, kv_quant=kv_quant)
+    return run, {k: shape for k, (shape, _) in decoder_shapes(m).items()}
+
+
+def _bare_runner(monkeypatch, config, eng, *, latent=False, spec_k=0,
+                 kv_quant=False):
+    """A ``ModelRunner`` that holds what its program builders read and
+    nothing on a device: ``config`` with a cell's ``engine`` sizes."""
+    from paddle_tpu.serving.parallel.runner import ModelRunner
+    # the kernel gate asks for the backend; the described chip is a TPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    run = object.__new__(ModelRunner)
+    run.config = config
+    run.tp, run.mesh, run.latent, run.emit_logits = 1, None, latent, False
     run.spec_k, run.kv_quant = spec_k, kv_quant
     run.max_slots, run.page_size = eng["max_slots"], eng["page_size"]
     run.table_width = eng["max_model_len"] // run.page_size
     run.num_pages = run.max_slots * run.table_width     # + the dump page
     run._rope_len = eng["max_model_len"]
-    run.decode_traces = run.verify_traces = 0
-    return run, {k: shape for k, (shape, _) in decoder_shapes(m).items()}
+    run.decode_traces = run.verify_traces = run.push_traces = 0
+    return run
+
+
+def _latent_cell_runner(monkeypatch):
+    """The GigaChat cell's runner, as ``_cell_runner`` makes the Mistral
+    cell's: its configuration as the benchmark's driver reads it, every
+    layer of the cut (1 dense + 4 expert layers), shapes only."""
+    import json
+    import sys
+    from paddle_tpu.models.deepseek_v3 import DeepseekV3Config
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.lib import mla_moe_state
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "gigachat3.1-702b-a36b-ep16-l5.json")) as f:
+        conf = json.load(f)
+    m, eng = conf["model"], conf["engine"]
+    run = _bare_runner(monkeypatch, DeepseekV3Config(
+        n_routed_experts=mla_moe_state.router_width(conf),
+        local_experts=mla_moe_state.local_experts(conf),
+        dtype=conf["assumed"]["torch_dtype"],
+        **{k: m[k] for k in (
+            "vocab_size", "hidden_size", "intermediate_size",
+            "moe_intermediate_size", "num_hidden_layers",
+            "first_k_dense_replace", "num_attention_heads", "q_lora_rank",
+            "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+            "v_head_dim", "n_shared_experts", "num_experts_per_tok",
+            "n_group", "topk_group", "routed_scaling_factor",
+            "norm_topk_prob", "max_position_embeddings", "rms_norm_eps",
+            "rope_theta", "rope_scaling")}), eng, latent=True)
+    return run, {k: shape for k, (shape, _)
+                 in mla_moe_state.shapes(conf).items()}
 
 
 def _compile_decode_program(sds, run, shapes):
@@ -294,6 +335,89 @@ def test_cell_programs_keep_their_kernels_and_scope_order(
     assert lowered.as_text().count("@tpu_custom_call") == layers
     assert _scope_order(lowered, {"embed", "head", *layer_scopes}) == (
         ["embed"] + layer_scopes * layers + ["head"])
+
+
+def _donated(lowered) -> list[bool]:
+    """Whether each argument of the lowered program's main function is
+    donated (``jax.buffer_donor`` or an aliased output), in order."""
+    text = lowered.as_text()
+    sig = text[text.index("func.func public @main("):]
+    sig = sig[:sig.index(") -> ")]
+    args = re.split(r",?\s*(?=%arg\d+: )", sig[sig.index("%arg0"):])
+    return [("jax.buffer_donor" in a or "tf.aliasing_output" in a)
+            for a in args if a]
+
+
+@pytest.mark.parametrize("cell,low_mb,high_mb", [
+    ("mistral", 48, 60), ("gigachat", 10, 18)])
+def test_cell_decode_steps_lend_the_ring_and_keep_their_temporaries(
+        sds, monkeypatch, record_property, cell, low_mb, high_mb):
+    """Both cells' ``decode_step`` at the depth they run (16 layers;
+    1 dense + 4 expert layers): the ring is the one piece of decode
+    state that is NOT donated (the host fetches step n's after step n+1
+    is out), which costs no temporary worth the name: 53.9 MB and 14 MB
+    declared before it (PERF.md), one Pallas call a layer still."""
+    if cell == "mistral":
+        run, shapes = _cell_runner(monkeypatch, layers=None)
+        lowered = _lower_decode_program(sds, run, shapes)
+        kernels = run.config.num_hidden_layers
+    else:
+        run, shapes = _latent_cell_runner(monkeypatch)
+        from paddle_tpu.serving.parallel import latent
+        slots = run.max_slots
+        pool = sds(latent.pool_shape(run.config, run.num_pages,
+                                     run.page_size))
+        rope = sds((run._rope_len, run.config.qk_rope_head_dim),
+                   jnp.float32)
+
+        def i32(*shape):
+            return sds(shape, jnp.int32)
+        lowered = run._make_step_fn().lower(
+            {name: sds(shape) for name, shape in shapes.items()}, pool,
+            (), (), (), i32(slots, run.table_width), i32(slots),
+            i32(slots), i32(slots), i32(1, slots), i32(), rope, rope, (),
+            (), sds(latent.counters0().shape, latent.counters0().dtype))
+        kernels = None
+    donated = _donated(lowered)
+    n_state = len(shapes)
+    # after the weights: pool(s), table, pos, tok, active, ring, ridx
+    tail = donated[n_state:]
+    pools = 2 if cell == "mistral" else 1
+    assert tail[:pools] == [True] * pools               # the pools
+    table, pos, tok, active, ring, ridx = tail[pools:pools + 6]
+    assert (pos, tok, ridx) == (True, True, True)
+    assert (table, active) == (False, False)            # read, not written
+    assert ring is False                                # lent, not given
+    compiled = lowered.compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    record_property("temp_size_in_bytes", temp)
+    print(f"{cell} decode_step: temp_size_in_bytes {temp}")
+    assert low_mb * 1e6 < temp < high_mb * 1e6
+    if kernels is not None:
+        assert lowered.as_text().count("@tpu_custom_call") == kernels
+
+
+def test_slot_patch_is_one_program_at_the_cells_shapes(sds, monkeypatch):
+    """``push_slot`` for the Mistral cell's 32 slots of 64 pages: one
+    program whose slot is data, every state array donated and updated
+    where it lies."""
+    run, _ = _cell_runner(monkeypatch)
+    slots, width = run.max_slots, run.table_width
+
+    def i32(*shape):
+        return sds(shape, jnp.int32)
+    lowered = run._make_push_fn().lower(
+        i32(slots, width), i32(slots), i32(slots), i32(slots), (),
+        i32(5 + width))
+    assert run.push_traces == 1
+    assert _donated(lowered) == [True, True, True, True, False]
+    compiled = lowered.compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+    text = compiled.as_text()
+    assert "custom_call_target=\"tpu_custom_call\"" not in text
+    assert text.count("input_output_alias") == 1
+    for out in range(4):                # each output aliases its input
+        assert re.search(rf"\{{{out}\}}: \({out}, \{{\}}", text), out
 
 
 def test_int8_pages_decode_step_reports_its_temporaries(sds, monkeypatch,

@@ -99,9 +99,11 @@ def test_prefill_then_decode_logits_match_the_reference(share):
         logits = np.asarray(logits)
         for slot, r in enumerate(eng.scheduler.slots):
             # the step that produced token n of r read position
-            # len(prompt) + n - 2 ... keep (tokens so far, row)
-            if r is not None and r.num_generated >= 2:
-                rows[r.id].append((r.num_generated, logits[slot]))
+            # len(prompt) + n - 2 ... keep (token it makes, row).  The
+            # host runs one step behind: the step just dispatched makes
+            # the token AFTER those the request has been handed
+            if r is not None:
+                rows[r.id].append((r.num_generated + 1, logits[slot]))
     assert eng.decode_traces == 1
     m = model_dict(cfg)
     for p, r in zip(prompts, reqs):
@@ -431,8 +433,11 @@ def test_counters_stay_on_the_device_until_asked(share):
     eng = engine(cfg, state)
     _run(eng, [([1, 2, 3], 5), ([4, 5, 6, 7], 5)])
     s = eng.stats()
-    # 2 slots x 4 decode steps x 4 choices x 2 expert layers
-    assert s["moe_routed_pairs"] == 2 * 4 * 4 * 2
+    # 2 slots x 5 decode steps x 4 choices x 2 expert layers: 4 steps
+    # make tokens 2..5, and the overrun step was dispatched before the
+    # host had seen the fourth's row (both finishes are seen one late)
+    assert s["decode_steps"] == 5 and s["overrun_rows"] == 2
+    assert s["moe_routed_pairs"] == 2 * 5 * 4 * 2
     assert 0 < s["moe_local_pairs"] <= s["moe_routed_pairs"]
     assert 0 < s["moe_experts_live"] <= 4 * 2 * 8
     assert s["moe_local_pairs"] >= s["moe_experts_live"]

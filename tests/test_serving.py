@@ -351,8 +351,10 @@ def test_engine_scheduler_eviction_parks_slot(tiny_model):
     a = eng.submit(np.arange(1, 6), GenerationConfig(max_new_tokens=40))
     b = eng.submit(np.arange(1, 6), GenerationConfig(max_new_tokens=2))
     d = eng.submit(np.arange(1, 6), GenerationConfig(max_new_tokens=30))
-    eng.step()                  # all three admitted; b finishes (slot 1)
-    assert b.state == RequestState.DONE
+    eng.step()                  # all three admitted, first step dispatched
+    assert b.state == RequestState.DECODE   # its row is still in flight
+    eng.step()                  # ... and walked after the second's
+    assert b.state == RequestState.DONE     # dispatch: b finishes (slot 1)
     d.cancel()
     eng.step()                  # scheduler evicts d from slot 2
     assert d.state == RequestState.CANCELLED
@@ -527,8 +529,13 @@ def test_engine_sync_interval_host_syncs_and_logits_skip(tiny_model):
     _, ref = _greedy_outputs(model, [p], [9], **kw)
     eng, got = _greedy_outputs(model, [p], [9], sync_interval=4, **kw)
     assert got == ref
-    # 8 decode steps (the 9th token comes from prefill) = 2 ring drains
+    # 8 decode steps make tokens 2..9 (the first comes from prefill) =
+    # 2 ring drains; the 9th step is the overrun dispatched before the
+    # second drain (the host runs one step behind): its row is dropped
+    # unfetched, and the 8th row, walked after it, ends the request
     assert eng.host_syncs == 2
+    assert eng.decode_steps == 9 and eng.overrun_rows == 1
+    assert eng.overlapped_steps == 8            # all but the first
     # all-greedy: emit_logits=True must not pull logits to the host
     assert eng.logit_fetches == 0
 
@@ -538,6 +545,8 @@ def test_engine_sync_interval_host_syncs_and_logits_skip(tiny_model):
     eng.run_until_complete(max_steps=100)
     assert rs.num_generated == 4
     assert eng.logit_fetches >= 3               # one per sampled step
+    # ... in lockstep: no step is dispatched ahead of a sampled token
+    assert eng.overlapped_steps == 8 and eng.overrun_rows == 1
     assert eng.decode_traces == 1
 
 
@@ -568,21 +577,25 @@ def test_engine_paged_block_counters(tiny_model, monkeypatch):
     s0 = counted()
     assert s0.tolist() == [0, 0]
     # prompt 30, 5 tokens: the first from the prefill, then 4 decode
-    # steps that see 31, 32, 33, 34 tokens = 1 + 1 + 2 + 2 blocks of 12;
-    # prompt 63, 4 tokens: 3 steps over 64, 65, 66 = 2 + 3 + 3 of 9
+    # steps that see 31, 32, 33, 34 tokens = 1 + 1 + 2 + 2 blocks, and
+    # the overrun step (dispatched before the host saw the finish) over
+    # 35 = 2 more, of 15; prompt 63, 4 tokens: 3 steps over 64, 65, 66
+    # = 2 + 3 + 3 and its overrun step over 67 = 3 more, of 12
     serve((30, 5), (63, 4))
     s1 = counted()
-    assert (s1 - s0).tolist() == [14, 21]
-    # prompt 10, 3 tokens: 2 steps over 11, 12 tokens = 1 + 1 of 6
+    assert (s1 - s0).tolist() == [14 + 2 + 3, 21 + 3 + 3]
+    # prompt 10, 3 tokens: 2 steps over 11, 12 tokens = 1 + 1, and the
+    # overrun step over 13 = 1 more, of 9
     serve((10, 3))
     s2 = counted()
-    assert (s2 - s1).tolist() == [2, 6]
-    assert (s2 - s0).tolist() == [16, 27]
+    assert (s2 - s1).tolist() == [2 + 1, 6 + 3]
+    assert (s2 - s0).tolist() == [22, 36]
+    assert eng.stats()["overrun_rows"] == 3     # one a request
     # the same numbers ride the dispatch spans, step by step
     spans = [s for s in obs.tracer().spans()
              if s.name == "engine.decode.dispatch"]
     assert len(spans) == eng.decode_steps
-    assert [sum(s.attributes[k] for s in spans) for k in keys] == [16, 27]
+    assert [sum(s.attributes[k] for s in spans) for k in keys] == [22, 36]
 
 
 def test_engine_prefix_cache_staggered_no_retrace(tiny_model):

@@ -105,8 +105,14 @@ def test_mesh_continuous_batching_parity_no_retrace(tp_model):
     e2, got = drive(2)
     assert got == ref
     assert e1.decode_traces == e2.decode_traces == 1
-    # deferred host sync batches ring drains identically on the mesh
+    # deferred host sync batches ring drains identically on the mesh,
+    # and the host lags the mesh's step as it lags one chip's: the same
+    # steps dispatched ahead, the same overrun rows (one a finish, and
+    # what a group of 3 rows holds past it) dropped
     assert e2.host_syncs == e1.host_syncs
+    assert e2.overlapped_steps == e1.overlapped_steps > 0
+    assert e2.overrun_rows == e1.overrun_rows >= len(got)
+    assert e2.decode_steps == e1.decode_steps
 
 
 def test_mesh_prefix_cache_cow_divergence(tp_model):

@@ -286,11 +286,19 @@ class TestEnginePhases:
             range(engine.progress))
         decoding = [s for s in steps if s.attributes["active"]]
         assert len(decoding) == engine.decode_steps > 0
+        first_decode = True
         for step in steps:
             kids = sorted((s for s in spans if s.parent_id == step.span_id),
                           key=lambda s: s.start)
-            want = self.STEP_PARTS if step.attributes["active"] \
-                else self.STEP_PARTS[:1]
+            # the host runs one step behind: a step fetches and walks
+            # the row of the step BEFORE it, after its own dispatch, so
+            # the first decoding step has nothing to fetch yet
+            want = self.STEP_PARTS[:1]
+            if step.attributes["active"]:
+                want = self.STEP_PARTS[:2] if first_decode \
+                    else self.STEP_PARTS
+                assert kids[1].attributes["overlapped"] is not first_decode
+                first_decode = False
             assert [k.name for k in kids] == want
             assert step.parent_id is None
             at = step.start
@@ -302,6 +310,13 @@ class TestEnginePhases:
         emits = [s for s in spans if s.name == "engine.emit"]
         assert all(s.attributes["rows"] == s.attributes["steps"] == 1
                    for s in emits)
+        # decode_steps includes the overrun step dispatched before the
+        # last finish was seen: its row is dropped unfetched, every
+        # other step's row is fetched by the step after it
+        assert len(emits) == engine.host_syncs == engine.decode_steps - 1
+        assert engine.overlapped_steps == engine.decode_steps - 1
+        # one overrun row a request: each finish is seen one step late
+        assert engine.overrun_rows == len(reqs)
         # every token but each request's first comes out of an emit
         assert sum(s.attributes["tokens"] for s in emits) == sum(
             r.num_generated - 1 for r in reqs)

@@ -246,8 +246,12 @@ def _reinject_retrace(eng):
 
 def scenario_steady_decode(inject_retrace=False) -> dict:
     """Greedy decode across two admission waves: the decode step must
-    trace ONCE for the engine's lifetime, each step costs exactly one
-    host sync (sync_interval=1), and no logits ever cross the wire."""
+    trace ONCE for the engine's lifetime, each step that makes a token
+    costs exactly one host sync (sync_interval=1), and no logits ever
+    cross the wire.  The host runs one step behind the device, so each
+    wave's last finish is seen after one more step has been dispatched:
+    a wave is 7 steps whose rows are fetched and the overrun step whose
+    row is dropped unfetched — 14 syncs over 16 steps = 0.875."""
     eng = _engine(max_slots=2, page_size=4, sync_interval=1)
     reqs = [eng.submit([1, 2, 3, 4, 5, 6], _gen(8)),
             eng.submit([3, 4, 5, 6, 7, 8], _gen(8))]
@@ -295,7 +299,11 @@ def scenario_prefix_cache() -> dict:
 
 def scenario_deferred_sync() -> dict:
     """sync_interval=4 greedy decode must amortize the ring fetch over
-    4 device steps — host syncs are the serving scalability ceiling."""
+    4 device steps — host syncs are the serving scalability ceiling.
+    9 steps over 2 fetches: the 7 that make tokens 2..8, the second
+    group's 4th row (overrun, as ever at this interval), and the step
+    dispatched before that group was fetched (the host runs one step
+    behind; its row is dropped unfetched)."""
     eng = _engine(max_slots=2, page_size=4, sync_interval=4)
     reqs = [eng.submit([1, 2, 3, 4, 5, 6], _gen(8)),
             eng.submit([2, 3, 4, 5, 6, 7], _gen(8))]
@@ -363,6 +371,8 @@ def scenario_tp_decode() -> dict:
         "decode_traces": e2.decode_traces,
         "prefill_compiles": (len(e2._prefill_fns)
                              + len(e2._prefill_cached_fns)),
+        # 0.875 as in steady_decode: each wave ends with one overrun
+        # step whose row is dropped unfetched (7 syncs over 8 steps)
         "host_syncs_per_decode_step": round(
             e2.host_syncs / max(e2.decode_steps, 1), 6),
         "host_syncs_delta_vs_tp1": e2.host_syncs - e1.host_syncs,
@@ -469,7 +479,11 @@ def scenario_telemetry() -> dict:
     syncs / decode traces over the sampler-off control (the
     zero-overhead contract of FLAGS_obs_timeseries_interval_s).
     Sources read engine python mirrors, not the process registry, so
-    the scenario is isolated no matter which scenarios ran before."""
+    the scenario is isolated no matter which scenarios ran before.
+    One tick a supervisor step and one before: 9 = the 7 steps whose
+    rows make the surviving request's tokens 2..8, the overrun step
+    after which the host sees the last of them (it runs one step behind
+    the device), and the baseline tick."""
     from paddle_tpu import observability as obs
     from paddle_tpu.serving import EngineSupervisor, FaultPlan
 
