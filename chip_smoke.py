@@ -2,6 +2,7 @@
 
     python chip_smoke.py               # one TPU chip: a server, then a trainer
     python chip_smoke.py --four-chips  # four chips: the sharded paths only
+    python chip_smoke.py --ssm-update  # one chip: the state-update kernel
 
 One process, which touches JAX itself and starts no child.  Any phase that
 raises, any device that is not a TPU, any check that fails ends the run
@@ -26,6 +27,14 @@ Default run, on one chip:
   train  BERT-base at its published defaults, batch 32 x seq 384,
          ``paddle.jit.train_step`` + AdamW + bf16 autocast, until the loss
          on one fixed batch falls below the first step's.
+
+``--ssm-update`` runs ``ops/pallas/ssm_update.py``'s kernel at the
+serving cell's shape (64 slots of 128 x 4096, bfloat16 and float32)
+against its XLA form with slots parked at the front, in the middle, at the
+end, all and none: the live slots' new states and outputs, every parked
+slot's and every other layer's state bit for bit; then times it with every
+slot live and with half of them parked (a parked slot moves no bytes: half
+the time), at two block widths.
 
 ``--four-chips`` runs only what exists across chips, each beside what it is
 compared with: the same server at ``mesh="tp=4"`` and at ``mesh=None``, and
@@ -63,7 +72,7 @@ SEED = 0     # weights, prompts and batches are all made from it
 
 PALLAS_MODULES = ("flash_attention", "flash_mask", "paged_attention",
                   "decode_attention", "quant_matmul", "lora_matmul",
-                  "grouped_ffn")
+                  "grouped_ffn", "ssm_update")
 
 
 def say(**fields):
@@ -144,15 +153,19 @@ def runner_kernels(runner) -> dict:
     r = runner
     pools = (r.kpool, r.vpool, r.kscale, r.vscale)
     tail = (r._cos, r._sin, r.lora, r._prefill_aidx(0))
+    # a recurrent family's per-slot state and the prefill's slot; empty
+    # tuples for the others
+    slot = jnp.zeros((), jnp.int32) if r.recurrent else ()
     i32 = jnp.int32
     out = {"decode_step": kernels_in(r._step_fn, (
         r.state, *pools, r._table_dev, r._pos_dev, r._tok_dev,
         r._active_dev, r._ring_dev, r._ridx_dev, r._cos, r._sin, r.lora,
-        r._aidx_dev, r._counters_dev))}
+        r._aidx_dev, r._counters_dev, r._rstate))}
     for bucket, fn in sorted(r._prefill_fns.items()):
         out[f"prefill[{bucket}]"] = kernels_in(fn, (
             r.state, jnp.zeros((1, bucket), i32), jnp.zeros((1,), i32),
-            jnp.zeros((bucket // r.page_size,), i32), *pools, *tail))
+            jnp.zeros((bucket // r.page_size,), i32), *pools, *tail,
+            r._rstate, slot))
     for bucket, fn in sorted(r._prefill_cached_fns.items()):
         out[f"prefill_cached[{bucket}]"] = kernels_in(fn, (
             r.state, jnp.zeros((1, bucket), i32), jnp.zeros((1,), i32),
@@ -482,11 +495,129 @@ def hybrid_phase(cfg, devices, *, seed, layouts=((1, 1, 4), (1, 2, 2)),
     return out
 
 
+# ------------------------------------------------------- the state update
+PARKED = {"none": lambda s: [], "first": lambda s: [0],
+          "middle": lambda s: [s // 2], "last": lambda s: [s - 1],
+          "front-half": lambda s: list(range(s // 2)),
+          "every-other": lambda s: list(range(0, s, 2)),
+          "all-but-last": lambda s: list(range(s - 1)),
+          "all": lambda s: list(range(s))}
+
+
+def ssm_update_phase(*, seed, slots=64, n=128, hp=4096, layers=3,
+                     dtypes=("bfloat16", "float32"),
+                     lane_blocks=(2048, 4096), reps=20) -> dict:
+    """``ssm_state_update`` against ``ssm_state_update_xla`` on layer 1
+    of ``layers`` with slots parked as ``PARKED`` names them, then its
+    time a layer with every slot live and with the front half parked."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import ssm_update as U
+    t0 = time.perf_counter()
+    rng = np.random.default_rng(seed)
+    rows = {k: jnp.asarray(v, jnp.float32) for k, v in (
+        ("decay", rng.uniform(0.5, 1.0, (slots, hp))),
+        ("dtx", rng.normal(size=(slots, hp))),
+        ("b", rng.normal(size=(slots, n))),
+        ("c", rng.normal(size=(slots, n))))}
+    out = {"phase": "ssm_update", "slots": slots, "state": [n, hp],
+           "checks": {}, "ms_a_layer": {}}
+
+    def active_of(parked):
+        act = np.ones((slots,), np.int32)
+        act[parked] = 0
+        return jnp.asarray(act)
+
+    for dtype in dtypes:
+        pool = jnp.asarray(rng.normal(size=(layers, slots, n, hp)),
+                           jnp.float32).astype(dtype)
+        was = np.asarray(pool.astype(jnp.float32))
+        # one rounding of the pool's dtype: the two forms may fuse the
+        # multiply and the add differently
+        ulp = 2.0 ** -7 if dtype == "bfloat16" else 2.0 ** -22
+        kernel = jax.jit(U.ssm_state_update, static_argnums=1)
+        twin = jax.jit(U.ssm_state_update_xla, static_argnums=1)
+        for name, pick in PARKED.items():
+            act = active_of(pick(slots))
+            live = np.asarray(act, bool)
+            args = (rows["decay"], rows["dtx"], rows["b"], rows["c"], act)
+            got, got_y = kernel(pool, 1, *args)
+            want, want_y = twin(pool, 1, *args)
+            got = np.asarray(got.astype(jnp.float32))
+            want = np.asarray(want.astype(jnp.float32))
+            state_gap = float(np.max(
+                np.abs(got[1][live] - want[1][live])
+                / np.maximum(np.abs(want[1][live]), 1.0), initial=0.0))
+            y_gap = float(np.max(np.abs(np.asarray(got_y)
+                                        - np.asarray(want_y))
+                                 / (1.0 + np.abs(np.asarray(want_y)))))
+            found = {
+                "live": int(live.sum()), "state_gap": state_gap,
+                "y_gap": y_gap,
+                "parked_untouched": bool(
+                    np.array_equal(got[1][~live], was[1][~live])),
+                "other_layers_untouched": bool(
+                    np.array_equal(got[0], was[0])
+                    and np.array_equal(got[2:], was[2:])),
+                "parked_y_zero": not np.asarray(got_y)[~live].any()}
+            out["checks"][f"{dtype}.{name}"] = found
+            if not (state_gap <= ulp and y_gap <= 1e-4
+                    and found["parked_untouched"]
+                    and found["other_layers_untouched"]
+                    and found["parked_y_zero"]):
+                raise RuntimeError(
+                    f"ssm_state_update differs from its XLA form with "
+                    f"{name} parked ({dtype}): {found}")
+        del was
+        for lanes in lane_blocks:
+            if hp % lanes:
+                continue
+            for name in ("none", "front-half"):
+                act = active_of(PARKED[name](slots))
+                out["ms_a_layer"][f"{dtype}.lanes{lanes}.{name}"] = (
+                    _time_update(U, lanes, pool, rows, act, reps))
+        del pool
+    out["seconds"] = round(time.perf_counter() - t0, 2)
+    return out
+
+
+def _time_update(U, lanes, pool, rows, act, reps):
+    """Milliseconds a layer of ``ssm_state_update`` over every layer of
+    a donated pool at ``lanes`` to a block, or the compiler's refusal."""
+    import jax
+    layers = pool.shape[0]
+    was, U.LANE_BLOCK = U.LANE_BLOCK, lanes
+
+    def every_layer(pool, decay, dtx, b, c, act):
+        for layer in range(layers):
+            pool, y = U.ssm_state_update(pool, layer, decay, dtx, b, c,
+                                         act)
+        return pool, y
+
+    try:
+        fn = jax.jit(every_layer, donate_argnums=0)
+        args = (rows["decay"], rows["dtx"], rows["b"], rows["c"], act)
+        try:
+            held, y = fn(pool + 0, *args)       # a copy to donate
+        except Exception as e:       # e.g. more VMEM than a kernel may use
+            return f"refused: {type(e).__name__}: {str(e)[:200]}"
+        jax.block_until_ready(y)
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            held, y = fn(held, *args)
+        jax.block_until_ready((held, y))
+        return round((time.perf_counter() - t0) * 1e3 / (reps * layers), 4)
+    finally:
+        U.LANE_BLOCK = was
+
+
 # ------------------------------------------------------------------- main
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--four-chips", action="store_true",
                     help="run only the paths that span four chips")
+    ap.add_argument("--ssm-update", action="store_true",
+                    help="run only the state-update kernel's check")
     args = ap.parse_args(argv)
 
     from paddle_tpu.utils.compile_cache import enable_compile_cache
@@ -496,6 +627,10 @@ def main(argv=None) -> int:
     import jax
     say(jax=jax.__version__, compile_cache=cache_dir,
         devices=[str(d) for d in devices])
+
+    if args.ssm_update:
+        say(**ssm_update_phase(seed=SEED))
+        return _ok(devices)
 
     from paddle_tpu.models.bert import BertConfig
     from paddle_tpu.models.llama import llama3_8b
@@ -517,6 +652,10 @@ def main(argv=None) -> int:
         say(**serve_phase(cfg, devices, seed=SEED))
         say(**train_phase(BertConfig(), devices, seed=SEED))
 
+    return _ok(devices)
+
+
+def _ok(devices) -> int:
     d = devices[0]
     print(json.dumps({"ok": True, "device": {
         "platform": d.platform, "kind": d.device_kind,

@@ -220,6 +220,58 @@ def _rope_at(cos, sin, pos):
     return jnp.take(cos, pos, axis=0), jnp.take(sin, pos, axis=0)
 
 
+def residual_add(x, y, cfg):
+    """``x + y``, or ``x + residual_multiplier * y`` where the model
+    description has one (no attribute: the plain sum, the same
+    program)."""
+    rm = getattr(cfg, "residual_multiplier", None)
+    return x + y if rm is None else x + y * jnp.asarray(rm, y.dtype)
+
+
+def _position_and_scale(q, k, cos, sin, cfg, width):
+    """What a description says of q and k before attention: the rotary
+    encoding unless ``position_embedding_type`` is "nope", and, where it
+    names an ``attention_multiplier``, that softmax scale in place of
+    the kernels' own ``1 / sqrt(width)`` (``width`` the head dim the
+    kernel will see), folded into q."""
+    if getattr(cfg, "position_embedding_type", "rope") != "nope":
+        q = q * cos + _rotate_half(q) * sin
+        k = k * cos + _rotate_half(k) * sin
+    mult = getattr(cfg, "attention_multiplier", None)
+    if mult is not None:
+        q = q * jnp.asarray(mult * np.sqrt(width), q.dtype)
+    return q, k
+
+
+def mlp_block(w, x, cfg, *, li, axis=None, lora=(), aidx=None):
+    """A layer's second half, on one token a row (x [B, H]) or a prompt
+    (x [B, S, H]): ``x + MLP(RMSNorm(x))``.  Every layer of every family
+    the runner serves through ``_ffn`` ends here."""
+    with jax.named_scope("mlp"):
+        if x.ndim == 2:
+            h = _rms(x[:, None], w["ln2"], cfg.rms_norm_eps)[:, 0]
+        else:
+            h = _rms(x, w["ln2"], cfg.rms_norm_eps)
+        return residual_add(x, _ffn(w, h, axis, lora, aidx, li), cfg)
+
+
+def _attend_packed(cache, q, group: int, pack: int, li, table, lens, axis):
+    """``cache.attend`` over pools that hold ``pack`` KV heads side by
+    side in one row (a head dim below the TPU's 128 lanes: the page
+    copies stay whole lane rows and no lane is padding).  Each query
+    head's values go to its own KV head's part of the row and zeros to
+    the rest, so its scores are its head's alone; of the output row it
+    keeps that part.  ``group`` query heads share a KV head."""
+    b, nh, d = q.shape
+    # [nh, pack] one-hot: the part of the row that is head h's KV head
+    part = (jnp.arange(nh) // group) % pack
+    parts = (part[:, None] == jnp.arange(pack)[None, :]).astype(q.dtype)
+    wide = (q[:, :, None, :] * parts[None, :, :, None]).reshape(
+        b, nh, pack * d)
+    out = cache.attend(wide, li, table, lens, axis).reshape(b, nh, pack, d)
+    return (out * parts[None, :, :, None]).sum(axis=2)
+
+
 # ------------------------------------------------------- the layer bodies
 # One Llama-family decoder layer, written twice: over a prompt
 # (``prefill_layer``) and over one token a slot against the paged cache
@@ -261,10 +313,9 @@ def prefill_layer(w, x, cos, sin, mask, cfg: LlamaConfig, *, li,
         q = qp.reshape(b, s, -1, hd)
         k = kp.reshape(b, s, -1, hd)
         v = vp.reshape(b, s, -1, hd)
-        cos_c = cos[None, :, None, :].astype(q.dtype)
-        sin_c = sin[None, :, None, :].astype(q.dtype)
-        q = q * cos_c + _rotate_half(q) * sin_c
-        k = k * cos_c + _rotate_half(k) * sin_c
+        cos_c = None if cos is None else cos[None, :, None, :].astype(q.dtype)
+        sin_c = None if sin is None else sin[None, :, None, :].astype(q.dtype)
+        q, k = _position_and_scale(q, k, cos_c, sin_c, cfg, hd)
 
     with jax.named_scope("attn.prefill"):
         # flash path: causal + key-padding mask, GQA in-kernel, O(S) memory
@@ -279,10 +330,8 @@ def prefill_layer(w, x, cos, sin, mask, cfg: LlamaConfig, *, li,
             attn = sdpa(q, kcat, vcat, attn_mask=mask, is_causal=False)
         attn = attn.reshape(b, s, qp.shape[-1])
     with jax.named_scope("attn.out"):
-        x = x + _out_proj(w, attn, axis, lora, aidx, li)
-    with jax.named_scope("mlp"):
-        h = _rms(x, w["ln2"], cfg.rms_norm_eps)
-        return (x + _ffn(w, h, axis, lora, aidx, li), k, v)
+        x = residual_add(x, _out_proj(w, attn, axis, lora, aidx, li), cfg)
+    return mlp_block(w, x, cfg, li=li, axis=axis, lora=lora, aidx=aidx), k, v
 
 
 def decode_layer(w, x, cache, table, cos1, sin1, pos, cfg: LlamaConfig, *,
@@ -299,29 +348,37 @@ def decode_layer(w, x, cache, table, cos1, sin1, pos, cfg: LlamaConfig, *,
     b = x.shape[0]
     hd = cfg.head_dim
     ps = cache.k.shape[3]
+    # KV heads a pool row holds: 1, but for a head dim under 128 lanes
+    pack = cache.k.shape[4] // hd
     with jax.named_scope("attn.qkv"):
         h = _rms(x[:, None], w["ln1"], cfg.rms_norm_eps)[:, 0]
         qp, kp, vp = _qkv_proj(w, h, cfg, lora, aidx, li)
         q = qp.reshape(b, -1, hd)
         k = kp.reshape(b, -1, hd)
         v = vp.reshape(b, -1, hd)
-        cos_c = cos1[:, None, :].astype(q.dtype)
-        sin_c = sin1[:, None, :].astype(q.dtype)
-        q = q * cos_c + _rotate_half(q) * sin_c
-        k = k * cos_c + _rotate_half(k) * sin_c
+        group = q.shape[1] // k.shape[1]    # query heads a KV head
+        cos_c = None if cos1 is None else cos1[:, None, :].astype(q.dtype)
+        sin_c = None if sin1 is None else sin1[:, None, :].astype(q.dtype)
+        q, k = _position_and_scale(q, k, cos_c, sin_c, cfg, hd * pack)
 
     with jax.named_scope("kv.write"):
         page = jnp.take_along_axis(table, (pos // ps)[:, None], axis=1)[:, 0]
+        if pack > 1:
+            k = k.reshape(b, -1, hd * pack)
+            v = v.reshape(b, -1, hd * pack)
         cache = cache.write(li, page, pos % ps, k, v)
 
     with jax.named_scope("attn.decode"):
-        attn = cache.attend(q, li, table, pos + 1, axis).reshape(
-            b, qp.shape[-1])
+        if pack > 1:
+            attn = _attend_packed(cache, q, group, pack, li, table, pos + 1,
+                                  axis)
+        else:
+            attn = cache.attend(q, li, table, pos + 1, axis)
+        attn = attn.reshape(b, qp.shape[-1])
     with jax.named_scope("attn.out"):
-        x = x + _out_proj(w, attn, axis, lora, aidx, li)
-    with jax.named_scope("mlp"):
-        h = _rms(x[:, None], w["ln2"], cfg.rms_norm_eps)[:, 0]
-        return x + _ffn(w, h, axis, lora, aidx, li), cache
+        x = residual_add(x, _out_proj(w, attn, axis, lora, aidx, li), cfg)
+    return (mlp_block(w, x, cfg, li=li, axis=axis, lora=lora, aidx=aidx),
+            cache)
 
 
 # ------------------------------------------- decode step, contiguous cache

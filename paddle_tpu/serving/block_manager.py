@@ -750,7 +750,10 @@ class BlockManager:
         mode: 1-byte KV elements plus the two f32 scale pools
         (``2 * [L, rows, kvh, page_size]``).  ``latent_width`` sizes a
         latent cache instead: ONE pool ``[L, rows, page_size, width]``,
-        a row a token a layer, no heads."""
+        a row a token a layer, no heads.  A family with recurrent layers
+        passes its attention layers alone as ``num_layers``: what it
+        keeps a slot beside the pages is the runner's
+        ``recurrent_state_bytes``."""
         if tp < 1 or num_kv_heads % tp:
             raise ValueError(
                 f"tp={tp} must be >= 1 and divide num_kv_heads="

@@ -85,6 +85,7 @@ from .block_manager import BlockManager
 from .faults import InjectedFault, fault_plan_from_flags
 from .parallel import ModelRunner, parse_mesh
 from .parallel import latent as _latent
+from .parallel import recurrent as _recurrent
 from .request import Request, RequestState
 from .scheduler import Scheduler
 
@@ -195,6 +196,7 @@ class Engine:
         self.latent = _latent.is_latent(config)
         if self.latent:     # the runner refuses tp, kv_quant and spec_k
             _latent.check_options(quant=bool(quant), lora=lora is not None)
+        self.recurrent = _recurrent.is_recurrent(config)
         self.quant = quant
         self.kv_quant = bool(kv_quant)
         if self.quant:
@@ -257,8 +259,20 @@ class Engine:
                 FLAGS.get("FLAGS_serving_prefill_chunk") or 0)
         self.prefill_chunk = max(int(prefill_chunk), 0)
         if preempt is None:
-            preempt = bool(FLAGS.get("FLAGS_serving_preempt"))
+            # on by default, but where the family refuses the spill it
+            # is off unless asked for (and then refused by name)
+            preempt = (bool(FLAGS.get("FLAGS_serving_preempt"))
+                       and not self.recurrent)
         self.preempt = bool(preempt)
+        if self.recurrent:
+            # what a per-slot recurrent state cannot do yet, by name
+            _recurrent.check_options(
+                mesh=self.tp > 1, kv_quant=self.kv_quant,
+                quant=bool(quant), lora=lora is not None,
+                spec_k=self.spec_k > 0,
+                enable_prefix_cache=self.enable_prefix_cache,
+                preempt=self.preempt,
+                prefill_chunk=self.prefill_chunk > 0)
         # chaos harness: None (the default when FLAGS_serving_fault_plan
         # is empty) keeps every injection site to a single None test
         self.faults = fault_plan_from_flags() if faults is None else faults
@@ -325,6 +339,17 @@ class Engine:
             sizing = self.blocks.pool_bytes(
                 num_layers=L, dtype_itemsize=self._embed_itemsize,
                 latent_width=_latent.pool_shape(config, 0, 1)[-1])
+        elif self.recurrent:
+            dtype = state[_recurrent.EMBED].dtype
+            self._embed_itemsize = int(np.dtype(dtype).itemsize)
+            # pages for the attention layers alone; the other layers'
+            # state is a fixed number of bytes a slot, which the runner
+            # counts (``recurrent_state_bytes``)
+            sizing = self.blocks.pool_bytes(
+                num_layers=len(config.attention_layers),
+                num_kv_heads=config.num_key_value_heads,
+                head_dim=config.head_dim,
+                dtype_itemsize=self._embed_itemsize)
         else:
             kvh, hd = config.num_key_value_heads, config.head_dim
             dtype = state["llama.embed_tokens.weight"].dtype
@@ -386,7 +411,7 @@ class Engine:
         self.paged_blocks_grid = 0
         # the device's expert counters as of the last stats() (the decode
         # span carries them; a step never fetches them)
-        self._moe_seen: dict = {}
+        self._counters_seen: dict = {}
         self.host_syncs = 0         # ring fetches (1 per sync_interval)
         # decode steps dispatched while an earlier step's row was still
         # unfetched (the device did not wait for the host), and rows
@@ -713,7 +738,8 @@ class Engine:
                                           int(row[cached // ps]))
                 if not chunked:
                     logits, bucket = self._dispatch_prefill(
-                        req.prompt[cached:], cached, row, req._adapter_row)
+                        req.prompt[cached:], cached, row, req._adapter_row,
+                        slot)
                     ph.set_attribute("bucket", bucket)
                 req.num_cached_tokens = cached
                 req.prefill_cached_tokens += cached
@@ -746,10 +772,12 @@ class Engine:
         self._emit(slot, req, tok, now)
 
     def _dispatch_prefill(self, tokens, cached: int, row,
-                          adapter_row: int):
+                          adapter_row: int, slot: int = 0):
         """Hand ``tokens`` (what follows the ``cached`` resident tokens
         of a sequence), padded to their page-multiple bucket, to the
-        runner's prefill program.  Returns (logits handle, bucket)."""
+        runner's prefill program, with the ``slot`` the sequence decodes
+        in (where a family keeps a state by slot).  Returns (logits
+        handle, bucket)."""
         n = len(tokens)
         bucket = -(-n // self.page_size) * self.page_size
         ids = np.zeros((1, bucket), np.int32)
@@ -757,7 +785,8 @@ class Engine:
         with _obs.tracer().phase("engine.prefill.dispatch"):
             if cached == 0:
                 logits = self.runner.prefill(ids, n, row,
-                                             adapter_row=adapter_row)
+                                             adapter_row=adapter_row,
+                                             slot=slot)
             else:
                 logits = self.runner.prefill_cached(
                     ids, n, cached, row, adapter_row=adapter_row)
@@ -827,7 +856,7 @@ class Engine:
             try:
                 logits, _ = self._dispatch_prefill(
                     ids_all[done:done + this], done, st["row"],
-                    getattr(req, "_adapter_row", 0))
+                    getattr(req, "_adapter_row", 0), slot)
                 st["chunks"] += 1
                 self.prefill_chunks += 1
                 req.prefill_chunks += 1
@@ -1053,7 +1082,7 @@ class Engine:
                                and suffix > self.prefill_chunk)
                 if suffix > 0 and not chunked:
                     self._dispatch_prefill(ids_all[cached:], cached, row,
-                                           req._adapter_row)
+                                           req._adapter_row, slot)
                     self._note_gap(suffix)
                 # the resume logits are discarded (the last token is
                 # already known) — no host sync happens here
@@ -1122,7 +1151,7 @@ class Engine:
         with self._phase("engine.decode.dispatch", "decode",
                          slots=len(active), paged_blocks_live=live,
                          paged_blocks_grid=grid, overlapped=overlapped,
-                         **self._moe_seen):
+                         **self._counters_seen):
             logits = self.runner.decode_step()
         self.decode_steps += 1
         self.overlapped_steps += overlapped
@@ -1653,7 +1682,8 @@ class Engine:
             req.prefill_computed_tokens += n - cached
             row = self.blocks.table_row(req.id, self.table_width)
             arow = getattr(req, "_adapter_row", 0)
-            self._dispatch_prefill(ids_all[cached:], cached, row, arow)
+            self._dispatch_prefill(ids_all[cached:], cached, row, arow,
+                                   slot)
             # the replay's logits are discarded (the last token is
             # already known), so no host sync happens here
             drift = self.blocks.committed_tokens(req.id) - len(tokens)
@@ -1706,7 +1736,8 @@ class Engine:
             "overrun_rows": self.overrun_rows,
             "paged_blocks_live": self.paged_blocks_live,
             "paged_blocks_grid": self.paged_blocks_grid,
-            **self._moe_counters(),
+            **self._device_counters(),
+            "recurrent_state_bytes": self.runner.recurrent_state_bytes,
             "pages_allocated": b.pages_allocated,
             "prefill_chunk": self.prefill_chunk,
             "prefill_chunks": self.prefill_chunks,
@@ -1732,12 +1763,13 @@ class Engine:
                                 if self.faults is not None else {}),
         }
 
-    def _moe_counters(self) -> dict:
-        """The expert layers' counters, fetched from the device now (a
-        family without experts has none).  The decode step's span shows
-        what the last call here read."""
-        self._moe_seen = self.runner.moe_counters()
-        return self._moe_seen
+    def _device_counters(self) -> dict:
+        """What the decode step counts on the device (the expert layers'
+        ``moe_*``, a recurrent family's ``ssm_rows_live``; a family that
+        counts nothing has none), fetched now.  The decode step's span
+        shows what the last call here read."""
+        self._counters_seen = self.runner.device_counters()
+        return self._counters_seen
 
     def _page_bytes(self, *, dense: bool = False) -> int:
         """Bytes one KV page pair (k + v, full heads) occupies — the
@@ -1749,8 +1781,10 @@ class Engine:
         if self.latent:
             return int(np.prod(self.runner.kpool.shape[2:])
                        * cfg.num_hidden_layers * self._embed_itemsize)
-        rows = (cfg.num_hidden_layers * cfg.num_key_value_heads
-                * self.page_size)
+        # a recurrent family pages its attention layers alone
+        layers = (len(cfg.attention_layers) if self.recurrent
+                  else cfg.num_hidden_layers)
+        rows = layers * cfg.num_key_value_heads * self.page_size
         elems = rows * cfg.head_dim
         if self.kv_quant and not dense:
             return 2 * elems + 2 * rows * 4

@@ -69,7 +69,8 @@ def build_step(runner, count_trace):
     emit_logits = runner.emit_logits
 
     def decode_step(state, pool, vpool, kscale, vscale, table, pos, tok,
-                    active, ring, ridx, cos, sin, lora, aidx, counters):
+                    active, ring, ridx, cos, sin, lora, aidx, counters,
+                    rstate):
         count_trace()
         with jax.named_scope("embed"):
             posc = jnp.minimum(pos, rope_len - 1)
@@ -91,7 +92,7 @@ def build_step(runner, count_trace):
             ridx2 = (ridx + 1) % ring.shape[0]
         return (pool, vpool, kscale, vscale, pos2, tok2, ring2, ridx2,
                 logits if emit_logits else jnp.zeros((), jnp.float32),
-                counters)
+                counters, rstate)
 
     return decode_step
 
@@ -103,7 +104,7 @@ def build_prefill(runner, bucket: int, count_trace):
     row = cfg.cache_row
 
     def prefill(state, ids, length, table_row, pool, vpool, kscale,
-                vscale, cos, sin, lora, aidx):
+                vscale, cos, sin, lora, aidx, rstate, slot):
         count_trace()
         with jax.named_scope("embed"):
             x = jnp.take(state[EMBED], ids, axis=0)
@@ -120,7 +121,7 @@ def build_prefill(runner, bucket: int, count_trace):
                 x, (length - 1)[:, None, None].astype(jnp.int32),
                 axis=1)
             logits = _head(cfg, state, last)[:, 0]
-        return pool, vpool, kscale, vscale, logits
+        return pool, vpool, kscale, vscale, logits, rstate
 
     return prefill
 

@@ -18,6 +18,11 @@ Two construction modes, selected by ``tp``:
 A model description whose ``family`` is ``"deepseek_v3"`` keeps the
 seam and changes the cache: one pool of latent rows, and the programs of
 ``latent.py`` (one chip only; the value pool is then the empty tuple).
+One whose ``family`` is ``"granitemoehybrid"`` keeps K/V pages for its
+attention layers and adds a per-slot recurrent state for the others: the
+programs of ``recurrent.py``, and ``rstate``, one more donated argument
+of every step and prefill program (the empty tuple for the other
+families).
 
 ``tp > 1``
     A 1-axis ``jax.sharding.Mesh`` over the first ``tp`` devices.
@@ -73,7 +78,7 @@ from ...models.llama import _rope_tables
 from ...models.llama_hybrid import _rms
 from ...ops.pallas.paged_attention import PagedKV
 from ...ops.pallas.quant_matmul import QuantizedWeight
-from . import latent
+from . import latent, recurrent
 from .mesh import TP_AXIS, mesh_devices, validate_tp
 
 __all__ = ["ModelRunner"]
@@ -160,11 +165,19 @@ class ModelRunner:
                 f" got {self.lora_rank}")
         # the cache the model description asks for: K and V pages per
         # head, or (latent.py) one pool of latent rows and no V pool
+        # ... or (recurrent.py) K/V pages for the attention layers and a
+        # per-slot recurrent state for the rest
         self.latent = latent.is_latent(config)
+        self.recurrent = recurrent.is_recurrent(config)
         if self.latent:
             latent.check_options(tp=self.tp > 1, kv_quant=self.kv_quant,
                                  lora_slots=self.lora_slots > 0,
                                  spec_k=self.spec_k > 0)
+        elif self.recurrent:
+            recurrent.check_options(mesh=self.tp > 1,
+                                    kv_quant=self.kv_quant,
+                                    lora=self.lora_slots > 0,
+                                    spec_k=self.spec_k > 0)
         else:
             validate_tp(config, self.tp)
         self._validate_quantized_state(state)
@@ -179,6 +192,12 @@ class ModelRunner:
                                            self.page_size)
             scale_shape = ()
             cos, sin = rope_tables(config, self._rope_len)
+        elif self.recurrent:            # no position encoding at all
+            dtype = state[recurrent.EMBED].dtype
+            pool_shape = recurrent.kv_pool_shape(config, self.num_pages,
+                                                 self.page_size)
+            scale_shape = ()
+            cos = sin = jnp.zeros((0,), jnp.float32)
         else:
             kvh, hd = config.num_key_value_heads, config.head_dim
             dtype = state["llama.embed_tokens.weight"].dtype
@@ -229,8 +248,13 @@ class ModelRunner:
             self._ridx_dev = jnp.zeros((), jnp.int32)
             # the expert layers' counters, kept on the device by the
             # decode step (no leaves where the family has no experts)
-            self._counters_dev = (latent.counters0() if self.latent
-                                  else ())
+            self._counters_dev = (
+                latent.counters0() if self.latent else
+                recurrent.counters0() if self.recurrent else ())
+            # the per-slot recurrent state (ssm, conv); no leaves where
+            # the family has none
+            self._rstate = (recurrent.state_pools(config, self.max_slots)
+                            if self.recurrent else ())
         else:
             from jax.sharding import Mesh, NamedSharding, PartitionSpec
             self._check_state_shardable(state)
@@ -283,7 +307,7 @@ class ModelRunner:
                 jnp.zeros(ring_shape, jnp.int32), rep)
             self._ridx_dev = jax.device_put(
                 jnp.zeros((), jnp.int32), rep)
-            self._counters_dev = ()
+            self._counters_dev = self._rstate = ()
 
         self.decode_traces = 0      # python mirror of _M_STEP_TRACES
         self.verify_traces = 0      # python mirror of _M_VERIFY_TRACES
@@ -309,6 +333,9 @@ class ModelRunner:
         self._pool_bytes_per_device = (
             int(per_device_pool_bytes) if per_device_pool_bytes
             else pool_total // self.tp)
+        self.recurrent_state_bytes = (
+            recurrent.state_bytes(config, self.max_slots)
+            if self.recurrent else 0)
         sharded = sum(
             _leaf_bytes(v) for k, v in state.items()
             if k.endswith(_COL_SHARDED) or k.endswith(_ROW_SHARDED))
@@ -540,7 +567,7 @@ class ModelRunner:
     def _make_step_fn(self):
         if self.tp == 1:
             return jax.jit(self._build_step(None),
-                           donate_argnums=(1, 2, 3, 4, 6, 7, 10, 15))
+                           donate_argnums=(1, 2, 3, 4, 6, 7, 10, 15, 16))
         from jax.sharding import PartitionSpec as P
         pool = self._pool_pspec
         sspec = self._scale_pspec if self.kv_quant else P()
@@ -548,9 +575,9 @@ class ModelRunner:
             self._build_step(TP_AXIS), mesh=self.mesh,
             in_specs=(self._state_specs(), pool, pool, sspec, sspec,
                       P(), P(), P(), P(), P(), P(), P(), P(),
-                      self._lora_pspecs(), P(), P()),
+                      self._lora_pspecs(), P(), P(), P()),
             out_specs=(pool, pool, sspec, sspec, P(), P(), P(), P(),
-                       P(), P()),
+                       P(), P(), P()),
             check_vma=False)
         return jax.jit(mapped, donate_argnums=(1, 2, 3, 4, 6, 7, 10))
 
@@ -568,6 +595,8 @@ class ModelRunner:
         the argmax'd next token and the ring) are device-invariant."""
         if self.latent:
             return latent.build_step(self, self._count_step_trace)
+        if self.recurrent:
+            return recurrent.build_step(self, self._count_step_trace)
         cfg = self.config
         L = cfg.num_hidden_layers
         emit_logits = self.emit_logits
@@ -577,7 +606,7 @@ class ModelRunner:
 
         def decode_step(state, kpool, vpool, kscale, vscale, table, pos,
                         tok, active, ring, ridx, cos, sin, lora, aidx,
-                        counters):
+                        counters, rstate):
             count_trace()
             cache = PagedKV(kpool, vpool, kscale, vscale)
             # a finished slot keeps decoding until the next host sync
@@ -607,7 +636,7 @@ class ModelRunner:
                 ridx2 = (ridx + 1) % ring.shape[0]
             return (*cache, pos2, tok2, ring2, ridx2,
                     logits if emit_logits else jnp.zeros((), jnp.float32),
-                    counters)
+                    counters, rstate)
 
         return decode_step
 
@@ -743,10 +772,10 @@ class ModelRunner:
         fn = self._prefill_fns.get(bucket)
         if fn is not None:
             return fn
-        if self.latent:
-            fn = jax.jit(latent.build_prefill(
+        if self.latent or self.recurrent:
+            fn = jax.jit((latent if self.latent else recurrent).build_prefill(
                 self, bucket, _M_PREFILL_TRACES.labels(str(bucket)).inc),
-                donate_argnums=(4, 5, 6, 7))
+                donate_argnums=(4, 5, 6, 7, 12))
             self._prefill_fns[bucket] = fn
             return fn
         cfg = self.config
@@ -756,7 +785,7 @@ class ModelRunner:
         axis = None if tp == 1 else TP_AXIS
 
         def prefill(state, ids, length, table_row, kpool, vpool,
-                    kscale, vscale, cos, sin, lora, aidx):
+                    kscale, vscale, cos, sin, lora, aidx, rstate, slot):
             _M_PREFILL_TRACES.labels(str(bucket)).inc()
             cache = PagedKV(kpool, vpool, kscale, vscale)
             with jax.named_scope("embed"):
@@ -776,12 +805,12 @@ class ModelRunner:
                     x, (length - 1)[:, None, None].astype(jnp.int32),
                     axis=1)[:, 0]
                 logits = _logits_of(state, last).astype(jnp.float32)
-            return (*cache, logits)
+            return (*cache, logits, rstate)
 
         # kpool/vpool donation: prefill updates the pool in place instead
         # of double-buffering the engine's whole KV footprint per admit
         if tp == 1:
-            fn = jax.jit(prefill, donate_argnums=(4, 5, 6, 7))
+            fn = jax.jit(prefill, donate_argnums=(4, 5, 6, 7, 12))
         else:
             from jax.sharding import PartitionSpec as P
             pool = self._pool_pspec
@@ -790,8 +819,8 @@ class ModelRunner:
                 prefill, mesh=self.mesh,
                 in_specs=(self._state_specs(), P(), P(), P(), pool,
                           pool, sspec, sspec, P(), P(),
-                          self._lora_pspecs(), P()),
-                out_specs=(pool, pool, sspec, sspec, P()),
+                          self._lora_pspecs(), P(), P(), P()),
+                out_specs=(pool, pool, sspec, sspec, P(), P()),
                 check_vma=False)
             fn = jax.jit(mapped, donate_argnums=(4, 5, 6, 7))
         self._prefill_fns[bucket] = fn
@@ -805,6 +834,8 @@ class ModelRunner:
         fn = self._prefill_cached_fns.get(bucket)
         if fn is not None:
             return fn
+        if self.recurrent:
+            recurrent.check_options(enable_prefix_cache=True)
         if self.latent:
             fn = jax.jit(latent.build_prefill_cached(
                 self, bucket,
@@ -892,12 +923,12 @@ class ModelRunner:
         t0 = time.perf_counter()
         (self.kpool, self.vpool, self.kscale, self.vscale,
          self._pos_dev, self._tok_dev, self._ring_dev, self._ridx_dev,
-         logits, self._counters_dev) = self._step_fn(
+         logits, self._counters_dev, self._rstate) = self._step_fn(
             self.state, self.kpool, self.vpool, self.kscale,
             self.vscale, self._table_dev, self._pos_dev, self._tok_dev,
             self._active_dev, self._ring_dev, self._ridx_dev,
             self._cos, self._sin, self.lora, self._aidx_dev,
-            self._counters_dev)
+            self._counters_dev, self._rstate)
         if self.decode_traces != traces_before:
             sig = f"slots={self.max_slots} ring={self.sync_interval}"
             if self.tp > 1:
@@ -942,22 +973,29 @@ class ModelRunner:
         return jnp.asarray(int(adapter_row), jnp.int32)
 
     def prefill(self, ids: np.ndarray, plen: int, row: np.ndarray,
-                adapter_row: int = 0):
+                adapter_row: int = 0, slot: int | None = None):
         """Full-prompt prefill: pages the prompt's KV into the pool and
         returns the last-token logits handle.  ``ids`` is the
-        [1, bucket] padded prompt."""
+        [1, bucket] padded prompt.  ``slot`` is where the request will
+        decode: a family with a per-slot recurrent state writes that
+        slot's state here (the others take no such argument: an empty
+        tuple, no leaf)."""
+        if self.recurrent and slot is None:
+            raise ValueError("a recurrent family's prefill writes its "
+                             "slot's state: pass slot=")
         bucket = ids.shape[1]
         fresh = bucket not in self._prefill_fns
         fn = self._prefill_fn(bucket)
         t0 = time.perf_counter()
         (self.kpool, self.vpool, self.kscale, self.vscale,
-         logits) = fn(
+         logits, self._rstate) = fn(
             self.state, jnp.asarray(ids),
             jnp.asarray([plen], jnp.int32),
             jnp.asarray(row[:bucket // self.page_size]),
             self.kpool, self.vpool, self.kscale, self.vscale,
             self._cos, self._sin, self.lora,
-            self._prefill_aidx(adapter_row))
+            self._prefill_aidx(adapter_row), self._rstate,
+            jnp.asarray(int(slot), jnp.int32) if self.recurrent else ())
         if fresh:
             record_compile(f"prefill[{bucket}]", t0,
                            signature=f"ids=[1,{bucket}]")
@@ -1089,13 +1127,15 @@ class ModelRunner:
             record_compile("push_slot", t0,
                            signature=f"row=[{packed.size}]")
 
-    def moe_counters(self) -> dict:
-        """The expert layers' counters since the runner was built, by
-        name ({} for a family without experts).  A device fetch: on
-        demand, never inside a step."""
-        if not self.latent:
+    def device_counters(self) -> dict:
+        """What the decode step counts on the device, since the runner
+        was built, by name: the expert layers' ``moe_*``, a recurrent
+        family's ``ssm_rows_live``; {} for a family that counts nothing.
+        A device fetch: on demand, never inside a step."""
+        if not (self.latent or self.recurrent):
             return {}
-        return latent.counters_by_name(np.asarray(self._counters_dev))
+        return (latent if self.latent else recurrent).counters_by_name(
+            np.asarray(self._counters_dev))
 
     def hold_ring(self):
         """Keep the ring as the last step left it, for the next
@@ -1136,6 +1176,7 @@ class ModelRunner:
             entry = {
                 "device": f"{d.platform}:{d.id}", TP_AXIS: i,
                 "kv_pool_bytes": self._pool_bytes_per_device,
+                "recurrent_state_bytes": self.recurrent_state_bytes,
                 "weight_bytes": self._weight_bytes_per_device,
                 "lora_bank_bytes": self._lora_bytes_per_device,
             }

@@ -193,9 +193,10 @@ class EngineWorker:
         thread keeps running but stops calling ``engine.step()``, so an
         in-flight request sits in its slot making zero progress (the
         condition the serving watchdog exists to catch)."""
-        with self._wake:
-            self._stall_until = time.monotonic() + float(seconds)
-            self._wake.notify_all()
+        # no lock: the loop holds ``_wake`` while it steps and may not
+        # hand it over before the request is done; it reads this at its
+        # next turn (an idle loop has nothing to wedge)
+        self._stall_until = time.monotonic() + float(seconds)
 
     # ------------------------------------------------------------ intake
     @property

@@ -168,8 +168,8 @@ def _cell_runner(monkeypatch, *, spec_k=0, kv_quant=False, layers=2):
     return run, {k: shape for k, (shape, _) in decoder_shapes(m).items()}
 
 
-def _bare_runner(monkeypatch, config, eng, *, latent=False, spec_k=0,
-                 kv_quant=False):
+def _bare_runner(monkeypatch, config, eng, *, latent=False,
+                 recurrent=False, spec_k=0, kv_quant=False):
     """A ``ModelRunner`` that holds what its program builders read and
     nothing on a device: ``config`` with a cell's ``engine`` sizes."""
     from paddle_tpu.serving.parallel.runner import ModelRunner
@@ -178,6 +178,7 @@ def _bare_runner(monkeypatch, config, eng, *, latent=False, spec_k=0,
     run = object.__new__(ModelRunner)
     run.config = config
     run.tp, run.mesh, run.latent, run.emit_logits = 1, None, latent, False
+    run.recurrent = recurrent
     run.spec_k, run.kv_quant = spec_k, kv_quant
     run.max_slots, run.page_size = eng["max_slots"], eng["page_size"]
     run.table_width = eng["max_model_len"] // run.page_size
@@ -239,7 +240,7 @@ def _lower_decode_program(sds, run, shapes):
             i32(slots), i32(slots), i32(slots))
     if k == 0:
         return run._make_step_fn().lower(
-            *head, i32(1, slots), i32(), rope, rope, (), (), ())
+            *head, i32(1, slots), i32(), rope, rope, (), (), (), ())
     return run._make_verify_fn().lower(
         *head, i32(1, slots, k + 1), i32(), i32(slots, k), i32(slots),
         rope, rope, (), ())
@@ -331,10 +332,186 @@ def test_cell_programs_keep_their_kernels_and_scope_order(
             {name: sds(shape) for name, shape in shapes.items()},
             sds((1, bucket), jnp.int32), sds((1,), jnp.int32),
             sds((bucket // run.page_size,), jnp.int32), pool, pool, (), (),
-            rope, rope, (), ())
+            rope, rope, (), (), (), ())
     assert lowered.as_text().count("@tpu_custom_call") == layers
     assert _scope_order(lowered, {"embed", "head", *layer_scopes}) == (
         ["embed"] + layer_scopes * layers + ["head"])
+
+
+# sha256 (first 16 hex digits) of the two existing serving cells' lowered
+# programs as the PARENT of PR 32 (commit ec95bdd) lowers them, with every
+# Pallas call's ``backend_config`` blanked (it holds the kernel as
+# bytecode WITH source paths and lines, which differ between checkouts
+# of one program: PERF.md, PR 30).  Computed from a ``git archive`` of
+# that commit with the arguments it took then; here the programs take
+# ``rstate`` (and the prefill ``slot``) as empty tuples: no leaf, no op.
+# A jax upgrade moves these; so does any edit that reaches the Mistral
+# or the GigaChat program, which is what they are here to show.
+PARENT_HLO = {
+    ("mistral", "decode_step"): "8fadd2647ac63de8",
+    ("mistral", "prefill"): "b60cf16e4026bb63",
+    ("gigachat", "decode_step"): "82aae69a205bf43f",
+    ("gigachat", "prefill"): "ea3b1ffe856fee5e"}
+
+
+@pytest.mark.parametrize("cell,program", sorted(PARENT_HLO))
+def test_existing_cells_programs_are_the_parents_hlo(sds, monkeypatch, cell,
+                                                     program):
+    """The recurrent family rides the shared programs as one more
+    argument that is empty for the others, and the shared layer pieces
+    read multipliers the others do not have: the Mistral cell's and the
+    GigaChat cell's ``decode_step`` and ``prefill[256]``, at the depth
+    they run, still lower to the parent's StableHLO."""
+    import hashlib
+    from paddle_tpu.serving.parallel import latent
+
+    def i32(*shape):
+        return sds(shape, jnp.int32)
+    if cell == "mistral":
+        run, shapes = _cell_runner(monkeypatch, layers=None)
+        pools = (sds(_pool_shape(run)),) * 2
+        rope = sds((run._rope_len, HD), jnp.float32)
+        counters = ()
+    else:
+        run, shapes = _latent_cell_runner(monkeypatch)
+        pools = (sds(latent.pool_shape(run.config, run.num_pages,
+                                       run.page_size)), ())
+        rope = sds((run._rope_len, run.config.qk_rope_head_dim),
+                   jnp.float32)
+        counters = sds(latent.counters0().shape, latent.counters0().dtype)
+    state = {name: sds(shape) for name, shape in shapes.items()}
+    slots = run.max_slots
+    if program == "decode_step":
+        lowered = run._make_step_fn().lower(
+            state, *pools, (), (), i32(slots, run.table_width), i32(slots),
+            i32(slots), i32(slots), i32(1, slots), i32(), rope, rope, (),
+            (), counters, ())
+    else:
+        run._prefill_fns = {}
+        lowered = run._prefill_fn(256).lower(
+            state, i32(1, 256), i32(1), i32(256 // run.page_size), *pools,
+            (), (), rope, rope, (), (), (), ())
+    text = re.sub(r'backend_config = "[^"]*"', 'backend_config = ""',
+                  lowered.as_text())
+    assert "@tpu_custom_call" in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == PARENT_HLO[
+        cell, program]
+
+
+def _hybrid_cell_runner(monkeypatch):
+    """The Granite cell's runner, as ``_cell_runner`` makes the Mistral
+    cell's: the configuration as the benchmark's driver reads it, all 40
+    layers, shapes only."""
+    import json
+    import sys
+    from paddle_tpu.models.granite_hybrid import GraniteHybridConfig
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.lib import hybrid_state
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "granite-4.0-h-micro.json")) as f:
+        conf = json.load(f)
+    m, eng = conf["model"], conf["engine"]
+    run = _bare_runner(monkeypatch, GraniteHybridConfig(
+        intermediate_size=m["shared_intermediate_size"],
+        layer_types=tuple(m["layer_types"]),
+        dtype=conf["assumed"]["torch_dtype"],
+        **{k: m[k] for k in (
+            "vocab_size", "hidden_size", "num_hidden_layers",
+            "num_attention_heads", "num_key_value_heads", "mamba_n_heads",
+            "mamba_d_head", "mamba_d_state", "mamba_n_groups",
+            "mamba_d_conv", "mamba_expand", "mamba_chunk_size",
+            "mamba_conv_bias", "mamba_proj_bias", "embedding_multiplier",
+            "attention_multiplier", "residual_multiplier", "logits_scaling",
+            "position_embedding_type", "rms_norm_eps",
+            "max_position_embeddings", "tie_word_embeddings")}), eng,
+        recurrent=True)
+    return run, {k: shape for k, (shape, _)
+                 in hybrid_state.shapes(conf).items()}
+
+
+def _lower_hybrid_program(sds, run, shapes, program):
+    """The Granite cell's ``decode_step`` or ``prefill[256]``, donated as
+    the runner donates, lowered from shapes."""
+    from paddle_tpu.serving.parallel import recurrent
+    slots = run.max_slots
+    state = {name: sds(shape) for name, shape in shapes.items()}
+    pool = sds(recurrent.kv_pool_shape(run.config, run.num_pages,
+                                       run.page_size))
+    rstate = recurrent.state_pools(run.config, slots, zeros=sds)
+    none = sds((0,), jnp.float32)
+
+    def i32(*shape):
+        return sds(shape, jnp.int32)
+    if program == "decode_step":
+        return run._make_step_fn().lower(
+            state, pool, pool, (), (), i32(slots, run.table_width),
+            i32(slots), i32(slots), i32(slots), i32(1, slots), i32(), none,
+            none, (), (), i32(1), rstate)
+    bucket = 256
+    run._prefill_fns = {}
+    return run._prefill_fn(bucket).lower(
+        state, i32(1, bucket), i32(1), i32(bucket // run.page_size), pool,
+        pool, (), (), none, none, (), (), rstate, i32())
+
+
+MAMBA_DECODE = ["ssm.in_proj", "ssm.conv", "ssm.update", "ssm.gate",
+                "ssm.out", "mlp"]
+MAMBA_PREFILL = ["ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate",
+                 "ssm.out", "mlp", "ssm.write"]
+ATTN_DECODE = ["attn.qkv", "kv.write", "attn.decode", "attn.out", "mlp"]
+ATTN_PREFILL = ["attn.qkv", "attn.prefill", "attn.out", "mlp", "kv.write"]
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill"])
+def test_hybrid_cell_programs_keep_their_kernels_and_scope_order(
+        sds, monkeypatch, record_property, program):
+    """The Granite cell's programs at the published widths, all 40
+    layers: one ``ssm_state_update`` call a Mamba layer (decode), one
+    ``paged_attention`` call an attention layer at two KV heads of 64 a
+    128-lane row (decode), the flash kernel at head dim 64 (prefill),
+    and the scopes in layer order.  The compiled ``decode_step`` updates
+    the recurrent state where it lies: no second copy of the 2.42 GB
+    pool (temporaries well under 1 GB)."""
+    run, shapes = _hybrid_cell_runner(monkeypatch)
+    cfg = run.config
+    lowered = _lower_hybrid_program(sds, run, shapes, program)
+    mamba, attn = ((MAMBA_DECODE, ATTN_DECODE) if program == "decode_step"
+                   else (MAMBA_PREFILL, ATTN_PREFILL))
+    want = ["embed"]
+    for kind in cfg.layer_types:
+        want += mamba if kind == "mamba" else attn
+    assert _scope_order(lowered, set(want) | {"head"}) == want + ["head"]
+    text = lowered.as_text()
+    calls = text.count("@tpu_custom_call")
+    if program == "prefill":
+        assert calls == len(cfg.attention_layers)       # flash, D = 64
+        return
+    assert calls == cfg.num_hidden_layers       # a kernel a layer
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    updates = _hlo_lines(hlo, "ssm_state_update")
+    assert len(updates) == len(cfg.mamba_layers) == 36
+    paged = _hlo_lines(hlo, "paged_attention")
+    assert len(paged) == 4
+    # each roofline's pattern finds its own kernel's events and no other
+    for lines, metric in ((updates, "ssm_update_roofline.serve"),
+                          (paged, "paged_attention_roofline.serve.hybrid")):
+        found = [ln for ln in updates + paged if any(
+            re.search(p, ln) for p in _metric_events(metric))]
+        assert found == lines, metric
+    mem = compiled.memory_analysis()
+    record_property("temp_size_in_bytes", mem.temp_size_in_bytes)
+    print(f"granite decode_step: temp {mem.temp_size_in_bytes}, "
+          f"arguments {mem.argument_size_in_bytes}, "
+          f"aliased {mem.alias_size_in_bytes}")
+    assert mem.temp_size_in_bytes < 400e6
+    # weights 6.38 + ssm 2.42 (bfloat16, the served dtype) + conv 0.06
+    # + K/V 2.15 GB, nothing padded
+    assert 10.9e9 < mem.argument_size_in_bytes < 11.2e9
+    # the state pools and the K/V pools are all updated in place
+    assert mem.alias_size_in_bytes > 2.41e9 + 2.1e9
 
 
 def _donated(lowered) -> list[bool]:
@@ -376,7 +553,7 @@ def test_cell_decode_steps_lend_the_ring_and_keep_their_temporaries(
             {name: sds(shape) for name, shape in shapes.items()}, pool,
             (), (), (), i32(slots, run.table_width), i32(slots),
             i32(slots), i32(slots), i32(1, slots), i32(), rope, rope, (),
-            (), sds(latent.counters0().shape, latent.counters0().dtype))
+            (), sds(latent.counters0().shape, latent.counters0().dtype), ())
         kernels = None
     donated = _donated(lowered)
     n_state = len(shapes)

@@ -127,6 +127,30 @@ def test_train_phase_loss_falls():
     json.dumps(out)                         # every line it prints is JSON
 
 
+def test_ssm_update_phase_checks_every_parking(monkeypatch):
+    """The state-update kernel's chip check at a toy size, under the
+    Pallas interpreter: every way of parking in both dtypes, and a
+    kernel that touches a parked slot is refused."""
+    from paddle_tpu.ops.pallas import ssm_update as U
+    monkeypatch.setattr(U, "_INTERPRET", True)
+    out = C.ssm_update_phase(seed=C.SEED, slots=6, n=16, hp=256,
+                             lane_blocks=(128, 256), reps=1)
+    assert len(out["checks"]) == 2 * len(C.PARKED)
+    assert all(c["parked_untouched"] and c["other_layers_untouched"]
+               for c in out["checks"].values())
+    assert out["checks"]["bfloat16.all"]["live"] == 0
+    assert len(out["ms_a_layer"]) == 8 and U.LANE_BLOCK == 2048
+    json.dumps(out)
+    xla = U.ssm_state_update_xla
+
+    def touches_the_parked(pool, layer, decay, dtx, b, c, active):
+        return xla(pool, layer, decay, dtx, b, c, active * 0 + 1)
+    monkeypatch.setattr(U, "ssm_state_update", touches_the_parked)
+    with pytest.raises(RuntimeError, match="with first parked"):
+        C.ssm_update_phase(seed=C.SEED, slots=6, n=16, hp=256,
+                           dtypes=("float32",), lane_blocks=(), reps=1)
+
+
 def test_interpret_switches_are_checked(monkeypatch):
     from paddle_tpu.ops.pallas import paged_attention
     C.interpret_is_off()

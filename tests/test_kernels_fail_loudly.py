@@ -106,9 +106,22 @@ def test_paged_chooser_takes_the_kernel_on_tpu(fake_tpu):
     assert PA.select_paged_attention() is PA.paged_attention
 
 
+def test_state_update_chooser_takes_the_kernel_on_tpu(fake_tpu, monkeypatch):
+    """The recurrent family's decode step: on a TPU the Pallas kernel and
+    nothing else; a failure inside it reaches the caller."""
+    from paddle_tpu.ops.pallas import ssm_update as SU
+    assert SU.select_ssm_state_update() is SU.ssm_state_update
+    monkeypatch.setattr(SU, "ssm_state_update", _boom)
+    pool = jnp.zeros((1, 2, 8, 128), jnp.float32)
+    row, col = jnp.zeros((2, 128)), jnp.zeros((2, 8))
+    with pytest.raises(RuntimeError, match="scoped vmem exceeded"):
+        SU.select_ssm_state_update()(pool, 0, row, row, col, col,
+                                     jnp.ones((2,), jnp.int32))
+
+
 @pytest.mark.parametrize("name", [
     "flash_attention.py", "decode_attention.py", "paged_attention.py",
-    "quant_matmul.py", "lora_matmul.py"])
+    "quant_matmul.py", "lora_matmul.py", "ssm_update.py"])
 def test_no_handler_between_a_kernel_and_its_caller(name):
     """No ``try`` at all in the kernel files: nothing there opens a
     resource, so a handler could only be hiding a kernel failure."""

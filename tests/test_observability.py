@@ -181,16 +181,22 @@ class TestRetraceTelemetry:
         x = paddle.to_tensor(np.ones((8, 8), np.float32))
         probe(x)                                    # populate cache
         N = 300
-        t0 = time.perf_counter()
-        for _ in range(N):
-            probe(x)
-        dispatch = time.perf_counter() - t0
-
         hits = reg.get("eager_cache_hits_total")
-        t0 = time.perf_counter()
-        for _ in range(N):
-            hits.inc()
-        metrics_cost = time.perf_counter() - t0
+
+        def best_of(fn, tries=5):
+            # the least of a few timings: on a shared machine one
+            # descheduling inside 30 us of counter upkeep reads as
+            # thirty times its cost
+            took = []
+            for _ in range(tries):
+                t0 = time.perf_counter()
+                for _ in range(N):
+                    fn()
+                took.append(time.perf_counter() - t0)
+            return min(took)
+
+        dispatch = best_of(lambda: probe(x))
+        metrics_cost = best_of(hits.inc)
         assert metrics_cost < 0.10 * dispatch, (
             f"metrics {metrics_cost * 1e6 / N:.2f}us/hit vs dispatch "
             f"{dispatch * 1e6 / N:.2f}us/hit")

@@ -428,11 +428,11 @@ class TestDeviceNames:
         names = [n for f in sorted(os.listdir(self.PALLAS))
                  if f.endswith(".py")
                  for n in self._kernel_names(os.path.join(self.PALLAS, f))]
-        assert len(names) == 25 == len(set(names))
+        assert len(names) == 26 == len(set(names))
         assert {"paged_attention", "flash_fwd", "flash_bwd_dq",
                 "flash_bwd_dkv", "rms_norm", "decode_attention",
                 "mla_paged_attention", "mla_cache_write",
-                "grouped_matmul"} <= set(names)
+                "grouped_matmul", "ssm_state_update"} <= set(names)
 
     def test_programs_and_scopes_carry_their_names(self, tiny_model):
         engine = create_engine(tiny_model, max_slots=2, page_size=PAGE,
@@ -447,7 +447,8 @@ class TestDeviceNames:
             r.state, r.kpool, r.vpool, r.kscale, r.vscale, r._table_dev,
             r._pos_dev, r._tok_dev, r._active_dev, r._ring_dev,
             r._ridx_dev, r._cos, r._sin, r.lora,
-            r._aidx_dev, r._counters_dev).as_text(debug_info=True)
+            r._aidx_dev, r._counters_dev,
+            r._rstate).as_text(debug_info=True)
         assert "jit_decode_step" in text
         for scope in self.SCOPES:
             assert f"jit(decode_step)/{scope}/" in text, scope
@@ -486,7 +487,8 @@ class TestDeviceNames:
             r.state, r.kpool, r.vpool, r.kscale, r.vscale, r._table_dev,
             r._pos_dev, r._tok_dev, r._active_dev, r._ring_dev,
             r._ridx_dev, r._cos, r._sin, r.lora,
-            r._aidx_dev, r._counters_dev).as_text(debug_info=True)
+            r._aidx_dev, r._counters_dev,
+            r._rstate).as_text(debug_info=True)
         for scope in ("embed", "attn.mla.q", "attn.mla.kv", "kv.write",
                       "attn.decode", "attn.out", "mlp", "moe.route",
                       "moe.experts", "moe.shared", "head"):
@@ -495,10 +497,71 @@ class TestDeviceNames:
             r.state, jnp.zeros((1, PAGE), jnp.int32),
             jnp.ones((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
             r.kpool, r.vpool, r.kscale, r.vscale, r._cos, r._sin, (),
-            ()).as_text(debug_info=True)
+            (), (), ()).as_text(debug_info=True)
         for scope in ("attn.mla.q", "attn.mla.kv", "attn.prefill",
                       "kv.write", "moe.experts", "head"):
             assert f"jit(prefill)/{scope}/" in text, scope
+
+    @staticmethod
+    def _hybrid_engine():
+        import jax.numpy as jnp
+        from paddle_tpu.models import granite_hybrid as gh
+        from paddle_tpu.serving.engine import Engine
+        cfg = gh.GraniteHybridConfig(
+            vocab_size=64, hidden_size=32, intermediate_size=64,
+            num_hidden_layers=3,
+            layer_types=("mamba", "attention", "mamba"),
+            num_attention_heads=4, num_key_value_heads=2, mamba_n_heads=4,
+            mamba_d_head=16, mamba_d_state=8, mamba_chunk_size=8,
+            max_position_embeddings=128, dtype="float32")
+        rng = np.random.default_rng(0)
+        state = {k: jnp.asarray(
+            np.ones(s) if k.endswith(("norm.weight", ".D", "A_log"))
+            else 0.1 * rng.normal(size=s), jnp.float32)
+            for k, s in gh.weight_shapes(cfg).items()}
+        return Engine(config=cfg, state=state, max_slots=2, page_size=PAGE,
+                      max_model_len=64)
+
+    def test_recurrent_familys_programs_scopes_and_counter(self):
+        """The hybrid family's programs under the same names, the Mamba
+        layer's scopes beside the attention layer's, and the decode span
+        carrying ``ssm_rows_live`` as ``stats()`` last read it."""
+        import jax.numpy as jnp
+        obs.tracer().reset()
+        engine = self._hybrid_engine()
+        r = engine.runner
+        assert r._step_fn.__name__ == "decode_step"
+        assert r._prefill_fn(PAGE).__name__ == "prefill"
+        text = r._step_fn.lower(
+            r.state, r.kpool, r.vpool, r.kscale, r.vscale, r._table_dev,
+            r._pos_dev, r._tok_dev, r._active_dev, r._ring_dev,
+            r._ridx_dev, r._cos, r._sin, r.lora, r._aidx_dev,
+            r._counters_dev, r._rstate).as_text(debug_info=True)
+        for scope in ("embed", "ssm.in_proj", "ssm.conv", "ssm.update",
+                      "ssm.gate", "ssm.out", "attn.qkv", "kv.write",
+                      "attn.decode", "attn.out", "mlp", "head"):
+            assert f"jit(decode_step)/{scope}/" in text, scope
+        text = r._prefill_fn(PAGE).lower(
+            r.state, jnp.zeros((1, PAGE), jnp.int32),
+            jnp.ones((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
+            r.kpool, r.vpool, r.kscale, r.vscale, r._cos, r._sin, (), (),
+            r._rstate, jnp.zeros((), jnp.int32)).as_text(debug_info=True)
+        for scope in ("ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate",
+                      "ssm.out", "ssm.write", "attn.prefill", "kv.write",
+                      "mlp", "head"):
+            assert f"jit(prefill)/{scope}/" in text, scope
+        engine.submit(np.array(PROMPT, np.int32),
+                      GenerationConfig(max_new_tokens=4))
+        engine.step()
+        engine.step()
+        seen = engine.stats()
+        assert seen["ssm_rows_live"] >= 2       # two Mamba layers a step
+        engine.run_until_complete(max_steps=50)
+        spans = [s for s in obs.tracer().spans()
+                 if s.name == "engine.decode.dispatch"]
+        assert "ssm_rows_live" not in spans[0].attributes
+        assert spans[-1].attributes["ssm_rows_live"] == \
+            seen["ssm_rows_live"]
 
     def test_decode_span_carries_the_expert_counters(self):
         """``engine.decode.dispatch`` shows the device's expert counters
@@ -843,27 +906,46 @@ class TestWatchdogIntegration:
 
         def consume():
             done["toks"] = [t for ev in
-                            cl.completion(PROMPT, max_tokens=32,
+                            cl.completion(PROMPT, max_tokens=64,
                                           stream=True)
                             for t in ev["choices"][0]["token_ids"]]
 
         t = threading.Thread(target=consume, daemon=True)
         try:
+            # the first request compiles the programs, which on a busy
+            # machine outlasts both the watchdog's 0.15 s and this
+            # test's patience: let it pass, and count stalls from here
+            # (the watched request is long enough to be caught running
+            # once nothing compiles)
+            cl.completion(PROMPT, max_tokens=4)
+            deadline = time.monotonic() + 5.0
+            while srv.watchdog.state()["stalled"]:
+                assert time.monotonic() < deadline
+                time.sleep(0.02)
+            before = srv.watchdog.stalls
+            dump_before = srv.watchdog.last_dump_path
             t.start()
             deadline = time.monotonic() + 10.0
-            while not srv.worker.stats()["active"]:
+            # what the watchdog itself reads, lock-free: stats() waits
+            # for the engine's lock, which a stepping worker may not
+            # hand over before the request is done
+            while not srv.worker.engine.scheduler.active_count:
                 assert time.monotonic() < deadline, "request never ran"
-                time.sleep(0.005)
+                time.sleep(0.002)
             req = srv.worker.requests[-1]
             srv.worker.inject_stall(0.8)
             deadline = time.monotonic() + 5.0
-            while srv.watchdog.stalls == 0:
+            while srv.watchdog.stalls == before:
                 assert time.monotonic() < deadline, \
                     "watchdog did not trip on an injected stall"
                 time.sleep(0.01)
             state = srv.watchdog.state()
-            assert state["stalled"] is True and state["stalls"] >= 1
-            assert cl.healthz()["watchdog"]["stalls"] >= 1
+            assert state["stalled"] is True and state["stalls"] > before
+            assert cl.healthz()["watchdog"]["stalls"] > before
+            # the count goes up before the report is written
+            while srv.watchdog.last_dump_path == dump_before:
+                assert time.monotonic() < deadline, "no hang report"
+                time.sleep(0.01)
             doc = json.loads(open(srv.watchdog.last_dump_path).read())
             assert doc["active_slots"] >= 1
             assert any(e.get("req") == req.id and e["event"] == "submit"
@@ -873,7 +955,7 @@ class TestWatchdogIntegration:
             assert "engine-worker" in thread_names
             # the stall passes, the stream finishes, the latch clears
             t.join(timeout=30.0)
-            assert not t.is_alive() and len(done["toks"]) == 32
+            assert not t.is_alive() and len(done["toks"]) == 64
             deadline = time.monotonic() + 5.0
             while srv.watchdog.state()["stalled"]:
                 assert time.monotonic() < deadline
