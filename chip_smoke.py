@@ -3,6 +3,7 @@
     python chip_smoke.py               # one TPU chip: a server, then a trainer
     python chip_smoke.py --four-chips  # four chips: the sharded paths only
     python chip_smoke.py --ssm-update  # one chip: the state-update kernel
+    python chip_smoke.py --mla-decode  # one chip: the latent decode kernel
 
 One process, which touches JAX itself and starts no child.  Any phase that
 raises, any device that is not a TPU, any check that fails ends the run
@@ -35,6 +36,13 @@ end, all and none: the live slots' new states and outputs, every parked
 slot's and every other layer's state bit for bit; then times it with every
 slot live and with half of them parked (a parked slot moves no bytes: half
 the time), at two block widths.
+
+``--mla-decode`` runs ``ops/pallas/mla_paged_attention.py``'s kernel at
+the GigaChat cell's shape (64 slots, 64 heads over latents of 512 + 64,
+pages of 16 rows, 256 table columns, 5 layers in one pool) against its
+dense-gather XLA form at contexts of 1, 628, 1,170 and 4,032 tokens and
+at a mix with an empty slot, the largest gap printed; then times it, call
+after call inside one program, at each block size swept.
 
 ``--four-chips`` runs only what exists across chips, each beside what it is
 compared with: the same server at ``mesh="tp=4"`` and at ``mesh=None``, and
@@ -72,7 +80,7 @@ SEED = 0     # weights, prompts and batches are all made from it
 
 PALLAS_MODULES = ("flash_attention", "flash_mask", "paged_attention",
                   "decode_attention", "quant_matmul", "lora_matmul",
-                  "grouped_ffn", "ssm_update")
+                  "grouped_ffn", "ssm_update", "mla_paged_attention")
 
 
 def say(**fields):
@@ -611,6 +619,108 @@ def _time_update(U, lanes, pool, rows, act, reps):
         U.LANE_BLOCK = was
 
 
+# ------------------------------------------------- the latent decode kernel
+# Both forms round the softmax's weights to bfloat16 before p.c, the
+# kernel against a running maximum and a chunk at a time, so they differ
+# by roundings of 2**-9 of a weight that mostly cancel: outputs are
+# means of unit normals, and a wrong page, length or mask moves them by
+# tenths.
+MLA_TOL = 2.0 ** -6
+
+
+def mla_decode_phase(*, seed, slots=64, heads=64, rank=512, rope=64,
+                     page=16, width=256, layers=5,
+                     contexts=(1, 628, 1170, 4032),
+                     blocks=(256, 512, 1024, 2048, 4096), calls=200,
+                     dtype="bfloat16", tol=MLA_TOL) -> dict:
+    """``mla_paged_attention`` against ``mla_paged_attention_xla`` with
+    every slot at each of ``contexts`` and at a mix (one slot empty, the
+    rest anywhere up to the table's width), pages scattered over the
+    pool and the table padded by the dump page; then milliseconds a call
+    and GB/s of the rows' content at each of ``blocks`` tokens a block."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import mla_paged_attention as M
+    t0 = time.perf_counter()
+    rng = np.random.default_rng(seed)
+    kp, kl, kr = jax.random.split(jax.random.key(seed), 3)
+    dump = slots * width
+    pool = jax.random.normal(kp, (layers, dump + 1, page, M.row_width(
+        rank + rope)), jnp.float32).astype(dtype)
+    ql = jax.random.normal(kl, (slots, heads, rank), jnp.float32
+                           ).astype(dtype)
+    qr = jax.random.normal(kr, (slots, heads, rope), jnp.float32
+                           ).astype(dtype)
+    scale = (rank + rope) ** -0.5
+    owned = rng.permutation(dump).reshape(slots, width).astype(np.int32)
+    most = width * page
+    mixed = rng.integers(1, most + 1, slots)
+    mixed[slots // 2] = 0
+    lens_of = {str(c): np.full((slots,), min(c, most)) for c in contexts}
+    lens_of["mixed"] = mixed
+    out = {"phase": "mla_decode", "slots": slots, "heads": heads,
+           "row": [rank, rope], "page": page, "table": width,
+           "layers": layers, "tol": tol, "gap": {}, "ms_a_call": {},
+           "gb_a_s": {}}
+
+    def table_of(lens):
+        used = np.arange(width)[None, :] < -(-lens[:, None] // page)
+        return jnp.asarray(np.where(used, owned, dump), jnp.int32)
+
+    twin = jax.jit(lambda ql, qr, pool, table, lens:
+                   M.mla_paged_attention_xla(ql, qr, pool, layers - 1,
+                                             table, lens, sm_scale=scale))
+    cases = {}      # name -> (lens, table, lens on the device, XLA's answer)
+    for name, lens in lens_of.items():
+        table, n = table_of(lens), jnp.asarray(lens, jnp.int32)
+        cases[name] = (lens, table, n, twin(ql, qr, pool, table, n))
+    was = M.BLOCK_TOKENS
+    try:
+        for tokens in blocks:
+            M.BLOCK_TOKENS = tokens     # read as a call is traced
+
+            def one_call(ql, qr, pool, table, lens, layer=layers - 1):
+                return M.mla_paged_attention(ql, qr, pool, layer, table,
+                                             lens, sm_scale=scale)
+
+            def every_call(ql, qr, pool, table, lens):
+                # one program, a call a turn of the loop on layer
+                # i % layers: the device's time, no dispatch between
+                return jax.lax.fori_loop(
+                    0, calls, lambda i, _: one_call(ql, qr, pool, table,
+                                                    lens, i % layers),
+                    jnp.zeros_like(ql))
+
+            kernel, timed = jax.jit(one_call), jax.jit(every_call)
+            for name, (lens, table, n, want) in cases.items():
+                key = f"block{tokens}.ctx{name}"
+                try:
+                    got = kernel(ql, qr, pool, table, n)
+                except Exception as e:  # more VMEM than a kernel may use
+                    out["ms_a_call"][key] = (
+                        f"refused: {type(e).__name__}: {str(e)[:200]}")
+                    continue
+                gap = float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                            - want.astype(jnp.float32))))
+                out["gap"][key] = gap
+                if not gap <= tol:      # a NaN fails too
+                    raise RuntimeError(
+                        f"mla_paged_attention differs from its XLA form "
+                        f"at {key}: {gap} > {tol}")
+                jax.block_until_ready(timed(ql, qr, pool, table, n))
+                t = time.perf_counter()
+                jax.block_until_ready(timed(ql, qr, pool, table, n))
+                ms = (time.perf_counter() - t) * 1e3 / calls
+                out["ms_a_call"][key] = round(ms, 4)
+                out["gb_a_s"][key] = round(
+                    int(lens.sum()) * (rank + rope) * pool.dtype.itemsize
+                    / ms / 1e6, 1)
+    finally:
+        M.BLOCK_TOKENS = was
+    out["seconds"] = round(time.perf_counter() - t0, 2)
+    return out
+
+
 # ------------------------------------------------------------------- main
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
@@ -618,6 +728,8 @@ def main(argv=None) -> int:
                     help="run only the paths that span four chips")
     ap.add_argument("--ssm-update", action="store_true",
                     help="run only the state-update kernel's check")
+    ap.add_argument("--mla-decode", action="store_true",
+                    help="run only the latent decode kernel's check")
     args = ap.parse_args(argv)
 
     from paddle_tpu.utils.compile_cache import enable_compile_cache
@@ -630,6 +742,9 @@ def main(argv=None) -> int:
 
     if args.ssm_update:
         say(**ssm_update_phase(seed=SEED))
+        return _ok(devices)
+    if args.mla_decode:
+        say(**mla_decode_phase(seed=SEED))
         return _ok(devices)
 
     from paddle_tpu.models.bert import BertConfig

@@ -80,6 +80,8 @@ from ..flags import FLAGS
 from ..observability.resources import resource_tracker
 from ..models.generation import GenerationConfig
 from ..models.llama import LlamaConfig
+from ..ops.pallas.mla_paged_attention import (
+    pages_per_block as latent_pages_per_block)
 from ..ops.pallas.paged_attention import pages_per_block
 from .block_manager import BlockManager
 from .faults import InjectedFault, fault_plan_from_flags
@@ -225,9 +227,11 @@ class Engine:
                 f"max_model_len {self.max_model_len} exceeds the model's "
                 f"max_position_embeddings {config.max_position_embeddings}")
         self.table_width = -(-self.max_model_len // self.page_size)
-        # the paged decode kernel's unit of work, by its own rule: the
-        # tokens one grid step covers and the steps a slot's row makes
-        blk = pages_per_block(self.page_size, self.table_width)
+        # the paged decode kernel's unit of work, by its own rule (the
+        # latent kernel has its own): the tokens one grid step covers
+        # and the steps a slot's row makes
+        rule = latent_pages_per_block if self.latent else pages_per_block
+        blk = rule(self.page_size, self.table_width)
         self._block_tokens = blk * self.page_size
         self._blocks_per_row = -(-self.table_width // blk)
         if num_pages is None:       # full residency: every slot can run

@@ -347,10 +347,15 @@ def test_cell_programs_keep_their_kernels_and_scope_order(
 # ``rstate`` (and the prefill ``slot``) as empty tuples: no leaf, no op.
 # A jax upgrade moves these; so does any edit that reaches the Mistral
 # or the GigaChat program, which is what they are here to show.
+# The GigaChat ``decode_step`` is PR 33's own (its parent's was
+# 82aae69a205bf43f): the block table rides flat into
+# ``mla_paged_attention``, so each layer reshapes it ([64, 256] ->
+# [16384]) and the call's first operand is that; with SSA numbers
+# stripped nothing else differs from the parent's text.
 PARENT_HLO = {
     ("mistral", "decode_step"): "8fadd2647ac63de8",
     ("mistral", "prefill"): "b60cf16e4026bb63",
-    ("gigachat", "decode_step"): "82aae69a205bf43f",
+    ("gigachat", "decode_step"): "b32ad1ec7af330f5",
     ("gigachat", "prefill"): "ea3b1ffe856fee5e"}
 
 
@@ -616,23 +621,54 @@ def test_int8_pages_decode_step_reports_its_temporaries(sds, monkeypatch,
         assert "copy" not in opcode and "copy" not in stem, (stem, opcode)
 
 
+# what a Mosaic call that sets no ``vmem_limit_bytes`` may use on a v5e
+V5E_SCOPED_VMEM = 16 << 20
+
+
+def _pallas_call(fn, *args):
+    """The one ``pallas_call`` equation ``fn`` traces to."""
+    found = [e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert len(found) == 1, found
+    return found[0]
+
+
 def test_mla_paged_attention_at_the_cells_shapes(sds):
     """64 slots, 256 pages a slot, 5 layers in one pool, rows of 576
     values (declared at the 640 lanes they occupy), 64 heads over a
-    latent of 512 + 64."""
-    slots, width, heads = 64, 256, 64
+    latent of 512 + 64: the grid the kernel's own block rule gives, its
+    buffers inside the VMEM the call asks for."""
+    slots, width, heads, page = 64, 256, 64, 16
     assert MLA.row_width(576) == 640
-    text = compiled_text(
-        lambda ql, qr, pool, t, n: MLA.mla_paged_attention(
-            ql, qr, pool, 3, t, n, sm_scale=0.1447),
-        sds((slots, heads, 512)), sds((slots, heads, 64)),
-        sds((5, slots * width + 1, 16, 640)),
-        sds((slots, width), jnp.int32), sds((slots,), jnp.int32))
+
+    def call(ql, qr, pool, t, n):
+        return MLA.mla_paged_attention(ql, qr, pool, 3, t, n,
+                                       sm_scale=0.1447)
+    args = (sds((slots, heads, 512)), sds((slots, heads, 64)),
+            sds((5, slots * width + 1, page, 640)),
+            sds((slots, width), jnp.int32), sds((slots,), jnp.int32))
+    text = compiled_text(call, *args)
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     lines = _hlo_lines(text, "mla_paged_attention")
     assert len(lines) == 1
     assert any(re.search(p, lines[0])
                for p in _metric_events("mla_decode_roofline.serve"))
+    eqn = _pallas_call(call, *args)
+    blk = MLA.pages_per_block(page, width)
+    assert blk * page == MLA.BLOCK_TOKENS == 4096
+    mapping = eqn.params["grid_mapping"]
+    assert mapping.grid == (slots, -(-width // blk)) == (64, 1)
+    scratch = [v.aval for v in
+               eqn.params["jaxpr"].invars[-mapping.num_scratch_operands:]]
+    vmem = sum(a.size * a.dtype.itemsize for a in scratch
+               if str(a.memory_space) == "vmem")
+    # two buffers of a block's rows, the accumulator, two lane-wide sides
+    assert vmem == (2 * blk * page * 640 * 2 + heads * 512 * 4
+                    + 2 * heads * 128 * 4)
+    asked = dict(eqn.params["compiler_params"]).get("mosaic_tpu")
+    limit = getattr(asked, "vmem_limit_bytes", None) or V5E_SCOPED_VMEM
+    # beside them the pipeline holds each query and output block twice
+    assert vmem + 2 * heads * (512 + 64 + 512) * 2 <= limit
 
 
 def test_cache_write_rewrites_pages_in_place(sds):

@@ -151,6 +151,34 @@ def test_ssm_update_phase_checks_every_parking(monkeypatch):
                            dtypes=("float32",), lane_blocks=(), reps=1)
 
 
+def test_mla_decode_phase_checks_every_context_and_block(monkeypatch):
+    """The latent decode kernel's chip check at a toy size, under the
+    Pallas interpreter: every context at every block size against the
+    dense gather, the rule put back, and a kernel that reads past a
+    slot's context refused."""
+    from paddle_tpu.ops.pallas import mla_paged_attention as M
+    monkeypatch.setattr(M, "_INTERPRET", True)
+    monkeypatch.setattr(M, "CHUNK_TOKENS", 8)
+    toy = dict(seed=C.SEED, slots=4, heads=4, rank=16, rope=8, page=4,
+               width=12, layers=2, contexts=(1, 9, 48), calls=2,
+               dtype="float32", tol=1e-5)
+    out = C.mla_decode_phase(blocks=(8, 16, 32), **toy)
+    keys = [f"block{b}.ctx{c}" for b in (8, 16, 32)
+            for c in ("1", "9", "48", "mixed")]
+    assert sorted(out["gap"]) == sorted(keys)
+    assert sorted(out["ms_a_call"]) == sorted(out["gb_a_s"]) == sorted(keys)
+    assert out["gap"]["block8.ctx1"] == 0.0     # one row: its own mean
+    assert M.BLOCK_TOKENS == 4096
+    json.dumps(out)
+    xla = M.mla_paged_attention_xla
+
+    def reads_a_row_too_many(ql, qr, pool, layer, table, lens, **kw):
+        return xla(ql, qr, pool, layer, table, lens + 1, **kw)
+    monkeypatch.setattr(M, "mla_paged_attention", reads_a_row_too_many)
+    with pytest.raises(RuntimeError, match="differs from its XLA form"):
+        C.mla_decode_phase(blocks=(16,), **toy)
+
+
 def test_interpret_switches_are_checked(monkeypatch):
     from paddle_tpu.ops.pallas import paged_attention
     C.interpret_is_off()

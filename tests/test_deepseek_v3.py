@@ -24,7 +24,6 @@ from paddle_tpu.models import deepseek_v3 as ds  # noqa: E402
 from paddle_tpu.models.generation import GenerationConfig  # noqa: E402
 from paddle_tpu.ops.pallas import grouped_ffn as G  # noqa: E402
 from paddle_tpu.ops.pallas import mla_paged_attention as M  # noqa: E402
-from paddle_tpu.ops.pallas import paged_attention as PA  # noqa: E402
 from paddle_tpu.serving.engine import Engine  # noqa: E402
 
 YARN = {"factor": 4.0, "original_max_position_embeddings": 64,
@@ -301,15 +300,16 @@ def interpret(monkeypatch):
     monkeypatch.setattr(G, "_INTERPRET", True)
 
 
-@pytest.mark.parametrize("block_tokens", [8, 16, 256])
-def test_mla_kernel_matches_the_dense_gather(interpret, monkeypatch,
-                                             block_tokens):
-    monkeypatch.setattr(PA, "BLOCK_TOKENS", block_tokens)
+def _kernel_equals_the_gather(lens, *, w, ps, rank, rope, nh, lanes=32,
+                              layers=2, atol=2e-6):
+    """``mla_paged_attention`` against the dense gather for slots that
+    see ``lens`` tokens: pages scattered over the pool, every table
+    entry past a context the dump page, every page no row names NaN."""
     rng = np.random.default_rng(0)
-    layers, pages, ps, rank, rope, nh, b, w = 2, 40, 4, 16, 8, 4, 3, 12
-    pool = rng.normal(size=(layers, pages + 1, ps, 32)).astype(np.float32)
+    lens = np.asarray(lens, np.int32)
+    b, pages = len(lens), len(lens) * w + 4
+    pool = rng.normal(size=(layers, pages + 1, ps, lanes)).astype(np.float32)
     table = np.full((b, w), pages, np.int32)
-    lens = np.array([5, 37, 1], np.int32)
     perm, at = rng.permutation(pages), 0
     for i in range(b):
         n = -(-lens[i] // ps)
@@ -327,7 +327,51 @@ def test_mla_kernel_matches_the_dense_gather(interpret, monkeypatch,
         got = M.mla_paged_attention(*args, sm_scale=0.3)
         want = M.mla_paged_attention_xla(*args, sm_scale=0.3)
         assert bool(jnp.all(jnp.isfinite(got)))
-        np.testing.assert_allclose(got, want, atol=2e-6)
+        np.testing.assert_allclose(got, want, atol=atol)
+        assert not np.asarray(got)[lens == 0].any()
+
+
+@pytest.mark.parametrize("block_tokens", [8, 16, 256])
+def test_mla_kernel_matches_the_dense_gather(interpret, monkeypatch,
+                                             block_tokens):
+    monkeypatch.setattr(M, "BLOCK_TOKENS", block_tokens)
+    _kernel_equals_the_gather([5, 37, 1], w=12, ps=4, rank=16, rope=8, nh=4)
+
+
+@pytest.mark.parametrize("context", [1, 255, 256, 257, 511, 512, 513, 767,
+                                     768, 769, 1023, 1024, 1025, 2048])
+def test_mla_kernel_at_its_block_and_chunk_edges(interpret, monkeypatch,
+                                                 context):
+    """A block of 1,024 tokens walked in whole chunks of 1,024 rows and
+    one masked round of 256, 512, 768 or 1,024: a context on either side
+    of each edge, up to the table's full width (two blocks), beside an
+    empty slot and a short one whose row is mostly the dump page."""
+    monkeypatch.setattr(M, "BLOCK_TOKENS", 1024)
+    assert M.tail_sizes(M.CHUNK_TOKENS) == [256, 512, 768, 1024]
+    assert M.pages_per_block(16, 128) == 64
+    _kernel_equals_the_gather([context, 0, 300], w=128, ps=16, rank=16,
+                              rope=8, nh=4)
+
+
+@pytest.mark.parametrize("chunk_tokens", [8, 24, 256])
+def test_mla_kernel_walks_a_block_in_chunks(interpret, monkeypatch,
+                                            chunk_tokens):
+    """Chunks that divide the block, that do not (40 rows in chunks of
+    24: the buffer's last rows are copied by nothing) and that exceed
+    it; contexts that end inside a page, leave every size of tail and
+    none, and live page counts with one bit set and with several."""
+    monkeypatch.setattr(M, "BLOCK_TOKENS", 40)
+    monkeypatch.setattr(M, "CHUNK_TOKENS", chunk_tokens)
+    _kernel_equals_the_gather([41, 0, 7, 96, 80, 33, 64, 22], w=12, ps=8,
+                              rank=16, rope=8, nh=4)
+
+
+def test_mla_kernel_at_the_cells_heads_and_rank(interpret):
+    """64 heads over a latent of 512 + 64 in rows declared 640 wide,
+    pages of 16: the cell's widths at a table of 80 pages."""
+    _kernel_equals_the_gather([1030, 0, 300], w=80, ps=16, rank=512,
+                              rope=64, nh=64, lanes=M.row_width(576),
+                              layers=1, atol=2e-5)
 
 
 def test_cache_write_kernel_replaces_one_row_a_slot(interpret):

@@ -598,6 +598,39 @@ def test_engine_paged_block_counters(tiny_model, monkeypatch):
     assert [sum(s.attributes[k] for s in spans) for k in keys] == [22, 36]
 
 
+@pytest.mark.parametrize("family", ["llama", "latent"])
+def test_paged_block_counters_follow_the_familys_kernel(tiny_model,
+                                                        monkeypatch, family):
+    """A latent engine counts grid steps by ``mla_paged_attention``'s
+    block of pages, a Llama engine by ``paged_attention``'s: the host's
+    arithmetic on its position mirror, nothing dispatched."""
+    from paddle_tpu.ops.pallas import mla_paged_attention as MLA
+    from paddle_tpu.ops.pallas import paged_attention as PA
+    from paddle_tpu.serving.engine import Engine
+    monkeypatch.setattr(PA, "BLOCK_TOKENS", 16)     # 2 pages, 8 blocks a row
+    monkeypatch.setattr(MLA, "BLOCK_TOKENS", 64)    # 8 pages, 2 blocks a row
+    kw = dict(max_slots=3, page_size=8, max_model_len=128)
+    if family == "latent":
+        from test_deepseek_v3 import toy_cfg, toy_state
+        cfg = toy_cfg()
+        eng, block = Engine(config=cfg, state=toy_state(cfg), **kw), 64
+    else:
+        eng, block = create_engine(tiny_model, **kw), 16
+    assert (eng._block_tokens, eng._blocks_per_row) == (block, 128 // block)
+    # the step about to run sees 1, 64 and 65 tokens
+    eng._pos[:] = [0, 63, 64]
+    assert eng._count_paged_blocks([0, 1, 2]) == {
+        16: (1 + 4 + 5, 3 * 8), 64: (1 + 1 + 2, 3 * 2)}[block]
+    # slots left out add nothing; past the table's row a context is
+    # counted as the kernel sees it, cut to the row
+    eng._pos[2] = 500
+    assert eng._count_paged_blocks([2]) == (128 // block, 128 // block)
+    st = eng.stats()
+    assert (st["paged_blocks_live"], st["paged_blocks_grid"]) == {
+        16: (10 + 8, 24 + 8), 64: (4 + 2, 6 + 2)}[block]
+    assert eng.decode_steps == 0
+
+
 def test_engine_prefix_cache_staggered_no_retrace(tiny_model):
     """Admissions/evictions with caching enabled (shared-prefix
     workload, staggered arrivals, deferred sync) never retrace the
