@@ -2,7 +2,8 @@
 
     python chip_smoke.py               # one TPU chip: a server, then a trainer
     python chip_smoke.py --four-chips  # four chips: the sharded paths only
-    python chip_smoke.py --ssm-update  # one chip: the state-update kernel
+    python chip_smoke.py --ssm-update [G]  # one chip: the state-update kernel
+    python chip_smoke.py --grouped-matmul 16,2688,1856   # the experts' kernel
     python chip_smoke.py --mla-decode  # one chip: the latent decode kernel
 
 One process, which touches JAX itself and starts no child.  Any phase that
@@ -29,13 +30,20 @@ Default run, on one chip:
          ``paddle.jit.train_step`` + AdamW + bf16 autocast, until the loss
          on one fixed batch falls below the first step's.
 
-``--ssm-update`` runs ``ops/pallas/ssm_update.py``'s kernel at the
-serving cell's shape (64 slots of 128 x 4096, bfloat16 and float32)
-against its XLA form with slots parked at the front, in the middle, at the
-end, all and none: the live slots' new states and outputs, every parked
-slot's and every other layer's state bit for bit; then times it with every
-slot live and with half of them parked (a parked slot moves no bytes: half
-the time), at two block widths.
+``--ssm-update [G]`` runs ``ops/pallas/ssm_update.py``'s kernel at the
+serving cells' shape (64 slots of 128 x 4096, bfloat16 and float32; B and
+C in ``G`` groups, 1 where none is given) against its XLA form with slots
+parked at the front, in the middle, at the end, all and none, at each block
+width swept (a block inside one group, and several groups sliced out of one
+block): the live slots' new states and outputs, every parked slot's and
+every other layer's state bit for bit; then times it with every slot live
+and with half of them parked (a parked slot moves no bytes: half the time).
+
+``--grouped-matmul E,K,N`` runs ``ops/pallas/grouped_ffn.py``'s
+``grouped_matmul`` over ``E`` experts' ``[K, N]`` matrices against its
+dense-gather XLA form at the decode step's and a prefill's row tiles, with
+an expert that no row chose and tiles past the live ones, and times the
+decode call against the bytes of the experts it reads.
 
 ``--mla-decode`` runs ``ops/pallas/mla_paged_attention.py``'s kernel at
 the GigaChat cell's shape (64 slots, 64 heads over latents of 512 + 64,
@@ -512,12 +520,14 @@ PARKED = {"none": lambda s: [], "first": lambda s: [0],
           "all": lambda s: list(range(s))}
 
 
-def ssm_update_phase(*, seed, slots=64, n=128, hp=4096, layers=3,
+def ssm_update_phase(*, seed, slots=64, n=128, hp=4096, layers=3, groups=1,
                      dtypes=("bfloat16", "float32"),
-                     lane_blocks=(2048, 4096), reps=20) -> dict:
-    """``ssm_state_update`` against ``ssm_state_update_xla`` on layer 1
-    of ``layers`` with slots parked as ``PARKED`` names them, then its
-    time a layer with every slot live and with the front half parked."""
+                     lane_blocks=(512, 2048, 4096), reps=20) -> dict:
+    """``ssm_state_update`` (B and C in ``groups`` groups) against
+    ``ssm_state_update_xla`` on layer 1 of ``layers`` with slots parked
+    as ``PARKED`` names them, at every block width of ``lane_blocks``,
+    then its time a layer with every slot live and with the front half
+    parked."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops.pallas import ssm_update as U
@@ -526,15 +536,27 @@ def ssm_update_phase(*, seed, slots=64, n=128, hp=4096, layers=3,
     rows = {k: jnp.asarray(v, jnp.float32) for k, v in (
         ("decay", rng.uniform(0.5, 1.0, (slots, hp))),
         ("dtx", rng.normal(size=(slots, hp))),
-        ("b", rng.normal(size=(slots, n))),
-        ("c", rng.normal(size=(slots, n))))}
+        ("b", rng.normal(size=(slots, groups, n))),
+        ("c", rng.normal(size=(slots, groups, n))))}
+    lane_blocks = [x for x in lane_blocks if hp % x == 0]
     out = {"phase": "ssm_update", "slots": slots, "state": [n, hp],
-           "checks": {}, "ms_a_layer": {}}
+           "groups": groups, "checks": {}, "ms_a_layer": {}}
 
     def active_of(parked):
         act = np.ones((slots,), np.int32)
         act[parked] = 0
         return jnp.asarray(act)
+
+    def kernel_at(lanes):
+        """The kernel traced at ``lanes`` to a block (a jit of its own:
+        the width is read when it is traced)."""
+        def at(pool, *args):
+            was, U.LANE_BLOCK = U.LANE_BLOCK, lanes
+            try:
+                return U.ssm_state_update(pool, 1, *args)
+            finally:
+                U.LANE_BLOCK = was
+        return jax.jit(at)
 
     for dtype in dtypes:
         pool = jnp.asarray(rng.normal(size=(layers, slots, n, hp)),
@@ -543,43 +565,43 @@ def ssm_update_phase(*, seed, slots=64, n=128, hp=4096, layers=3,
         # one rounding of the pool's dtype: the two forms may fuse the
         # multiply and the add differently
         ulp = 2.0 ** -7 if dtype == "bfloat16" else 2.0 ** -22
-        kernel = jax.jit(U.ssm_state_update, static_argnums=1)
         twin = jax.jit(U.ssm_state_update_xla, static_argnums=1)
+        kernels = {lanes: kernel_at(lanes) for lanes in lane_blocks}
         for name, pick in PARKED.items():
             act = active_of(pick(slots))
             live = np.asarray(act, bool)
             args = (rows["decay"], rows["dtx"], rows["b"], rows["c"], act)
-            got, got_y = kernel(pool, 1, *args)
             want, want_y = twin(pool, 1, *args)
-            got = np.asarray(got.astype(jnp.float32))
             want = np.asarray(want.astype(jnp.float32))
-            state_gap = float(np.max(
-                np.abs(got[1][live] - want[1][live])
-                / np.maximum(np.abs(want[1][live]), 1.0), initial=0.0))
-            y_gap = float(np.max(np.abs(np.asarray(got_y)
-                                        - np.asarray(want_y))
-                                 / (1.0 + np.abs(np.asarray(want_y)))))
-            found = {
-                "live": int(live.sum()), "state_gap": state_gap,
-                "y_gap": y_gap,
-                "parked_untouched": bool(
-                    np.array_equal(got[1][~live], was[1][~live])),
-                "other_layers_untouched": bool(
-                    np.array_equal(got[0], was[0])
-                    and np.array_equal(got[2:], was[2:])),
-                "parked_y_zero": not np.asarray(got_y)[~live].any()}
-            out["checks"][f"{dtype}.{name}"] = found
-            if not (state_gap <= ulp and y_gap <= 1e-4
-                    and found["parked_untouched"]
-                    and found["other_layers_untouched"]
-                    and found["parked_y_zero"]):
-                raise RuntimeError(
-                    f"ssm_state_update differs from its XLA form with "
-                    f"{name} parked ({dtype}): {found}")
+            for lanes, kernel in kernels.items():
+                got, got_y = kernel(pool, *args)
+                got = np.asarray(got.astype(jnp.float32))
+                state_gap = float(np.max(
+                    np.abs(got[1][live] - want[1][live])
+                    / np.maximum(np.abs(want[1][live]), 1.0), initial=0.0))
+                y_gap = float(np.max(np.abs(np.asarray(got_y)
+                                            - np.asarray(want_y))
+                                     / (1.0 + np.abs(np.asarray(want_y)))))
+                found = {
+                    "live": int(live.sum()), "state_gap": state_gap,
+                    "y_gap": y_gap,
+                    "parked_untouched": bool(
+                        np.array_equal(got[1][~live], was[1][~live])),
+                    "other_layers_untouched": bool(
+                        np.array_equal(got[0], was[0])
+                        and np.array_equal(got[2:], was[2:])),
+                    "parked_y_zero": not np.asarray(got_y)[~live].any()}
+                out["checks"][f"{dtype}.lanes{lanes}.{name}"] = found
+                if not (state_gap <= ulp and y_gap <= 1e-4
+                        and found["parked_untouched"]
+                        and found["other_layers_untouched"]
+                        and found["parked_y_zero"]):
+                    raise RuntimeError(
+                        f"ssm_state_update differs from its XLA form with "
+                        f"{name} parked ({dtype}, {groups} groups, {lanes} "
+                        f"lanes a block): {found}")
         del was
         for lanes in lane_blocks:
-            if hp % lanes:
-                continue
             for name in ("none", "front-half"):
                 act = active_of(PARKED[name](slots))
                 out["ms_a_layer"][f"{dtype}.lanes{lanes}.{name}"] = (
@@ -617,6 +639,64 @@ def _time_update(U, lanes, pool, rows, act, reps):
         return round((time.perf_counter() - t0) * 1e3 / (reps * layers), 4)
     finally:
         U.LANE_BLOCK = was
+
+
+# ------------------------------------------------- the experts' kernel
+def grouped_matmul_phase(*, seed, experts=16, k=2688, n=1856,
+                         tiles=((16, 384), (128, 1536)), reps=50,
+                         dtype="bfloat16") -> dict:
+    """``grouped_matmul`` over ``experts`` matrices ``[k, n]`` against
+    ``grouped_matmul_xla``: for each (row tile, rows of pairs) of
+    ``tiles`` a sorted buffer as the expert layer builds it (every
+    expert's rows padded to the tile, the last expert chosen by no row,
+    dead tiles behind the live ones), the widest gap over the live
+    rows; then the first tile's call timed, and the rate at which it
+    read the weights of the experts it touched."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import grouped_ffn as GF
+    t0 = time.perf_counter()
+    rng = np.random.default_rng(seed)
+    w = jnp.asarray(rng.normal(size=(experts, k, n)) / np.sqrt(k),
+                    jnp.float32).astype(dtype)
+    out = {"phase": "grouped_matmul", "shape": [experts, k, n],
+           "blocks": list(GF.expert_blocks(k, n, w.dtype.itemsize)),
+           "checks": {}}
+    for tile, pairs in tiles:
+        rows = -(-pairs // tile) * tile + experts * tile
+        # rows an expert: uneven, none for the last
+        share = rng.multinomial(pairs, [1.0 / (experts - 1)] * (experts - 1))
+        emap = np.concatenate([np.full(-(-c // tile), e) for e, c
+                               in enumerate(share)]).astype(np.int32)
+        live = len(emap)
+        emap = np.pad(emap, (0, rows // tile - live), constant_values=0)
+        x = jnp.asarray(rng.normal(size=(rows, k)), jnp.float32).astype(dtype)
+        args = (x, w, jnp.asarray(emap), jnp.int32(live))
+        kernel = jax.jit(lambda *a, t=tile: GF.grouped_matmul(*a, tile_m=t))
+        twin = jax.jit(lambda *a, t=tile: GF.grouped_matmul_xla(
+            *a, tile_m=t))
+        got = np.asarray(kernel(*args).astype(jnp.float32))[:live * tile]
+        want = np.asarray(twin(*args).astype(jnp.float32))[:live * tile]
+        gap = float(np.max(np.abs(got - want) / (1.0 + np.abs(want))))
+        found = {"rows": rows, "live_tiles": live,
+                 "experts_touched": int((share > 0).sum()), "gap": gap}
+        # both accumulate in float32 and round once to the served dtype
+        if not gap <= 2.0 ** -7:
+            raise RuntimeError(f"grouped_matmul differs from its XLA form "
+                               f"at tile {tile}: {found}")
+        if "ms_a_call" not in out:
+            jax.block_until_ready(kernel(*args))
+            t1 = time.perf_counter()
+            for _ in range(reps):
+                y = kernel(*args)
+            jax.block_until_ready(y)
+            ms = (time.perf_counter() - t1) * 1e3 / reps
+            touched = found["experts_touched"] * k * n * w.dtype.itemsize
+            out["ms_a_call"] = round(ms, 4)
+            out["weights_gb_per_s"] = round(touched / ms / 1e6, 1)
+        out["checks"][f"tile{tile}"] = found
+    out["seconds"] = round(time.perf_counter() - t0, 2)
+    return out
 
 
 # ------------------------------------------------- the latent decode kernel
@@ -726,8 +806,13 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--four-chips", action="store_true",
                     help="run only the paths that span four chips")
-    ap.add_argument("--ssm-update", action="store_true",
-                    help="run only the state-update kernel's check")
+    ap.add_argument("--ssm-update", nargs="?", type=int, const=1,
+                    metavar="GROUPS",
+                    help="run only the state-update kernel's check, B and "
+                         "C in GROUPS groups (1 if none is given)")
+    ap.add_argument("--grouped-matmul", metavar="E,K,N",
+                    help="run only the experts' grouped matmul's check, "
+                         "over E matrices [K, N]")
     ap.add_argument("--mla-decode", action="store_true",
                     help="run only the latent decode kernel's check")
     args = ap.parse_args(argv)
@@ -741,7 +826,11 @@ def main(argv=None) -> int:
         devices=[str(d) for d in devices])
 
     if args.ssm_update:
-        say(**ssm_update_phase(seed=SEED))
+        say(**ssm_update_phase(seed=SEED, groups=args.ssm_update))
+        return _ok(devices)
+    if args.grouped_matmul:
+        e, k, n = (int(x) for x in args.grouped_matmul.split(","))
+        say(**grouped_matmul_phase(seed=SEED, experts=e, k=k, n=n))
         return _ok(devices)
     if args.mla_decode:
         say(**mla_decode_phase(seed=SEED))

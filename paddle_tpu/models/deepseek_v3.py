@@ -42,14 +42,26 @@ from .llama import _rotate_half
 from .llama_hybrid import _rms
 
 __all__ = ["DeepseekV3Config", "weight_shapes", "rope_tables",
-           "softmax_scale", "route", "decode_layer", "prefill_layer",
-           "layer_weights"]
+           "softmax_scale", "route", "routed_experts", "expert_ffn",
+           "decode_layer", "prefill_layer", "layer_weights"]
 
 HI = jax.lax.Precision.HIGHEST
 # rows of one tile of the sorted expert buffer: an expert sees a few
 # rows a decode step and some tens of a prefill
 DECODE_TILE, PREFILL_TILE = 16, 128
 MOE_COUNTERS = ("moe_routed_pairs", "moe_local_pairs", "moe_experts_live")
+
+
+def held_range(local_experts, n_routed: int) -> tuple:
+    """``local_experts`` as (first, count) of the ``n_routed`` routed
+    experts; None = all of them."""
+    first, count = (0, n_routed) if local_experts is None else (
+        int(x) for x in local_experts)
+    if not (0 <= first and count >= 1 and first + count <= n_routed):
+        raise ValueError(
+            f"local_experts={(first, count)} is no range of the "
+            f"{n_routed} routed experts")
+    return first, count
 
 
 @dataclass
@@ -85,15 +97,8 @@ class DeepseekV3Config:
     family: str = field(default="deepseek_v3", init=False)
 
     def __post_init__(self):
-        if self.local_experts is None:
-            self.local_experts = (0, self.n_routed_experts)
-        first, count = (int(x) for x in self.local_experts)
-        self.local_experts = (first, count)
-        if not (0 <= first and count >= 1
-                and first + count <= self.n_routed_experts):
-            raise ValueError(
-                f"local_experts={self.local_experts} is no range of the "
-                f"{self.n_routed_experts} routed experts")
+        self.local_experts = held_range(self.local_experts,
+                                        self.n_routed_experts)
         if self.n_routed_experts % self.n_group:
             raise ValueError("n_group must divide n_routed_experts")
 
@@ -309,12 +314,29 @@ def _sorted_rows(cfg: DeepseekV3Config, idx, valid, tile: int):
     return row_pair, pair_row, emap, tiles_end[-1].astype(jnp.int32), sizes
 
 
-def routed_experts(cfg: DeepseekV3Config, w: dict, x, valid, tile: int):
-    """Σ over the chosen experts this chip holds of
-    ``w_e . down_e(silu(gate_e x) . up_e x)`` for x [T, H]; tokens
-    where ``valid`` is false choose nothing.  Dropless: every pair of a
-    held expert is computed.  Returns (y [T, H] float32, counts [3]:
-    ``MOE_COUNTERS``)."""
+def _gated(cfg) -> bool:
+    """Whether the description's experts are the gated three-matrix
+    ``down(silu(gate x) . up x)`` (``mlp_hidden_act`` "silu", and where
+    the description does not say) or the two-matrix ``down(relu(up
+    x)^2)`` ("relu2")."""
+    act = getattr(cfg, "mlp_hidden_act", "silu")
+    if act not in ("silu", "relu2"):
+        raise ValueError(f"mlp_hidden_act={act!r} is not implemented "
+                         "(only 'silu' and 'relu2')")
+    return act == "silu"
+
+
+def _relu2(u):
+    return jnp.square(jax.nn.relu(u))
+
+
+def routed_experts(cfg, w: dict, x, valid, tile: int):
+    """Σ over the chosen experts this chip holds of ``w_e . expert_e(x)``
+    for x [T, H], an expert being ``down_e(silu(gate_e x) . up_e x)`` or
+    ``down_e(relu(up_e x)^2)`` as the description says (``_gated``);
+    tokens where ``valid`` is false choose nothing.  Dropless: every
+    pair of a held expert is computed.  Returns (y [T, H] float32,
+    counts [3]: ``MOE_COUNTERS``)."""
     from ..ops.pallas.grouped_ffn import select_grouped_matmul
     t = x.shape[0]
     k = cfg.num_experts_per_tok
@@ -326,10 +348,14 @@ def routed_experts(cfg: DeepseekV3Config, w: dict, x, valid, tile: int):
                          fill_value=0)
     with jax.named_scope("moe.experts"):
         gmm = select_grouped_matmul()
-        g = gmm(x_buf, w["e_gate"], emap, n_live, tile_m=tile)
-        u = gmm(x_buf, w["e_up"], emap, n_live, tile_m=tile)
-        act = (jax.nn.silu(g.astype(jnp.float32))
-               * u.astype(jnp.float32)).astype(x.dtype)
+        if _gated(cfg):
+            g = gmm(x_buf, w["e_gate"], emap, n_live, tile_m=tile)
+            u = gmm(x_buf, w["e_up"], emap, n_live, tile_m=tile)
+            act = (jax.nn.silu(g.astype(jnp.float32))
+                   * u.astype(jnp.float32)).astype(x.dtype)
+        else:
+            u = gmm(x_buf, w["e_up"], emap, n_live, tile_m=tile)
+            act = _relu2(u.astype(jnp.float32)).astype(x.dtype)
         y_buf = gmm(act, w["e_down"], emap, n_live, tile_m=tile)
         # rows of tiles that hold no pair were never written: a pair that
         # is not computed here points past the buffer and reads as zero
@@ -346,15 +372,25 @@ def _swiglu(x, gate, up, down):
     return (jax.nn.silu(x @ gate) * (x @ up)) @ down
 
 
+def expert_ffn(cfg, w: dict, x, valid, tile: int):
+    """The expert layer's feed-forward part on x [T, H] (normed): the
+    held routed experts' terms and the shared expert, whole.  Returns
+    (y [T, H], MoE counts [3])."""
+    routed, counts = routed_experts(cfg, w, x, valid, tile)
+    with jax.named_scope("moe.shared"):
+        if _gated(cfg):
+            shared = _swiglu(x, w["gate"], w["up"], w["down"])
+        else:
+            shared = _relu2(x @ w["up"]) @ w["down"]
+        return (shared.astype(jnp.float32) + routed).astype(x.dtype), counts
+
+
 def ffn(cfg: DeepseekV3Config, w: dict, li: int, x, valid, tile: int):
     """x [T, H] (normed) -> (ffn(x) [T, H], MoE counts [3] or None)."""
     if not cfg.is_expert_layer(li):
         with jax.named_scope("mlp"):
             return _swiglu(x, w["gate"], w["up"], w["down"]), None
-    routed, counts = routed_experts(cfg, w, x, valid, tile)
-    with jax.named_scope("moe.shared"):
-        shared = _swiglu(x, w["gate"], w["up"], w["down"])
-        return (shared.astype(jnp.float32) + routed).astype(x.dtype), counts
+    return expert_ffn(cfg, w, x, valid, tile)
 
 
 # ---------------------------------------------------------------- attention

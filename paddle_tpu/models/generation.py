@@ -233,13 +233,16 @@ def _position_and_scale(q, k, cos, sin, cfg, width):
     encoding unless ``position_embedding_type`` is "nope", and, where it
     names an ``attention_multiplier``, that softmax scale in place of
     the kernels' own ``1 / sqrt(width)`` (``width`` the head dim the
-    kernel will see), folded into q."""
+    kernel will see: several heads' where a pool row packs them, and
+    then ``1 / sqrt(head dim)`` is restored), folded into q."""
     if getattr(cfg, "position_embedding_type", "rope") != "nope":
         q = q * cos + _rotate_half(q) * sin
         k = k * cos + _rotate_half(k) * sin
     mult = getattr(cfg, "attention_multiplier", None)
     if mult is not None:
         q = q * jnp.asarray(mult * np.sqrt(width), q.dtype)
+    elif width != q.shape[-1]:      # packed rows: the head's own width
+        q = q * jnp.asarray(np.sqrt(width / q.shape[-1]), q.dtype)
     return q, k
 
 
@@ -294,9 +297,11 @@ def _attend_packed(cache, q, group: int, pack: int, li, table, lens, axis):
 # arrays the ``@`` they always did.  The scopes (``attn.qkv``,
 # ``kv.write``, ``attn.decode`` / ``attn.prefill``, ``attn.out``,
 # ``mlp``) are what the benchmark's breakdown names device time by.
-def prefill_layer(w, x, cos, sin, mask, cfg: LlamaConfig, *, li,
-                  axis=None, lora=(), aidx=None, prefix=None):
-    """x: [B, S, H]; cos/sin [S, D] at the rows' positions.  Returns
+def prefill_attention(w, x, cos, sin, mask, cfg: LlamaConfig, *, li,
+                      axis=None, lora=(), aidx=None, prefix=None):
+    """A layer's first half over a prompt: ``x + Attention(RMSNorm(x))``
+    (a family whose block is the attention alone ends here).  x: [B, S,
+    H]; cos/sin [S, D] at the rows' positions.  Returns
     (out, k, v [B, S, kvH, D]) — the caller owns the cache and writes
     k/v (keys already rotary-encoded, as every reader expects them).
 
@@ -331,12 +336,21 @@ def prefill_layer(w, x, cos, sin, mask, cfg: LlamaConfig, *, li,
         attn = attn.reshape(b, s, qp.shape[-1])
     with jax.named_scope("attn.out"):
         x = residual_add(x, _out_proj(w, attn, axis, lora, aidx, li), cfg)
+    return x, k, v
+
+
+def prefill_layer(w, x, cos, sin, mask, cfg: LlamaConfig, *, li,
+                  axis=None, lora=(), aidx=None, prefix=None):
+    """:func:`prefill_attention`, then the MLP half."""
+    x, k, v = prefill_attention(w, x, cos, sin, mask, cfg, li=li, axis=axis,
+                                lora=lora, aidx=aidx, prefix=prefix)
     return mlp_block(w, x, cfg, li=li, axis=axis, lora=lora, aidx=aidx), k, v
 
 
-def decode_layer(w, x, cache, table, cos1, sin1, pos, cfg: LlamaConfig, *,
-                 li, axis=None, lora=(), aidx=None):
-    """Paged-cache decode layer ``li``: x [B, H] one token a row;
+def decode_attention(w, x, cache, table, cos1, sin1, pos, cfg: LlamaConfig,
+                     *, li, axis=None, lora=(), aidx=None):
+    """A layer's first half against the paged cache, ``x +
+    Attention(RMSNorm(x))``, layer ``li``: x [B, H] one token a row;
     ``cache`` a :class:`~paddle_tpu.ops.pallas.paged_attention.PagedKV`,
     every layer's pools, passed whole and returned whole — the row
     write and the attention both take the layer as an index, so donated
@@ -377,6 +391,14 @@ def decode_layer(w, x, cache, table, cos1, sin1, pos, cfg: LlamaConfig, *,
         attn = attn.reshape(b, qp.shape[-1])
     with jax.named_scope("attn.out"):
         x = residual_add(x, _out_proj(w, attn, axis, lora, aidx, li), cfg)
+    return x, cache
+
+
+def decode_layer(w, x, cache, table, cos1, sin1, pos, cfg: LlamaConfig, *,
+                 li, axis=None, lora=(), aidx=None):
+    """:func:`decode_attention`, then the MLP half."""
+    x, cache = decode_attention(w, x, cache, table, cos1, sin1, pos, cfg,
+                                li=li, axis=axis, lora=lora, aidx=aidx)
     return (mlp_block(w, x, cfg, li=li, axis=axis, lora=lora, aidx=aidx),
             cache)
 

@@ -15,14 +15,17 @@ layer, with ``rm = residual_multiplier``::
 What the serving runner needs of a family is here: the description
 (:class:`GraniteHybridConfig`), the names and shapes of the weights
 (``weight_shapes``, the published names, ``[in, out]``), and the Mamba
-layer's two bodies: ``mamba_prefill`` (a whole prompt by chunks: inside
+mixer's two bodies: ``mamba_prefill`` (a whole prompt by chunks: inside
 a chunk the recurrence in its matmul form, the state carried from chunk
 to chunk; returns the final state and the convolution's tail) and
-``mamba_decode`` (one token a slot against the per-slot state pools).
-The attention layers and every layer's MLP half are
-``models/generation.py``'s (``decode_layer``, ``prefill_layer``,
-``mlp_block``), which read this description's ``position_embedding_type``,
-``attention_multiplier`` and ``residual_multiplier``.
+``mamba_decode`` (one token a slot against the per-slot state pools),
+each wrapped with its norm and residual add as a block part
+(``mamba_prefill_block``, ``mamba_decode_block``).  The attention part
+and the MLP part are ``models/generation.py``'s (``decode_attention``,
+``prefill_attention``, ``mlp_block``), which read this description's
+``position_embedding_type``, ``attention_multiplier`` and
+``residual_multiplier``.  ``serving/parallel/recurrent.py`` walks
+``blocks``.
 
 The recurrent state ``S`` of a head is ``[P, N]`` (head dim by state
 size).  It is held in the dtype the model is served in, as the
@@ -31,8 +34,18 @@ published cache allocates it (``HybridMambaAttentionDynamicCache``:
 float32: one pool ``[mamba layers, slots, N, H * P]`` for all layers
 and slots (``ops/pallas/ssm_update.py`` says why
 that layout), with the convolution's last ``d_conv - 1`` inputs in a
-second pool ``[mamba layers, slots, (d_conv - 1) * conv_dim]``.  One
-group of ``B``/``C`` (``mamba_n_groups == 1``) is what is implemented.
+second pool ``[mamba layers, slots, (d_conv - 1) * conv_dim]``.  ``B``
+and ``C`` come in ``mamba_n_groups`` groups, each shared by
+``mamba_n_heads / mamba_n_groups`` consecutive heads, whose channels
+the gated norm normalises apart (Granite has one group; the
+``nemotron_h`` family, ``models/nemotron_h.py``, whose Mamba blocks run
+through these same bodies, has eight).  ``d_inner`` is heads times head
+dim, whatever ``mamba_expand`` says of the hidden size.
+
+A description says what its blocks are made of (``blocks``: for each
+block the parts it runs in order, each with its own norm and residual
+add).  Here every block is a mixer and then the shared MLP:
+``("mamba", "mlp")`` or ``("attention", "mlp")``.
 """
 from __future__ import annotations
 
@@ -42,20 +55,49 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.pallas.ssm_update import select_ssm_state_update
-from .generation import _mm, mlp_block, residual_add
+from .generation import _mm, residual_add
 from .llama_hybrid import _rms
 
 __all__ = ["GraniteHybridConfig", "weight_shapes", "layer_weights",
-           "mamba_prefill", "mamba_decode", "mamba_prefill_layer",
-           "mamba_decode_layer", "state_shapes", "COUNTERS"]
+           "mamba_prefill", "mamba_decode", "mamba_prefill_block",
+           "mamba_decode_block", "state_shapes", "COUNTERS"]
 
 HI = jax.lax.Precision.HIGHEST
 EMBED = "model.embed_tokens.weight"
 COUNTERS = ("ssm_rows_live",)
 
 
+class RecurrentDescription:
+    """What a description of this family's kind derives from its
+    ``layer_types`` (a kind a block) and its Mamba sizes."""
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    def layers_of(self, kind: str) -> tuple:
+        return tuple(i for i, t in enumerate(self.layer_types) if t == kind)
+
+    @property
+    def mamba_layers(self) -> tuple:
+        return self.layers_of("mamba")
+
+    @property
+    def attention_layers(self) -> tuple:
+        return self.layers_of("attention")
+
+    def ordinal(self, i: int) -> int:
+        """Block ``i``'s place among the blocks of its own kind: its
+        row in that kind's pools."""
+        return self.layer_types[:i].count(self.layer_types[i])
+
+
 @dataclass
-class GraniteHybridConfig:
+class GraniteHybridConfig(RecurrentDescription):
     vocab_size: int = 100352
     hidden_size: int = 2048
     intermediate_size: int = 8192       # shared_intermediate_size
@@ -95,46 +137,25 @@ class GraniteHybridConfig:
             raise ValueError(
                 f"layer_types must name {self.num_hidden_layers} layers, "
                 f"each 'mamba' or 'attention': {self.layer_types}")
-        for name, want in (("mamba_n_groups", 1),
-                           ("mamba_proj_bias", False),
+        for name, want in (("mamba_proj_bias", False),
                            ("tie_word_embeddings", True),
                            ("position_embedding_type", "nope")):
             if getattr(self, name) != want:
                 raise ValueError(
                     f"{name}={getattr(self, name)!r} is not implemented "
                     f"for the granitemoehybrid family (only {want!r})")
-        if self.mamba_n_heads * self.mamba_d_head != (
-                self.mamba_expand * self.hidden_size):
-            raise ValueError(
-                "mamba_n_heads * mamba_d_head must be mamba_expand * "
-                "hidden_size")
+        if self.mamba_n_heads % self.mamba_n_groups:
+            raise ValueError("mamba_n_groups must divide mamba_n_heads")
 
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_attention_heads
 
     @property
-    def d_inner(self) -> int:
-        return self.mamba_n_heads * self.mamba_d_head
+    def blocks(self) -> tuple:
+        """The parts each block runs, in order: a mixer, then the MLP."""
+        return tuple((kind, "mlp") for kind in self.layer_types)
 
-    @property
-    def conv_dim(self) -> int:
-        return self.d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
-
-    @property
-    def mamba_layers(self) -> tuple:
-        return tuple(i for i, t in enumerate(self.layer_types)
-                     if t == "mamba")
-
-    @property
-    def attention_layers(self) -> tuple:
-        return tuple(i for i, t in enumerate(self.layer_types)
-                     if t == "attention")
-
-    def ordinal(self, i: int) -> int:
-        """Layer ``i``'s place among the layers of its own kind: its
-        row in that kind's pools."""
-        return self.layer_types[:i].count(self.layer_types[i])
 
 
 def kv_pack(cfg) -> int:
@@ -182,8 +203,7 @@ def weight_shapes(cfg: GraniteHybridConfig) -> dict:
         m = p + "mamba."
         out.update({
             m + "in_proj.weight": (
-                h, 2 * cfg.d_inner + 2 * cfg.mamba_d_state
-                + cfg.mamba_n_heads),
+                h, cfg.d_inner + cfg.conv_dim + cfg.mamba_n_heads),
             m + "conv1d.weight": (cfg.conv_dim, cfg.mamba_d_conv),
             m + "dt_bias": (cfg.mamba_n_heads,),
             m + "A_log": (cfg.mamba_n_heads,),
@@ -224,6 +244,15 @@ def _split(cfg, zxbcdt):
     return zxbcdt[..., :d], zxbcdt[..., d:d + c], zxbcdt[..., d + c:]
 
 
+def _x_b_c(cfg, act):
+    """The convolution's output, last axis [x | B | C], as (x [.., H *
+    P], B [.., G, N], C [.., G, N])."""
+    d, gn = cfg.d_inner, cfg.mamba_n_groups * cfg.mamba_d_state
+    by_group = act.shape[:-1] + (cfg.mamba_n_groups, cfg.mamba_d_state)
+    return (act[..., :d], act[..., d:d + gn].reshape(by_group),
+            act[..., d + gn:].reshape(by_group))
+
+
 def _conv_taps(w):
     """The depthwise convolution's (taps [conv_dim, d_conv], bias),
     float32."""
@@ -240,12 +269,14 @@ def _dt_and_a(w, dt):
 
 
 def _gate_and_out(cfg, w, y, z, dtype):
-    """``out_proj(RMSNorm(y * silu(z)))``: the norm over all of
-    ``d_inner`` (one group), computed in float32."""
+    """``out_proj(RMSNorm(y * silu(z)))``: the norm over each group's
+    ``d_inner / G`` channels apart, computed in float32."""
     with jax.named_scope("ssm.gate"):
         g = y * jax.nn.silu(z.astype(jnp.float32))
-        var = jnp.mean(jnp.square(g), axis=-1, keepdims=True)
-        g = (g * jax.lax.rsqrt(var + cfg.rms_norm_eps)).astype(dtype)
+        by_group = g.reshape(g.shape[:-1] + (cfg.mamba_n_groups, -1))
+        var = jnp.mean(jnp.square(by_group), axis=-1, keepdims=True)
+        g = (by_group * jax.lax.rsqrt(var + cfg.rms_norm_eps)).reshape(
+            g.shape).astype(dtype)
         g = g * w["norm"]
     with jax.named_scope("ssm.out"):
         return _mm(g, w["out"])
@@ -262,40 +293,45 @@ def _dot(a, b, dims, dtype):
 
 def _chunk_scan(xdt, da, b, c, q: int, dtype):
     """The recurrence over ``S`` tokens by chunks of ``q`` (SSD, section
-    6 of arXiv:2405.21060, one group).  xdt [S, H, P] = dt * x; da
-    [S, H] = dt * A (0 on padding: the state passes it unchanged); b, c
-    [S, N].  Returns (y [S, H, P], final state [N, H * P]), float32."""
+    6 of arXiv:2405.21060).  xdt [S, H, P] = dt * x; da [S, H] = dt * A
+    (0 on padding: the state passes it unchanged); b, c [S, G, N], group
+    ``g`` shared by heads ``g * H / G`` onwards.  Returns (y [S, H, P],
+    final state [N, H * P]), float32."""
     s, h, p = xdt.shape
-    n = b.shape[-1]
+    g, n = b.shape[1:]
+    hg = h // g                                 # heads a group
     nc = s // q
     f32 = jnp.float32
     causal = jnp.tril(jnp.ones((q, q), bool))
 
-    def chunk(state, part):
+    def chunk(state, part):                     # state [G, N, hg * P]
         xdt_c, da_c, b_c, c_c = part
         cs = jnp.cumsum(da_c, axis=0)                       # [q, H]
         # within the chunk: y[t] = sum_{u<=t} (c_t.b_u) e^{cs_t-cs_u} xdt_u
-        g = _dot(c_c, b_c, (((1,), (1,)), ((), ())), dtype)     # [t, u]
+        cb = _dot(c_c, b_c, (((2,), (2,)), ((1,), (1,))), dtype)  # [G,t,u]
         diff = cs[:, None, :] - cs[None, :, :]              # [t, u, H]
         decay = jnp.exp(jnp.where(causal[:, :, None], diff, -jnp.inf))
-        m = (g[:, :, None] * decay).transpose(2, 0, 1)      # [H, t, u]
+        m = (cb[:, None] * decay.transpose(2, 0, 1).reshape(g, hg, q, q)
+             ).reshape(h, q, q)                             # [H, t, u]
         y = _dot(m, xdt_c.transpose(1, 0, 2),
                  (((2,), (1,)), ((0,), (0,))), dtype)       # [H, t, P]
         y = y.transpose(1, 0, 2)
         # what the carried state adds: (c_t . S) e^{cs_t}
-        y = y + (_dot(c_c, state, (((1,), (0,)), ((), ())), dtype)
-                 .reshape(q, h, p) * jnp.exp(cs)[:, :, None])
+        carried = _dot(c_c, state, (((2,), (1,)), ((1,), (0,))), dtype)
+        y = y + (carried.transpose(1, 0, 2).reshape(q, h, p)
+                 * jnp.exp(cs)[:, :, None])
         # the state after the chunk
         to_end = jnp.exp(cs[-1][None, :] - cs)              # [q, H]
-        add = _dot(b_c, (xdt_c * to_end[:, :, None]).reshape(q, h * p),
-                   (((0,), (0,)), ((), ())), dtype)         # [N, H*P]
-        state = state * jnp.repeat(jnp.exp(cs[-1]), p)[None, :] + add
+        add = _dot(b_c, (xdt_c * to_end[:, :, None]).reshape(q, g, hg * p),
+                   (((0,), (0,)), ((1,), (1,))), dtype)     # [G, N, hg*P]
+        state = (state * jnp.repeat(jnp.exp(cs[-1]), p).reshape(
+            g, 1, hg * p) + add)
         return state, y
 
     parts = (xdt.reshape(nc, q, h, p), da.reshape(nc, q, h),
-             b.reshape(nc, q, n), c.reshape(nc, q, n))
-    state, y = jax.lax.scan(chunk, jnp.zeros((n, h * p), f32), parts)
-    return y.reshape(s, h, p), state
+             b.reshape(nc, q, g, n), c.reshape(nc, q, g, n))
+    state, y = jax.lax.scan(chunk, jnp.zeros((g, n, hg * p), f32), parts)
+    return y.reshape(s, h, p), state.transpose(1, 0, 2).reshape(n, h * p)
 
 
 def mamba_prefill(cfg: GraniteHybridConfig, w: dict, h, length):
@@ -319,9 +355,8 @@ def mamba_prefill(cfg: GraniteHybridConfig, w: dict, h, length):
             padded, (length.astype(jnp.int32), jnp.int32(0)),
             (k - 1, cfg.conv_dim)).reshape(-1)
     with jax.named_scope("ssm.scan"):
-        x = act[:, :cfg.d_inner].reshape(s, nh, p)
-        b = act[:, cfg.d_inner:cfg.d_inner + cfg.mamba_d_state]
-        c = act[:, cfg.d_inner + cfg.mamba_d_state:]
+        x, b, c = _x_b_c(cfg, act)
+        x = x.reshape(s, nh, p)
         dtv, a = _dt_and_a(w, dt)
         dtv = jnp.where((jnp.arange(s) < length)[:, None], dtv, 0.0)
         q = min(cfg.mamba_chunk_size, s)
@@ -356,9 +391,7 @@ def mamba_decode(cfg: GraniteHybridConfig, w: dict, h, ssm, conv, lm,
             window[j].astype(f32) * taps[:, j] for j in range(k)))
         conv = conv.at[lm].set(jnp.concatenate(window[1:], axis=-1))
     with jax.named_scope("ssm.update"):
-        x = act[:, :cfg.d_inner]
-        b = act[:, cfg.d_inner:cfg.d_inner + cfg.mamba_d_state]
-        c = act[:, cfg.d_inner + cfg.mamba_d_state:]
+        x, b, c = _x_b_c(cfg, act)
         dtv, a = _dt_and_a(w, dt)
         ssm, y = select_ssm_state_update()(
             ssm, lm, jnp.repeat(jnp.exp(dtv * a), p, axis=1),
@@ -367,21 +400,19 @@ def mamba_decode(cfg: GraniteHybridConfig, w: dict, h, ssm, conv, lm,
     return _gate_and_out(cfg, w, y, z, h.dtype), ssm, conv
 
 
-# ----------------------------------------------------------- layer bodies
-def mamba_prefill_layer(cfg, w, li, x, length):
-    """x [1, S, hidden] -> (x, state, tail): the Mamba layer over a
-    prompt, MLP half included."""
+# ----------------------------------------------------------- block parts
+def mamba_prefill_block(cfg, w, x, length):
+    """x [1, S, hidden] -> (x, state, tail): the Mamba mixer over a
+    prompt, with its norm and its residual add."""
     h = _rms(x, w["ln1"], cfg.rms_norm_eps)[0]
     out, state, tail = mamba_prefill(cfg, w, h, length)
-    x = residual_add(x, out[None], cfg)
-    return mlp_block(w, x, cfg, li=li), state, tail
+    return residual_add(x, out[None], cfg), state, tail
 
 
-def mamba_decode_layer(cfg, w, li, x, ssm, conv, active):
-    """x [slots, hidden] -> (x, ssm, conv): the Mamba layer for one
-    token a slot, MLP half included."""
+def mamba_decode_block(cfg, w, lm, x, ssm, conv, active):
+    """x [slots, hidden] -> (x, ssm, conv): the Mamba mixer for one
+    token a slot, with its norm and its residual add; ``lm`` its row in
+    the pools."""
     h = _rms(x[:, None], w["ln1"], cfg.rms_norm_eps)[:, 0]
-    out, ssm, conv = mamba_decode(cfg, w, h, ssm, conv, cfg.ordinal(li),
-                                  active)
-    x = residual_add(x, out, cfg)
-    return mlp_block(w, x, cfg, li=li), ssm, conv
+    out, ssm, conv = mamba_decode(cfg, w, h, ssm, conv, lm, active)
+    return residual_add(x, out, cfg), ssm, conv

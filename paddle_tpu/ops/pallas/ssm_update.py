@@ -13,9 +13,10 @@ lane axis.  The arithmetic is float32 whatever the pool holds: a state
 is widened as it is read and rounded once as it is written.  In that
 layout the whole update is elementwise, broadcasts along one axis only —
 the decay ``exp(dt A)`` and the input ``dt x`` are row vectors over the
-``H * P`` lanes, ``B`` and ``C`` (one group: shared by the heads) are
-column vectors over ``N`` — and the read-out is a sum over sublanes, so
-nothing goes through the lane-reduction unit or the MXU.  One slot of
+``H * P`` lanes, ``B`` and ``C`` are column vectors over ``N``, one pair
+for each of the ``G`` groups of heads, whose ``H * P / G`` lanes lie
+side by side — and the read-out is a sum over sublanes, so nothing goes
+through the lane-reduction unit or the MXU.  One slot of
 one layer is ``N * H * P`` values (1 MiB in bfloat16 at 64 heads of 64
 with state 128), read once and written once a step: the kernel is a
 stream over HBM, and at the serving cell's shape it is the largest
@@ -33,6 +34,8 @@ path.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -42,7 +45,8 @@ __all__ = ["ssm_state_update", "ssm_state_update_xla",
 _INTERPRET = False
 # lanes of one grid step's block: [N, LANE_BLOCK] in and out, each
 # double-buffered, beside the float32 values the body forms of them
-# (1 MiB apiece at N = 128)
+# (1 MiB apiece at N = 128).  A block wider than a group's lanes holds
+# several groups, each sliced out by the body at a fixed place.
 LANE_BLOCK = 2048
 
 
@@ -60,11 +64,16 @@ def ssm_state_update_xla(pool, layer, decay, dtx, b, c, active):
     f32 = jnp.float32
     live = active.astype(bool)
     s = pool[layer]
-    new = (s.astype(f32) * decay.astype(f32)[:, None, :]
-           + b.astype(f32)[:, :, None] * dtx.astype(f32)[:, None, :])
+    slots, g, n = b.shape
+    by_group = (slots, 1, g, s.shape[-1] // g)
+    new = (s.astype(f32).reshape(slots, n, *by_group[2:])
+           * decay.astype(f32).reshape(by_group)
+           + b.astype(f32).transpose(0, 2, 1)[..., None]
+           * dtx.astype(f32).reshape(by_group))
     y = jnp.where(live[:, None], jnp.einsum(
-        "snl,sn->sl", new, c.astype(f32),
-        precision=jax.lax.Precision.HIGHEST), 0.0)
+        "sngl,sgn->sgl", new, c.astype(f32),
+        precision=jax.lax.Precision.HIGHEST).reshape(slots, -1), 0.0)
+    new = new.reshape(s.shape)
     new = jnp.where(live[:, None, None], new.astype(pool.dtype), s)
     return pool.at[layer].set(new), y
 
@@ -84,7 +93,7 @@ def _sticky_blocks(active, blocks: int):
 
 
 def _update_kernel(act_ref, src_ref, blk_ref, layer_ref, s_ref, decay_ref,
-                   dtx_ref, b_ref, c_ref, o_ref, y_ref):
+                   dtx_ref, b_ref, c_ref, o_ref, y_ref, *, group_lanes):
     from jax.experimental import pallas as pl
 
     slot = pl.program_id(0)
@@ -92,10 +101,15 @@ def _update_kernel(act_ref, src_ref, blk_ref, layer_ref, s_ref, decay_ref,
 
     @pl.when(live)
     def _():
-        new = (s_ref[...].astype(jnp.float32) * decay_ref[...]
-               + b_ref[...] * dtx_ref[...])
-        o_ref[...] = new.astype(o_ref.dtype)
-        y_ref[...] = jnp.sum(new * c_ref[...], axis=0, keepdims=True)
+        # the groups this block holds, each at its own lanes (one group,
+        # or a part of one: the whole block)
+        for g in range(b_ref.shape[0]):
+            at = (slice(None) if b_ref.shape[0] == 1 else
+                  slice(g * group_lanes, (g + 1) * group_lanes))
+            new = (s_ref[:, at].astype(jnp.float32) * decay_ref[:, at]
+                   + b_ref[g] * dtx_ref[:, at])
+            o_ref[:, at] = new.astype(o_ref.dtype)
+            y_ref[:, at] = jnp.sum(new * c_ref[g], axis=0, keepdims=True)
 
     @pl.when(jnp.logical_not(live))
     def _():
@@ -115,7 +129,8 @@ def ssm_state_update(pool, layer, decay, dtx, b, c, active):
     (donate it: it is aliased to the first output; the arithmetic is
     float32 either way); ``layer`` a Python int or a traced
     scalar; decay, dtx [slots, HP] (``exp(dt A)`` and ``dt x`` spread
-    over each head's channels); b, c [slots, N]; active [slots].
+    over each head's channels); b, c [slots, G, N], one column for each
+    group of ``HP / G`` lanes; active [slots].
     Returns (pool, y [slots, HP] float32) with, for every live slot,
     ``pool[layer, s] = decay * S + b (x) dtx`` and ``y = c . S_new``
     (of the new state before it is rounded to the pool's dtype); a
@@ -124,11 +139,16 @@ def ssm_state_update(pool, layer, decay, dtx, b, c, active):
     from jax.experimental.pallas import tpu as pltpu
 
     _, slots, n, hp = pool.shape
+    groups = b.shape[1]
     lanes = min(LANE_BLOCK, hp)
-    if hp % lanes:
-        raise ValueError(f"the state's {hp} lanes do not divide into "
-                         f"blocks of {lanes}")
+    group_lanes = hp // groups
+    if hp % lanes or hp % groups or (
+            lanes % group_lanes and group_lanes % lanes):
+        raise ValueError(
+            f"the state's {hp} lanes in {groups} groups do not divide "
+            f"into blocks of {lanes}")
     blocks = hp // lanes
+    held = max(1, lanes // group_lanes)     # groups a block holds
     act = active.astype(jnp.int32)
     src, blk = _sticky_blocks(act, blocks)
     f32 = jnp.float32
@@ -142,17 +162,17 @@ def ssm_state_update(pool, layer, decay, dtx, b, c, active):
         return s, 0, j
 
     def col_map(s, j, act, src, blk, ly):
-        return s, 0, 0
+        return s, j * lanes // (group_lanes * held), 0, 0
 
     state = pl.BlockSpec((None, None, n, lanes), state_map)
     row = pl.BlockSpec((None, 1, lanes), row_map)
-    col = pl.BlockSpec((None, n, 1), col_map)
+    col = pl.BlockSpec((None, held, n, 1), col_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4, grid=(slots, blocks),
         in_specs=[state, row, row, col, col], out_specs=[state, row])
     with jax.enable_x64(False):
         pool, y = pl.pallas_call(
-            _update_kernel,
+            functools.partial(_update_kernel, group_lanes=group_lanes),
             grid_spec=grid_spec,
             out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype),
                        jax.ShapeDtypeStruct((slots, 1, hp), f32)],
@@ -161,5 +181,5 @@ def ssm_state_update(pool, layer, decay, dtx, b, c, active):
             name="ssm_state_update",
         )(act, src, blk, jnp.asarray(layer, jnp.int32).reshape(1), pool,
           decay.astype(f32)[:, None, :], dtx.astype(f32)[:, None, :],
-          b.astype(f32)[:, :, None], c.astype(f32)[:, :, None])
+          b.astype(f32)[..., None], c.astype(f32)[..., None])
     return pool, y[:, 0]

@@ -199,6 +199,8 @@ class Engine:
         if self.latent:     # the runner refuses tp, kv_quant and spec_k
             _latent.check_options(quant=bool(quant), lora=lora is not None)
         self.recurrent = _recurrent.is_recurrent(config)
+        if self.recurrent:  # before anything is quantized; the rest below
+            _recurrent.check_options(config, quant=bool(quant))
         self.quant = quant
         self.kv_quant = bool(kv_quant)
         if self.quant:
@@ -271,8 +273,8 @@ class Engine:
         if self.recurrent:
             # what a per-slot recurrent state cannot do yet, by name
             _recurrent.check_options(
-                mesh=self.tp > 1, kv_quant=self.kv_quant,
-                quant=bool(quant), lora=lora is not None,
+                config, mesh=self.tp > 1, kv_quant=self.kv_quant,
+                lora=lora is not None,
                 spec_k=self.spec_k > 0,
                 enable_prefix_cache=self.enable_prefix_cache,
                 preempt=self.preempt,
@@ -344,7 +346,7 @@ class Engine:
                 num_layers=L, dtype_itemsize=self._embed_itemsize,
                 latent_width=_latent.pool_shape(config, 0, 1)[-1])
         elif self.recurrent:
-            dtype = state[_recurrent.EMBED].dtype
+            dtype = state[_recurrent.embed_name(config)].dtype
             self._embed_itemsize = int(np.dtype(dtype).itemsize)
             # pages for the attention layers alone; the other layers'
             # state is a fixed number of bytes a slot, which the runner

@@ -174,7 +174,7 @@ class ModelRunner:
                                  lora_slots=self.lora_slots > 0,
                                  spec_k=self.spec_k > 0)
         elif self.recurrent:
-            recurrent.check_options(mesh=self.tp > 1,
+            recurrent.check_options(config, mesh=self.tp > 1,
                                     kv_quant=self.kv_quant,
                                     lora=self.lora_slots > 0,
                                     spec_k=self.spec_k > 0)
@@ -193,7 +193,7 @@ class ModelRunner:
             scale_shape = ()
             cos, sin = rope_tables(config, self._rope_len)
         elif self.recurrent:            # no position encoding at all
-            dtype = state[recurrent.EMBED].dtype
+            dtype = state[recurrent.embed_name(config)].dtype
             pool_shape = recurrent.kv_pool_shape(config, self.num_pages,
                                                  self.page_size)
             scale_shape = ()
@@ -250,7 +250,7 @@ class ModelRunner:
             # decode step (no leaves where the family has no experts)
             self._counters_dev = (
                 latent.counters0() if self.latent else
-                recurrent.counters0() if self.recurrent else ())
+                recurrent.counters0(config) if self.recurrent else ())
             # the per-slot recurrent state (ssm, conv); no leaves where
             # the family has none
             self._rstate = (recurrent.state_pools(config, self.max_slots)
@@ -835,7 +835,7 @@ class ModelRunner:
         if fn is not None:
             return fn
         if self.recurrent:
-            recurrent.check_options(enable_prefix_cache=True)
+            recurrent.check_options(self.config, enable_prefix_cache=True)
         if self.latent:
             fn = jax.jit(latent.build_prefill_cached(
                 self, bucket,
@@ -1134,8 +1134,10 @@ class ModelRunner:
         A device fetch: on demand, never inside a step."""
         if not (self.latent or self.recurrent):
             return {}
-        return (latent if self.latent else recurrent).counters_by_name(
-            np.asarray(self._counters_dev))
+        counters = np.asarray(self._counters_dev)
+        if self.latent:
+            return latent.counters_by_name(counters)
+        return recurrent.counters_by_name(self.config, counters)
 
     def hold_ring(self):
         """Keep the ring as the last step left it, for the next

@@ -519,6 +519,124 @@ def test_hybrid_cell_programs_keep_their_kernels_and_scope_order(
     assert mem.alias_size_in_bytes > 2.41e9 + 2.1e9
 
 
+# The Granite cell's programs as PR 34 lowers them (same blanking as
+# ``PARENT_HLO``).  They are not PR 33's (269a7fb9bb44d958,
+# 29af4c4d42b1eda0): B and C ride into ``ssm_state_update`` as
+# ``[slots, 1, N]``, the chunked scan carries its state as ``[1, N,
+# H * P]`` and batches its products over the one group, the gated norm
+# reshapes to one group: reshapes and unit batch dimensions around the
+# parent's arithmetic (PERF.md, PR 34, has the cell's numbers beside the
+# parent's).  An edit that reaches the Granite program moves these.
+GRANITE_HLO = {"decode_step": "25da47839de8b841",
+               "prefill": "2bac911596edc873"}
+
+
+@pytest.mark.parametrize("program", sorted(GRANITE_HLO))
+def test_granite_cells_programs_are_this_prs_hlo(sds, monkeypatch, program):
+    import hashlib
+    run, shapes = _hybrid_cell_runner(monkeypatch)
+    lowered = _lower_hybrid_program(sds, run, shapes, program)
+    text = re.sub(r'backend_config = "[^"]*"', 'backend_config = ""',
+                  lowered.as_text())
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == GRANITE_HLO[
+        program]
+
+
+def _hybrid_moe_cell_runner(monkeypatch):
+    """The Nemotron cell's runner: the configuration as the benchmark's
+    driver reads it, all 52 blocks, shapes only."""
+    import json
+    import sys
+    from paddle_tpu.models.nemotron_h import NemotronHConfig
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.drivers.engine_closed_loop_hybrid_moe import routed_model
+    from benchmarks.lib import hybrid_moe_state
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "nemotron-3-nano-30b-a3b-ep8.json")) as f:
+        conf = json.load(f)
+    m = routed_model(conf)
+    run = _bare_runner(monkeypatch, NemotronHConfig.from_published(
+        {k: v for k, v in m.items() if k != "local_experts"},
+        local_experts=tuple(m["local_experts"]),
+        dtype=conf["assumed"]["torch_dtype"]), conf["engine"],
+        recurrent=True)
+    return run, {k: shape for k, (shape, _)
+                 in hybrid_moe_state.shapes(conf).items()}
+
+
+MOE_PART = ["moe.route", "moe.experts", "moe.shared"]
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill"])
+def test_hybrid_moe_cell_programs_keep_their_kernels_and_scope_order(
+        sds, monkeypatch, record_property, program):
+    """The Nemotron cell's programs at the published widths, all 52
+    blocks, each ONE part: one ``ssm_state_update`` call a Mamba block
+    (8 groups of B and C), two ``grouped_matmul`` calls an expert block
+    (up and down, the held experts stored 1,920 wide), one
+    ``paged_attention`` call an attention block at 2 KV heads of 128
+    (decode), the flash kernel (prefill), the scopes in block order and
+    no ``mlp`` scope.  The compiled ``decode_step`` updates both kinds
+    of state where they lie and fits the chip."""
+    run, shapes = _hybrid_moe_cell_runner(monkeypatch)
+    cfg = run.config
+    lowered = _lower_hybrid_program(sds, run, shapes, program)
+    parts = {"mamba": (MAMBA_DECODE if program == "decode_step"
+                       else MAMBA_PREFILL),
+             "attention": (ATTN_DECODE if program == "decode_step"
+                           else ATTN_PREFILL), "moe": MOE_PART + ["mlp"]}
+    want = ["embed"]
+    for kind in cfg.layer_types:
+        want += [s for s in parts[kind] if s != "mlp"]
+    assert _scope_order(lowered, set(want) | {"head", "mlp"}) == (
+        want + ["head"])
+    text = lowered.as_text()
+    calls = text.count("@tpu_custom_call")
+    n_m, n_a, n_e = (len(cfg.layers_of(k))
+                     for k in ("mamba", "attention", "moe"))
+    assert (n_m, n_a, n_e) == (23, 6, 23)
+    if program == "prefill":
+        assert calls == n_a + 2 * n_e       # flash; up and down products
+        return
+    assert calls == n_m + n_a + 2 * n_e
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    by_stem = {stem: _hlo_lines(hlo, stem) for stem in (
+        "ssm_state_update", "grouped_matmul", "paged_attention")}
+    assert [len(by_stem[k]) for k in (
+        "ssm_state_update", "grouped_matmul", "paged_attention")] == [
+        n_m, 2 * n_e, n_a]
+    # each roofline's pattern finds its own kernel's events and no other
+    # (the experts' by the decode step's 640 sorted rows)
+    every = sum(by_stem.values(), [])
+    for stem, metric in (
+            ("ssm_state_update", "ssm_update_roofline.serve.hybrid_moe"),
+            ("grouped_matmul", "moe_experts_roofline.serve.hybrid_moe"),
+            ("paged_attention",
+             "paged_attention_roofline.serve.hybrid_moe")):
+        names = [n for n in event_names(compiled)
+                 if n.startswith(tuple("%" + s for s in by_stem))]
+        found = [n for n in names if any(
+            re.search(p, n) for p in _metric_events(metric))]
+        assert len(found) == len(by_stem[stem]), metric
+        assert all(n.startswith("%" + stem) for n in found), metric
+    assert len(every) == calls
+    mem = compiled.memory_analysis()
+    record_property("temp_size_in_bytes", mem.temp_size_in_bytes)
+    print(f"nemotron decode_step: temp {mem.temp_size_in_bytes}, "
+          f"arguments {mem.argument_size_in_bytes}, "
+          f"aliased {mem.alias_size_in_bytes}")
+    assert mem.temp_size_in_bytes < 400e6
+    # weights 10.52 + the held experts' lane padding 0.25 + ssm 1.54
+    # (bfloat16) + conv 0.05 + K/V 1.61 GB
+    assert 13.9e9 < mem.argument_size_in_bytes < 14.1e9
+    # the state pools and the K/V pools are all updated in place
+    assert mem.alias_size_in_bytes > 1.54e9 + 1.6e9
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) < 0.9 * 2**34
+
+
 def _donated(lowered) -> list[bool]:
     """Whether each argument of the lowered program's main function is
     donated (``jax.buffer_donor`` or an aliased output), in order."""

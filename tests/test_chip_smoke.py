@@ -127,18 +127,23 @@ def test_train_phase_loss_falls():
     json.dumps(out)                         # every line it prints is JSON
 
 
-def test_ssm_update_phase_checks_every_parking(monkeypatch):
+@pytest.mark.parametrize("groups", [1, 4])
+def test_ssm_update_phase_checks_every_parking(monkeypatch, groups):
     """The state-update kernel's chip check at a toy size, under the
-    Pallas interpreter: every way of parking in both dtypes, and a
-    kernel that touches a parked slot is refused."""
+    Pallas interpreter: every way of parking in both dtypes at both
+    block rules (with 4 groups of 64 lanes: a block that is one group,
+    and one that holds two), and a kernel that touches a parked slot is
+    refused."""
     from paddle_tpu.ops.pallas import ssm_update as U
     monkeypatch.setattr(U, "_INTERPRET", True)
     out = C.ssm_update_phase(seed=C.SEED, slots=6, n=16, hp=256,
-                             lane_blocks=(128, 256), reps=1)
-    assert len(out["checks"]) == 2 * len(C.PARKED)
+                             groups=groups, lane_blocks=(64, 128, 96),
+                             reps=1)
+    assert out["groups"] == groups
+    assert len(out["checks"]) == 2 * 2 * len(C.PARKED)  # 96 divides nothing
     assert all(c["parked_untouched"] and c["other_layers_untouched"]
                for c in out["checks"].values())
-    assert out["checks"]["bfloat16.all"]["live"] == 0
+    assert out["checks"]["bfloat16.lanes128.all"]["live"] == 0
     assert len(out["ms_a_layer"]) == 8 and U.LANE_BLOCK == 2048
     json.dumps(out)
     xla = U.ssm_state_update_xla
@@ -148,7 +153,65 @@ def test_ssm_update_phase_checks_every_parking(monkeypatch):
     monkeypatch.setattr(U, "ssm_state_update", touches_the_parked)
     with pytest.raises(RuntimeError, match="with first parked"):
         C.ssm_update_phase(seed=C.SEED, slots=6, n=16, hp=256,
-                           dtypes=("float32",), lane_blocks=(), reps=1)
+                           groups=groups, dtypes=("float32",),
+                           lane_blocks=(128,), reps=1)
+
+
+def test_a_kernel_that_reads_the_wrong_groups_column_is_refused(monkeypatch):
+    """What the grouped check is for: a kernel that gives every lane
+    group 0's B and C passes with one group and is refused with four."""
+    from paddle_tpu.ops.pallas import ssm_update as U
+    xla = U.ssm_state_update_xla
+
+    def one_group(pool, layer, decay, dtx, b, c, active):
+        return xla(pool, layer, decay, dtx, b[:, :1], c[:, :1], active)
+    monkeypatch.setattr(U, "ssm_state_update", one_group)
+    kw = dict(seed=C.SEED, slots=4, n=8, hp=128, dtypes=("float32",),
+              lane_blocks=(128,), reps=1)
+    C.ssm_update_phase(groups=1, **kw)
+    with pytest.raises(RuntimeError, match="4 groups, 128 lanes a block"):
+        C.ssm_update_phase(groups=4, **kw)
+
+
+def test_grouped_matmul_phase_checks_both_tiles(monkeypatch):
+    """The experts' kernel's chip check at a toy size, under the Pallas
+    interpreter, at a width that is no whole lane tile: the decode and
+    the prefill row tiles, an expert no row chose, dead tiles; a kernel
+    that reads the wrong expert is refused."""
+    from paddle_tpu.ops.pallas import grouped_ffn as GF
+    monkeypatch.setattr(GF, "_INTERPRET", True)
+    out = C.grouped_matmul_phase(seed=C.SEED, experts=4, k=32, n=24,
+                                 tiles=((16, 40), (128, 200)), reps=1,
+                                 dtype="float32")
+    assert out["shape"] == [4, 32, 24] and out["blocks"] == [32, 24]
+    assert set(out["checks"]) == {"tile16", "tile128"}
+    assert all(c["gap"] < 1e-5 and c["experts_touched"] <= 3
+               for c in out["checks"].values())
+    assert out["ms_a_call"] > 0 and out["weights_gb_per_s"] >= 0
+    json.dumps(out)
+    real = GF.grouped_matmul
+
+    def wrong_expert(x, w, emap, live, *, tile_m):
+        return real(x, w, (emap + 1) % 4, live, tile_m=tile_m)
+    monkeypatch.setattr(GF, "grouped_matmul", wrong_expert)
+    with pytest.raises(RuntimeError, match="differs from its XLA form"):
+        C.grouped_matmul_phase(seed=C.SEED, experts=4, k=32, n=24,
+                               tiles=((16, 40),), reps=1, dtype="float32")
+
+
+@pytest.mark.parametrize("argv,phase,kw", [
+    (["--ssm-update"], "ssm_update_phase", {"groups": 1}),
+    (["--ssm-update", "8"], "ssm_update_phase", {"groups": 8}),
+    (["--grouped-matmul", "16,2688,1856"], "grouped_matmul_phase",
+     {"experts": 16, "k": 2688, "n": 1856})])
+def test_main_hands_the_phase_its_shape(monkeypatch, argv, phase, kw):
+    seen = {}
+    monkeypatch.setattr(C, phase, lambda **k: seen.update(k) or {"phase": 0})
+    monkeypatch.setattr(C, "require_tpu", lambda n: jax.devices()[:1])
+    monkeypatch.setattr(C, "interpret_is_off", lambda: None)
+    monkeypatch.setattr(C, "_ok", lambda devices: 0)
+    assert C.main(argv) == 0
+    assert seen == dict(kw, seed=C.SEED)
 
 
 def test_mla_decode_phase_checks_every_context_and_block(monkeypatch):

@@ -349,11 +349,6 @@ def test_what_the_family_lacks_is_refused_by_name(toy, option, kw):
         engine(cfg, state, **kw)
 
 
-def test_more_than_one_group_is_refused_by_name():
-    with pytest.raises(ValueError, match="mamba_n_groups=2"):
-        toy_cfg(mamba_n_groups=2)
-
-
 # ------------------------------------------------------------- the census
 def test_the_census_counts_pages_of_attention_layers_and_state_by_slot(toy):
     cfg, state = toy
@@ -426,7 +421,7 @@ def test_state_update_kernel_is_its_xla_twin(monkeypatch, active, dtype):
                        jnp.float32).astype(dtype)
     decay = jnp.asarray(rng.uniform(0.5, 1.0, (slots, hp)), jnp.float32)
     dtx, b, c = (jnp.asarray(rng.normal(size=s), jnp.float32)
-                 for s in ((slots, hp), (slots, n), (slots, n)))
+                 for s in ((slots, hp), (slots, 1, n), (slots, 1, n)))
     act = jnp.asarray(active, jnp.int32)
     assert U.select_ssm_state_update() is U.ssm_state_update
     got_pool, got_y = jax.jit(U.ssm_state_update, static_argnums=1)(

@@ -113,15 +113,38 @@ def test_state_update_chooser_takes_the_kernel_on_tpu(fake_tpu, monkeypatch):
     assert SU.select_ssm_state_update() is SU.ssm_state_update
     monkeypatch.setattr(SU, "ssm_state_update", _boom)
     pool = jnp.zeros((1, 2, 8, 128), jnp.float32)
-    row, col = jnp.zeros((2, 128)), jnp.zeros((2, 8))
+    row = jnp.zeros((2, 128))
+    for groups in (1, 4):           # Granite's call and a grouped one
+        col = jnp.zeros((2, groups, 8))
+        with pytest.raises(RuntimeError, match="scoped vmem exceeded"):
+            SU.select_ssm_state_update()(pool, 0, row, row, col, col,
+                                         jnp.ones((2,), jnp.int32))
+
+
+def test_grouped_matmul_chooser_takes_the_kernel_on_tpu(fake_tpu,
+                                                        monkeypatch):
+    """The expert layers' products, whatever the description's
+    activation: on a TPU the Pallas kernel and nothing else; a failure
+    inside it reaches ``routed_experts``' caller."""
+    from paddle_tpu.models import deepseek_v3 as ds
+    from paddle_tpu.models.nemotron_h import NemotronHConfig
+    from paddle_tpu.ops.pallas import grouped_ffn as GF
+    assert GF.select_grouped_matmul() is GF.grouped_matmul
+    monkeypatch.setattr(GF, "grouped_matmul", _boom)
+    cfg = NemotronHConfig(hidden_size=16, n_routed_experts=4,
+                          num_experts_per_tok=2, moe_intermediate_size=24,
+                          hybrid_override_pattern="E")
+    w = {"router": jnp.zeros((16, 4)), "router_bias": jnp.zeros((4,)),
+         "e_up": jnp.zeros((4, 16, 24)), "e_down": jnp.zeros((4, 24, 16))}
     with pytest.raises(RuntimeError, match="scoped vmem exceeded"):
-        SU.select_ssm_state_update()(pool, 0, row, row, col, col,
-                                     jnp.ones((2,), jnp.int32))
+        ds.routed_experts(cfg, w, jnp.zeros((3, 16)), jnp.ones((3,), bool),
+                          ds.DECODE_TILE)
 
 
 @pytest.mark.parametrize("name", [
     "flash_attention.py", "decode_attention.py", "paged_attention.py",
-    "quant_matmul.py", "lora_matmul.py", "ssm_update.py"])
+    "quant_matmul.py", "lora_matmul.py", "ssm_update.py",
+    "grouped_ffn.py"])
 def test_no_handler_between_a_kernel_and_its_caller(name):
     """No ``try`` at all in the kernel files: nothing there opens a
     resource, so a handler could only be hiding a kernel failure."""

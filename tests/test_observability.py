@@ -223,6 +223,58 @@ def test_new_flags_defined():
 
 
 # ------------------------------------------------------------- watchdog
+class TestRecurrentFamilyTelemetry:
+    """What a family with a recurrent state and held experts adds to the
+    engine's numbers: device counters by name, state bytes by slot, and
+    no retrace for either."""
+
+    @staticmethod
+    def _engine(**kw):
+        import jax.numpy as jnp
+        from paddle_tpu.models import nemotron_h as nh
+        from paddle_tpu.serving.engine import Engine
+        cfg = nh.NemotronHConfig(
+            vocab_size=64, hidden_size=32, hybrid_override_pattern="ME*",
+            num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+            mamba_n_heads=4, mamba_d_head=16, mamba_d_state=8,
+            mamba_n_groups=2, mamba_chunk_size=8, moe_intermediate_size=24,
+            moe_shared_expert_intermediate_size=40, n_routed_experts=8,
+            num_experts_per_tok=2, local_experts=(4, 4),
+            max_position_embeddings=128, dtype="float32")
+        rng = np.random.default_rng(0)
+        state = {k: jnp.asarray(
+            np.ones(s) if k.endswith(("norm.weight", "norm_f.weight", ".D",
+                                      "A_log"))
+            else 0.1 * rng.normal(size=s), jnp.float32)
+            for k, s in nh.weight_shapes(cfg).items()}
+        return Engine(config=cfg, state=state, max_slots=2, page_size=8,
+                      max_model_len=64, **kw)
+
+    def test_stats_carry_the_four_device_counters_and_the_state_bytes(self):
+        from paddle_tpu.models.generation import GenerationConfig
+        eng = self._engine()
+        s0 = eng.stats()
+        assert [s0[k] for k in ("ssm_rows_live", "moe_routed_pairs",
+                                "moe_local_pairs", "moe_experts_live")] == [
+            0, 0, 0, 0]
+        # one Mamba block: 2 slots x (state 8 x 64 + conv tail 3 x 96) x 4 B
+        assert s0["recurrent_state_bytes"] == 2 * (8 * 64 + 3 * 96) * 4
+        reqs = [eng.submit(np.arange(3 + n, dtype=np.int32),
+                           GenerationConfig(max_new_tokens=5))
+                for n in range(3)]          # the third waits for a slot
+        eng.run_until_complete(max_steps=100)
+        assert all(r.is_finished() for r in reqs)
+        s1 = eng.stats()
+        # every decode row of a live slot: one Mamba block, top-2 routing
+        assert s1["ssm_rows_live"] > 0
+        assert s1["moe_routed_pairs"] == 2 * s1["ssm_rows_live"]
+        assert s1["moe_local_pairs"] <= s1["moe_routed_pairs"]
+        assert s1["moe_experts_live"] <= s1["decode_steps"] * 4
+        # three admissions, one compiled step and one slot patch
+        assert eng.decode_traces == 1 and eng.runner.push_traces == 1
+        json.dumps({k: s1[k] for k in s0 if k.startswith(("moe_", "ssm_"))})
+
+
 class TestWatchdogTelemetry:
     def test_flag_driven_timeout_and_hang_gauges(self):
         from paddle_tpu.distributed.watchdog import CommTaskManager

@@ -563,6 +563,80 @@ class TestDeviceNames:
         assert spans[-1].attributes["ssm_rows_live"] == \
             seen["ssm_rows_live"]
 
+    @staticmethod
+    def _hybrid_moe_engine():
+        import jax.numpy as jnp
+        from paddle_tpu.models import nemotron_h as nh
+        from paddle_tpu.serving.engine import Engine
+        cfg = nh.NemotronHConfig(
+            vocab_size=64, hidden_size=32, hybrid_override_pattern="ME*M",
+            num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+            mamba_n_heads=4, mamba_d_head=16, mamba_d_state=8,
+            mamba_n_groups=2, mamba_chunk_size=8, moe_intermediate_size=24,
+            moe_shared_expert_intermediate_size=40, n_routed_experts=8,
+            num_experts_per_tok=2, local_experts=(0, 4),
+            max_position_embeddings=128, dtype="float32")
+        rng = np.random.default_rng(0)
+        state = {k: jnp.asarray(
+            np.ones(s) if k.endswith(("norm.weight", "norm_f.weight", ".D",
+                                      "A_log"))
+            else 0.1 * rng.normal(size=s), jnp.float32)
+            for k, s in nh.weight_shapes(cfg).items()}
+        return Engine(config=cfg, state=state, max_slots=2, page_size=PAGE,
+                      max_model_len=64)
+
+    def test_one_part_blocks_keep_the_scopes_and_all_four_counters(self):
+        """The nemotron_h family through the recurrent family's programs:
+        the same program names; each block's one part under the scopes
+        the other families' parts have (``ssm.*``, ``attn.*``,
+        ``moe.route`` / ``moe.experts`` / ``moe.shared``) and no ``mlp``
+        scope, which no block of it has; the decode span carrying the
+        Mamba blocks' and the expert blocks' counters as ``stats()`` last
+        read them."""
+        import jax.numpy as jnp
+        obs.tracer().reset()
+        engine = self._hybrid_moe_engine()
+        r = engine.runner
+        assert r._step_fn.__name__ == "decode_step"
+        assert r._prefill_fn(PAGE).__name__ == "prefill"
+        text = r._step_fn.lower(
+            r.state, r.kpool, r.vpool, r.kscale, r.vscale, r._table_dev,
+            r._pos_dev, r._tok_dev, r._active_dev, r._ring_dev,
+            r._ridx_dev, r._cos, r._sin, r.lora, r._aidx_dev,
+            r._counters_dev, r._rstate).as_text(debug_info=True)
+        for scope in ("embed", "ssm.in_proj", "ssm.conv", "ssm.update",
+                      "ssm.gate", "ssm.out", "moe.route", "moe.experts",
+                      "moe.shared", "attn.qkv", "kv.write", "attn.decode",
+                      "attn.out", "head"):
+            assert f"jit(decode_step)/{scope}/" in text, scope
+        assert "jit(decode_step)/mlp/" not in text
+        text = r._prefill_fn(PAGE).lower(
+            r.state, jnp.zeros((1, PAGE), jnp.int32),
+            jnp.ones((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
+            r.kpool, r.vpool, r.kscale, r.vscale, r._cos, r._sin, (), (),
+            r._rstate, jnp.zeros((), jnp.int32)).as_text(debug_info=True)
+        for scope in ("ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate",
+                      "ssm.out", "ssm.write", "moe.route", "moe.experts",
+                      "moe.shared", "attn.prefill", "kv.write", "head"):
+            assert f"jit(prefill)/{scope}/" in text, scope
+        assert "jit(prefill)/mlp/" not in text
+        engine.submit(np.array(PROMPT, np.int32),
+                      GenerationConfig(max_new_tokens=4))
+        engine.step()
+        engine.step()
+        seen = engine.stats()
+        assert seen["ssm_rows_live"] >= 2       # two Mamba blocks a step
+        assert seen["moe_routed_pairs"] >= 2    # one expert block, top-2
+        assert 0 <= seen["moe_local_pairs"] <= seen["moe_routed_pairs"]
+        assert "moe_experts_live" in seen
+        engine.run_until_complete(max_steps=50)
+        spans = [s for s in obs.tracer().spans()
+                 if s.name == "engine.decode.dispatch"]
+        assert "ssm_rows_live" not in spans[0].attributes
+        for name in ("ssm_rows_live", "moe_routed_pairs", "moe_local_pairs",
+                     "moe_experts_live"):
+            assert spans[-1].attributes[name] == seen[name], name
+
     def test_decode_span_carries_the_expert_counters(self):
         """``engine.decode.dispatch`` shows the device's expert counters
         as ``stats()`` last read them: a step never fetches them."""
