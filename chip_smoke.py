@@ -5,6 +5,7 @@
     python chip_smoke.py --ssm-update [G]  # one chip: the state-update kernel
     python chip_smoke.py --grouped-matmul 16,2688,1856   # the experts' kernel
     python chip_smoke.py --mla-decode  # one chip: the latent decode kernel
+    python chip_smoke.py --paged-decode [CELL]  # the K/V paged decode kernel
 
 One process, which touches JAX itself and starts no child.  Any phase that
 raises, any device that is not a TPU, any check that fails ends the run
@@ -51,6 +52,13 @@ pages of 16 rows, 256 table columns, 5 layers in one pool) against its
 dense-gather XLA form at contexts of 1, 628, 1,170 and 4,032 tokens and
 at a mix with an empty slot, the largest gap printed; then times it, call
 after call inside one program, at each block size swept.
+
+``--paged-decode [CELL]`` runs ``ops/pallas/paged_attention.py``'s kernel
+at the three paged cells' shapes (``PAGED_CELLS``; one of them where it is
+named) against ``paged_attention_xla`` at each context and at a mix with
+an empty slot, at each block (tokens a grid step) and round (rows a round
+of the softmax) swept, the largest gap printed; then times it, call after
+call inside one program.
 
 ``--four-chips`` runs only what exists across chips, each beside what it is
 compared with: the same server at ``mesh="tp=4"`` and at ``mesh=None``, and
@@ -142,7 +150,9 @@ def shard_bytes(tree, devices) -> list:
 
 def kernels_in(jitted, args) -> dict:
     """Pallas kernels in the program ``jitted`` traces for ``args``, by
-    name and count, read from the lowered module: no compile, no run."""
+    name and count, read from the lowered module: no compile, no run.
+    (Bodies, not calls: the paged decode kernel, whose wrapper is one
+    ``jit`` with the layer as an operand, counts once a program.)"""
     import jax
 
     def aval(a):
@@ -699,6 +709,31 @@ def grouped_matmul_phase(*, seed, experts=16, k=2688, n=1856,
     return out
 
 
+# ------------------------------------------------- the decode kernels' cases
+def decode_cases(rng, *, slots, width, page, contexts) -> tuple:
+    """What both paged decode kernels' checks run over: every slot at
+    each of ``contexts`` (cut to the table) and at a mix (one slot
+    empty, the rest anywhere up to the table's width), pages scattered
+    over a pool of ``slots * width`` and the table padded by the dump
+    page after them.  ({name: (lens, table, lens on the device)}, the
+    table's tokens.)"""
+    import jax.numpy as jnp
+    dump = slots * width
+    owned = rng.permutation(dump).reshape(slots, width).astype(np.int32)
+    most = width * page
+    mixed = rng.integers(1, most + 1, slots)
+    mixed[slots // 2] = 0
+    lens_of = {str(c): np.full((slots,), min(c, most)) for c in contexts}
+    lens_of["mixed"] = mixed
+    cases = {}
+    for name, lens in lens_of.items():
+        used = np.arange(width)[None, :] < -(-lens[:, None] // page)
+        cases[name] = (lens, jnp.asarray(np.where(used, owned, dump),
+                                         jnp.int32),
+                       jnp.asarray(lens, jnp.int32))
+    return cases, most
+
+
 # ------------------------------------------------- the latent decode kernel
 # Both forms round the softmax's weights to bfloat16 before p.c, the
 # kernel against a running maximum and a chunk at a time, so they differ
@@ -732,28 +767,18 @@ def mla_decode_phase(*, seed, slots=64, heads=64, rank=512, rope=64,
     qr = jax.random.normal(kr, (slots, heads, rope), jnp.float32
                            ).astype(dtype)
     scale = (rank + rope) ** -0.5
-    owned = rng.permutation(dump).reshape(slots, width).astype(np.int32)
-    most = width * page
-    mixed = rng.integers(1, most + 1, slots)
-    mixed[slots // 2] = 0
-    lens_of = {str(c): np.full((slots,), min(c, most)) for c in contexts}
-    lens_of["mixed"] = mixed
     out = {"phase": "mla_decode", "slots": slots, "heads": heads,
            "row": [rank, rope], "page": page, "table": width,
            "layers": layers, "tol": tol, "gap": {}, "ms_a_call": {},
            "gb_a_s": {}}
-
-    def table_of(lens):
-        used = np.arange(width)[None, :] < -(-lens[:, None] // page)
-        return jnp.asarray(np.where(used, owned, dump), jnp.int32)
-
     twin = jax.jit(lambda ql, qr, pool, table, lens:
                    M.mla_paged_attention_xla(ql, qr, pool, layers - 1,
                                              table, lens, sm_scale=scale))
-    cases = {}      # name -> (lens, table, lens on the device, XLA's answer)
-    for name, lens in lens_of.items():
-        table, n = table_of(lens), jnp.asarray(lens, jnp.int32)
-        cases[name] = (lens, table, n, twin(ql, qr, pool, table, n))
+    # name -> (lens, table, lens on the device, XLA's answer)
+    cases = {name: (lens, table, n, twin(ql, qr, pool, table, n))
+             for name, (lens, table, n) in decode_cases(
+                 rng, slots=slots, width=width, page=page,
+                 contexts=contexts)[0].items()}
     was = M.BLOCK_TOKENS
     try:
         for tokens in blocks:
@@ -801,6 +826,112 @@ def mla_decode_phase(*, seed, slots=64, heads=64, rank=512, rope=64,
     return out
 
 
+# ------------------------------------------------- the K/V decode kernel
+# The paged cells' decode calls: slots, pool rows a page (KV heads; two
+# heads of 64 a 128-lane row in Granite's), query heads a row (half of
+# Granite's 8 are ``_attend_packed``'s zero padding), the table's width
+# in pages of 16, the layers in a pool and the contexts the mix runs.
+PAGED_CELLS = {
+    "mistral": dict(slots=32, kvh=8, rep=4, width=64, layers=16,
+                    contexts=(1, 145, 500, 900)),
+    "granite": dict(slots=64, kvh=4, rep=8, width=256, layers=4,
+                    contexts=(1, 300, 1100, 4032)),
+    "nemotron": dict(slots=64, kvh=2, rep=16, width=256, layers=6,
+                     contexts=(1, 300, 1100, 4032)),
+}
+
+# As MLA_TOL: both forms round the softmax's weights to bfloat16 before
+# p.v, the kernel a round at a time; relative to 1 + |the XLA form's|.
+PAGED_TOL = 2.0 ** -6
+
+
+def paged_decode_phase(*, seed, slots, kvh, rep, width, layers, contexts,
+                       d=128, page=16, blocks=(256, 512, 1024, 2048, 4096),
+                       rounds=(256, 512, 1024), calls=200,
+                       dtype="bfloat16", tol=PAGED_TOL) -> dict:
+    """``paged_attention`` against ``paged_attention_xla`` with every
+    slot at each of ``contexts`` and at a mix (one slot empty, the rest
+    anywhere up to the table's width), pages scattered over the pools
+    and the table padded by the dump page; then milliseconds a call and
+    GB/s of the K and V the contexts hold, at each of ``blocks`` tokens
+    a grid step (those the table holds) and ``rounds`` rows a round."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import paged_attention as PA
+    t0 = time.perf_counter()
+    rng = np.random.default_rng(seed)
+    kk, kv, kq = jax.random.split(jax.random.key(seed), 3)
+    dump = slots * width
+    shape = (layers, dump + 1, kvh, page, d)
+    kpool = jax.random.normal(kk, shape, jnp.float32).astype(dtype)
+    vpool = jax.random.normal(kv, shape, jnp.float32).astype(dtype)
+    q = jax.random.normal(kq, (slots, kvh * rep, d), jnp.float32
+                          ).astype(dtype)
+    page_bytes = PA.page_bytes(kpool)
+    token_bytes = 2 * page_bytes // page            # K and V
+    out = {"phase": "paged_decode", "slots": slots, "kv_rows": kvh,
+           "rep": rep, "d": d, "page": page, "table": width,
+           "layers": layers, "page_bytes": page_bytes, "tol": tol,
+           "gap": {}, "ms_a_call": {}, "gb_a_s": {}}
+
+    last = (calls - 1) % layers     # the layer the timed loop ends on
+    twin = jax.jit(lambda q, k, v, table, lens: PA.paged_attention_xla(
+        q, k, v, last, table, lens))
+    found, most = decode_cases(rng, slots=slots, width=width, page=page,
+                               contexts=contexts)
+    # name -> (lens, table, lens on the device, XLA's answer)
+    cases = {name: (lens, table, n, twin(q, kpool, vpool, table, n
+                                         ).astype(jnp.float32))
+             for name, (lens, table, n) in found.items()}
+    was = PA.BLOCK_BYTES, PA.ROUND_TOKENS
+    try:
+        for tokens, rows in ((t, r) for t in blocks for r in rounds
+                             if r <= t <= most):
+            # read as a call is traced
+            PA.BLOCK_BYTES = tokens // page * page_bytes
+            PA.ROUND_TOKENS = rows
+
+            def every_call(q, k, v, table, lens):
+                # one program, a call a turn of the loop on layer
+                # i % layers: the device's time, no dispatch between
+                return jax.lax.fori_loop(
+                    0, calls, lambda i, _: PA.paged_attention(
+                        q, k, v, i % layers, table, lens),
+                    jnp.zeros_like(q))
+
+            timed = jax.jit(every_call)
+            for name, (lens, table, n, want) in cases.items():
+                key = f"block{tokens}.round{rows}.ctx{name}"
+                try:
+                    got = timed(q, kpool, vpool, table, n)
+                except Exception as e:  # more VMEM than a kernel may use
+                    out["ms_a_call"][key] = (
+                        f"refused: {type(e).__name__}: {str(e)[:200]}")
+                    continue
+                # an empty slot's row is NaN in the XLA form (a softmax
+                # over nothing) and zeros in the kernel's
+                got = got.astype(jnp.float32)
+                gap = float(jnp.max(jnp.where(
+                    (n > 0)[:, None, None],
+                    jnp.abs(got - want) / (1.0 + jnp.abs(want)),
+                    jnp.abs(got))))
+                out["gap"][key] = gap
+                if not gap <= tol:      # a NaN fails too
+                    raise RuntimeError(
+                        f"paged_attention differs from its XLA form at "
+                        f"{key}: {gap} > {tol}")
+                t = time.perf_counter()
+                jax.block_until_ready(timed(q, kpool, vpool, table, n))
+                ms = (time.perf_counter() - t) * 1e3 / calls
+                out["ms_a_call"][key] = round(ms, 4)
+                out["gb_a_s"][key] = round(
+                    int(lens.sum()) * token_bytes / ms / 1e6, 1)
+    finally:
+        PA.BLOCK_BYTES, PA.ROUND_TOKENS = was
+    out["seconds"] = round(time.perf_counter() - t0, 2)
+    return out
+
+
 # ------------------------------------------------------------------- main
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
@@ -815,6 +946,10 @@ def main(argv=None) -> int:
                          "over E matrices [K, N]")
     ap.add_argument("--mla-decode", action="store_true",
                     help="run only the latent decode kernel's check")
+    ap.add_argument("--paged-decode", nargs="?", const="all",
+                    choices=("all", *PAGED_CELLS), metavar="CELL",
+                    help="run only the K/V paged decode kernel's check, "
+                         "at every paged cell's shape or at CELL's")
     args = ap.parse_args(argv)
 
     from paddle_tpu.utils.compile_cache import enable_compile_cache
@@ -834,6 +969,11 @@ def main(argv=None) -> int:
         return _ok(devices)
     if args.mla_decode:
         say(**mla_decode_phase(seed=SEED))
+        return _ok(devices)
+    if args.paged_decode:
+        for cell, shape in PAGED_CELLS.items():
+            if args.paged_decode in ("all", cell):
+                say(cell=cell, **paged_decode_phase(seed=SEED, **shape))
         return _ok(devices)
 
     from paddle_tpu.models.bert import BertConfig

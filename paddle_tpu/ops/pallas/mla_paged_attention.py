@@ -54,8 +54,8 @@ __all__ = ["mla_paged_attention", "mla_paged_attention_xla",
 _INTERPRET = False
 
 # Tokens of latent rows one grid step of the kernel covers (its pages:
-# pages_per_block).  The K/V kernel's 256 is sized for pages of 32 KB a
-# pool; a latent page is 20 KB.  Chosen on the v5e at the GigaChat cell's
+# pages_per_block).  The K/V kernel's rule goes by a page's bytes (two
+# pools); a latent page is 20 KB.  Chosen on the v5e at the GigaChat cell's
 # shape (64 slots, 64 heads over 512 + 64, page 16, 256 table columns, 5
 # layers in the pool; ``chip_smoke.py --mla-decode``, PR 33): 256 / 512 /
 # 1024 / 2048 / 4096 took 0.294 / 0.220 / 0.176 / 0.164 / 0.139 ms a call

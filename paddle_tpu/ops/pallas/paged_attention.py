@@ -10,21 +10,34 @@ TPU formulation: the page gather CANNOT be one dense einsum (the dense
 path's whole trick), so this is where a kernel is the only option.  The
 pools stay in HBM and the kernel copies pages itself.  One grid step is
 one BLOCK of pages of one slot, for all its KV heads: grid
-``(slots, ceil(max_pages / blk))``, ``blk = pages_per_block(...)`` pages
-of about ``BLOCK_TOKENS`` tokens.  The block table and the lengths ride
-Pallas scalar prefetch; the kernel reads ``table[b, p]`` and starts one
-DMA a page for K and one for V — the pool's layout makes a page
-contiguous across its KV heads, so that DMA moves ``kvH * page_size * D``
-elements into rows ``[p * page_size, (p + 1) * page_size)`` of a VMEM
-buffer ``[kvH, blk * page_size, D]``.  Two such buffers alternate: a
-step starts the copies of the next block that holds visible tokens (the
-slot's next, or the next slot's first) before it waits for its own, so
-the copies run under the arithmetic.  Nothing is copied or computed past
-a slot's context: a grid step whose block starts at or beyond
-``lens[b]`` does nothing, and the last live block copies only its live
-pages — table padding (the shared DUMP page) is never fetched.  The
-arithmetic takes the whole block for all heads at once (one batched
-``dot_general`` over the KV heads) with a float32 online softmax.
+``(slots, ceil(max_pages / blk))``, ``blk = pages_per_block(...)`` pages,
+as many as ``BLOCK_BYTES`` of one pool hold (the whole table where that
+is less).  The block table and the lengths ride Pallas scalar prefetch;
+the kernel reads ``table[b, p]`` and starts one DMA a page for K and one
+for V, ``START_UNROLL`` pages a loop turn — the pool's layout makes a
+page contiguous across its KV heads, so that DMA moves
+``kvH * page_size * D`` elements into rows
+``[p * page_size, (p + 1) * page_size)`` of a VMEM buffer
+``[kvH, rows, D]``.  Two such buffers a pool alternate: a step starts
+the copies of the next block that holds visible tokens (the slot's next,
+or the next slot's first) before it waits for its own, so the copies run
+under the arithmetic; the DMA semaphores count bytes, so a block's
+copies are waited for a power of two of pages at a time (one wait a pool
+for each power of two in the live page count, not one a page).  Nothing
+is copied or computed past a slot's context: a grid step whose block
+starts at or beyond ``lens[b]`` does nothing, and the last live block
+copies only its live pages — table padding (the shared DUMP page) is
+never fetched.  The arithmetic walks the block's copied rows in rounds
+of a float32 online softmax, all KV heads at once (one batched
+``dot_general`` a product): whole rounds of ``ROUND_TOKENS`` rows
+unmasked over rows the context covers, then ONE masked round of the
+smallest of ``tail_sizes`` that covers the rest.
+
+The call's wrapper is one ``jax.jit`` whose operands include the layer:
+the L calls of a step program share one jaxpr and one lowered Mosaic
+body, so what the body holds is traced and lowered once a program, not
+once a layer (tracing and lowering are paid by every process, whether
+or not the compile cache then serves the executable).
 
 Layout: pools [L, num_pages, kvH, page_size, D] (trailing dims tile):
 every layer's pages in one array, which the callers pass WHOLE with the
@@ -46,19 +59,58 @@ import numpy as np
 
 from .flash_attention import NUM_LANES
 
-__all__ = ["paged_attention", "pages_per_block", "PagedPool", "PagedKV",
-           "select_paged_attention",
+__all__ = ["paged_attention", "pages_per_block", "page_bytes", "PagedPool",
+           "PagedKV", "select_paged_attention",
            "gather_kv_pages", "quantize_kv_rows", "gather_scale_pages",
            "gather_kv_pages_quant", "paged_attention_quant"]
 
 _INTERPRET = False
 
-# Tokens of K/V one grid step of the kernel covers (its pages:
-# pages_per_block).  Chosen on the v5e at the serving cell's shape (32
-# slots, 8 KV heads, page 16, 64 table columns, contexts 145-617): 128 /
-# 256 / 512 took 0.106 / 0.086 / 0.086 ms a call, and at contexts of 1
-# token 0.044 / 0.042 / 0.050 ms.
-BLOCK_TOKENS = 256
+# Bytes of one pool one grid step of the kernel copies at most (its
+# pages: pages_per_block): 64 pages of 32 KiB in the Mistral cell (8 KV
+# heads; the whole table of 1,024 tokens), 128 of 16 KiB in Granite's (4
+# rows of two heads of 64; half its table, 2,048 tokens), 256 of 8 KiB in
+# Nemotron's (2 KV heads; the whole table, 4,096 tokens).  Measured on
+# the v5e with the kernel alone in one program, 200 calls on end, every
+# slot at one context (``chip_smoke.py --paged-decode``, PR 36; rounds of
+# 1,024 rows; ms a call at blocks of 256 / 512 / 1,024 / 2,048 / 4,096
+# tokens, and the kernel as it was before PR 36 — blocks of 256 tokens,
+# a wait a page, one masked round a block — last, in brackets):
+#   Mistral  [32 slots, 8 rows a page, rep 4, table 64]
+#     context 1      0.047 / 0.042 / 0.037                    [0.047]
+#     context 500    0.120 / 0.120 / 0.099                    [0.120]
+#     context 900    0.189 / 0.168 / 0.170                    [0.194]
+#   Granite  [64, 4, rep 8, table 256]
+#     context 1      0.142 / 0.100 / 0.080 / 0.068 / refused  [0.133]
+#     context 1,100  0.451 / 0.348 / 0.298 / 0.279 / refused  [0.475]
+#     context 4,032  1.284 / 1.009 / 0.866 / 0.842 / refused  [1.418]
+#   Nemotron [64, 2, rep 16, table 256]
+#     context 1      0.142 / 0.098 / 0.076 / 0.066 / 0.059    [0.134]
+#     context 1,100  0.446 / 0.337 / 0.285 / 0.268 / 0.263    [0.468]
+#     context 4,032  1.256 / 0.970 / 0.825 / 0.801 / 0.796    [1.385]
+# (blocks of 256 and 512 at rounds of their own size).  3 MiB (3,072
+# tokens of Granite's) read 1 % faster than 2 and 4 MiB are refused: two
+# buffers a pool of 2 MiB are 8 MiB of the 16 MiB of VMEM a call may use
+# unasked, and the rounds' own values want the rest.  At 2 KV heads the
+# kernel is bound by the issue of its copies, 25 ns a page a pool
+# whatever the page holds (8 KiB: 33 % of HBM's rate at contexts of
+# 1,100, Granite's 16 KiB 63 %, Mistral's 32 KiB 81 % at 500).
+BLOCK_BYTES = 2 << 20
+
+# Rows one whole round of the online softmax takes.  Same runs, ms a
+# call at rounds of 256 / 512 / 1,024 / 2,048 rows in the blocks above:
+# Mistral at 500 0.100 / 0.099 / 0.099 / -; Granite at 1,100 0.358 /
+# 0.306 / 0.279 / 0.284 and at 4,032 1.158 / 0.952 / 0.842 / 0.823;
+# Nemotron at 1,100 0.339 / 0.289 / 0.263 / 0.256, at 4,032 1.100 / 0.899
+# / 0.796 / 0.759 and at 1 token 0.057 / 0.058 / 0.059 / 0.064.  A round
+# costs much the same whatever it holds, as the latent kernel's does;
+# 2,048 gains 3 % only where every slot is long and wants the VMEM that
+# Granite's block of 3 MiB also asked for (refused together).
+ROUND_TOKENS = 1024
+
+# Page copies a pool one turn of the loop that starts a block's copies
+# issues (the latent kernel's: 4, 8 and 16 measured alike there).
+START_UNROLL = 8
 
 
 def select_paged_attention(tp_axis: str | None = None):
@@ -103,17 +155,37 @@ def _check_local_heads(q, kpool):
             "size must divide both head counts")
 
 
-def pages_per_block(page_size: int, max_pages: int) -> int:
-    """Pages one grid step of :func:`paged_attention` covers: a block of
-    about ``BLOCK_TOKENS`` tokens, at least one page and at most the
-    table's width.  The engine's ``paged_blocks_*`` counters read the
-    rule here rather than repeat it."""
-    return max(1, min(BLOCK_TOKENS // int(page_size), int(max_pages)))
+def page_bytes(pool) -> int:
+    """Bytes one page of ``pool`` [L, P, kvH, page_size, D] holds: what
+    one of the kernel's page copies moves."""
+    kvh, page_size, d = pool.shape[2:]
+    return kvh * page_size * d * jnp.dtype(pool.dtype).itemsize
+
+
+def pages_per_block(max_pages: int, page_bytes: int) -> int:
+    """Pages one grid step of :func:`paged_attention` covers: as many
+    pages of ``page_bytes`` (one pool's: :func:`page_bytes`) as
+    ``BLOCK_BYTES`` hold, at least one and at most the table's width.
+    The engine's ``paged_blocks_*`` counters read the rule here rather
+    than repeat it."""
+    return max(1, min(BLOCK_BYTES // int(page_bytes), int(max_pages)))
+
+
+def round_tokens(block_tokens: int) -> int:
+    """Rows one whole round of the online softmax takes in a block of
+    ``block_tokens``."""
+    return min(ROUND_TOKENS, int(block_tokens))
+
+
+def tail_sizes(chunk: int) -> list[int]:
+    """Rows the one masked round after a block's whole rounds may take:
+    one to four quarters of a round, in whole sublane tiles."""
+    return sorted({min(chunk, -(-chunk * k // 64) * 16) for k in (1, 2, 3, 4)})
 
 
 def _paged_kernel(table_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
                   o_ref, kbuf, vbuf, sems, side_ref, acc_ref, m_ref, l_ref,
-                  *, page_size, blk, max_pages, sm_scale):
+                  *, page_size, blk, chunk, unroll, max_pages, sm_scale):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -134,27 +206,40 @@ def _paged_kernel(table_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
         n_pages = (visible(b_) + page_size - 1) // page_size
         return jnp.clip(n_pages - j_ * blk, 0, blk)
 
-    def page_copies(side, p, page):
-        # one page = all its KV heads, contiguous in the pool
-        rows = pl.ds(pl.multiple_of(p * page_size, page_size), page_size)
-        return (pltpu.make_async_copy(k_hbm.at[layer, page],
-                                      kbuf.at[side, :, rows, :],
-                                      sems.at[0, side]),
-                pltpu.make_async_copy(v_hbm.at[layer, page],
-                                      vbuf.at[side, :, rows, :],
-                                      sems.at[1, side]))
-
     def start_block(b_, j_, side):
-        def body(p, _):
-            for c in page_copies(side, p, table_ref[b_, j_ * blk + p]):
-                c.start()
-        jax.lax.fori_loop(0, live_pages(b_, j_), body, None)
+        def start(p):
+            # one page = all its KV heads, contiguous in the pool
+            page = table_ref[b_, j_ * blk + p]
+            rows = pl.ds(pl.multiple_of(p * page_size, page_size), page_size)
+            pltpu.make_async_copy(k_hbm.at[layer, page],
+                                  kbuf.at[side, :, rows, :],
+                                  sems.at[0, side]).start()
+            pltpu.make_async_copy(v_hbm.at[layer, page],
+                                  vbuf.at[side, :, rows, :],
+                                  sems.at[1, side]).start()
+
+        def group(g, _):
+            for i in range(unroll):
+                start(g * unroll + i)
+        n = live_pages(b_, j_)
+        jax.lax.fori_loop(0, n // unroll, group, None)
+        jax.lax.fori_loop(n // unroll * unroll, n,
+                          lambda p, _: start(p), None)
 
     def wait_block(b_, j_, side):
-        def body(p, _):
-            for c in page_copies(side, p, 0):       # same sizes
-                c.wait()
-        jax.lax.fori_loop(0, live_pages(b_, j_), body, None)
+        # The semaphores count bytes, so one wait can stand for the
+        # copies of k pages of a pool: a wait a pool for each power of
+        # two in the live count, not one a page.
+        n = live_pages(b_, j_)
+        k = 1 << (blk.bit_length() - 1)
+        while k:
+            @pl.when((n & k) != 0)
+            def _wait(k=k):
+                for buf, sem in ((kbuf, sems.at[0, side]),
+                                 (vbuf, sems.at[1, side])):
+                    rows = buf.at[side, :, pl.ds(0, k * page_size), :]
+                    pltpu.make_async_copy(rows, rows, sem).wait()
+            k //= 2
 
     n_tok = visible(b)
 
@@ -186,22 +271,24 @@ def _paged_kernel(table_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
         side_ref[0] = 1 - side
         wait_block(b, j, side)
 
-        @pl.when(n_tok > 0)
-        def _compute():
+        # the block's rows that the slot sees: 0 where n_tok is 0
+        seen = jnp.clip(n_tok - j * tokens, 0, tokens)
+
+        def softmax_round(at, size, masked):
+            # one round of the online softmax over rows [at, at + size)
             q = q_ref[...]                          # [kvH, rep, D]
-            k = kbuf[side]                          # [kvH, tokens, D]
-            v = vbuf[side]
+            k = kbuf[side, :, pl.ds(at, size), :]   # [kvH, size, D]
+            v = vbuf[side, :, pl.ds(at, size), :]
             s = jax.lax.dot_general(
                 q, k, (((2,), (2,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32) * jnp.float32(sm_scale)
-            t_s = j * tokens + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 2)
-            s = jnp.where(t_s < n_tok, s, -jnp.inf)
-            # past the context the buffer holds whatever was there:
-            # 0 * NaN in p.v would poison the row, so v is masked too
-            t_v = j * tokens + jax.lax.broadcasted_iota(
-                jnp.int32, v.shape, 1)
-            v = jnp.where(t_v < n_tok, v, jnp.zeros_like(v))
+            if masked:
+                t_s = at + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+                s = jnp.where(t_s < seen, s, -jnp.inf)
+                # past the context the buffer holds whatever was there:
+                # 0 * NaN in p.v would poison the row, so v is masked too
+                t_v = at + jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
+                v = jnp.where(t_v < seen, v, jnp.zeros_like(v))
             m_prev = m_ref[:, :, :1]
             l_prev = l_ref[:, :, :1]
             m_cur = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
@@ -213,6 +300,21 @@ def _paged_kernel(table_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
                 preferred_element_type=jnp.float32)
             m_ref[...] = jnp.broadcast_to(m_cur, m_ref.shape)
             l_ref[...] = jnp.broadcast_to(l_cur, l_ref.shape)
+
+        # Whole rounds go by unmasked over rows the context covers, and
+        # what is left takes ONE masked round, of the smallest size that
+        # covers it.
+        whole = seen // chunk
+        jax.lax.fori_loop(
+            0, whole, lambda i, _: softmax_round(
+                pl.multiple_of(i * chunk, chunk), chunk, False), None)
+        left, covered = seen - whole * chunk, 0
+        for size in tail_sizes(chunk):
+            @pl.when((left > covered) & (left <= size))
+            def _tail(size=size):
+                softmax_round(pl.multiple_of(whole * chunk, chunk), size,
+                              True)
+            covered = size
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
@@ -226,7 +328,26 @@ def paged_attention(q, kpool, vpool, layer, table, lens):
     ``layer`` the pools' layer to read (a Python int or a traced
     scalar); table [B, max_pages] int32 page ids (padding = a dump page
     id, as PagedPool builds it — never a real page); lens [B] visible
-    tokens.  Returns [B, nh, D]."""
+    tokens.  Returns [B, nh, D].
+
+    Every call of one shape inside a program is a call of ONE traced
+    and lowered function (``layer`` is an operand of it): the kernel's
+    body is traced and lowered to Mosaic once a program, not once a
+    layer."""
+    blk = pages_per_block(table.shape[1], page_bytes(kpool))
+    return _paged_attention(
+        q, kpool, vpool, jnp.asarray(layer, jnp.int32).reshape(1),
+        table.astype(jnp.int32), lens.astype(jnp.int32), blk=blk,
+        chunk=round_tokens(blk * kpool.shape[3]), unroll=START_UNROLL,
+        interpret=_INTERPRET)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("blk", "chunk", "unroll", "interpret"))
+def _paged_attention(q, kpool, vpool, layer, table, lens, *, blk, chunk,
+                     unroll, interpret):
+    # what the module's switches said when the caller read them rides
+    # in as static arguments: a cached trace never reads a global
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -234,7 +355,9 @@ def paged_attention(q, kpool, vpool, layer, table, lens):
     kvh, page_size = kpool.shape[2], kpool.shape[3]
     rep = nh // kvh
     max_pages = table.shape[1]
-    blk = pages_per_block(page_size, max_pages)
+    # the buffers hold whole rounds, so the last round of a block that
+    # is no multiple of one reads rows nothing copies: masked like any
+    rows = -(-blk * page_size // chunk) * chunk
     qg = q.reshape(b, kvh, rep, d)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -251,8 +374,8 @@ def paged_attention(q, kpool, vpool, layer, table, lens):
         out_specs=pl.BlockSpec((None, kvh, rep, d),
                                lambda b_, j, tbl, ln, ly: (b_, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, kvh, blk * page_size, d), kpool.dtype),
-            pltpu.VMEM((2, kvh, blk * page_size, d), vpool.dtype),
+            pltpu.VMEM((2, kvh, rows, d), kpool.dtype),
+            pltpu.VMEM((2, kvh, rows, d), vpool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),        # [k|v, buffer]
             pltpu.SMEM((1,), jnp.int32),            # buffer being read
             pltpu.VMEM((kvh, rep, d), jnp.float32),
@@ -263,14 +386,14 @@ def paged_attention(q, kpool, vpool, layer, table, lens):
     with jax.enable_x64(False):   # see flash_attention._flash_fwd
         out = pl.pallas_call(
             functools.partial(_paged_kernel, page_size=page_size,
-                              blk=blk, max_pages=max_pages,
+                              blk=blk, chunk=chunk, unroll=unroll,
+                              max_pages=max_pages,
                               sm_scale=1.0 / np.sqrt(d)),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, kvh, rep, d), q.dtype),
-            interpret=_INTERPRET,
+            interpret=interpret,
             name="paged_attention",
-        )(table.astype(jnp.int32), lens.astype(jnp.int32),
-          jnp.asarray(layer, jnp.int32).reshape(1), qg, kpool, vpool)
+        )(table, lens, layer, qg, kpool, vpool)
     return out.reshape(b, nh, d)
 
 

@@ -82,7 +82,7 @@ from ..models.generation import GenerationConfig
 from ..models.llama import LlamaConfig
 from ..ops.pallas.mla_paged_attention import (
     pages_per_block as latent_pages_per_block)
-from ..ops.pallas.paged_attention import pages_per_block
+from ..ops.pallas.paged_attention import page_bytes, pages_per_block
 from .block_manager import BlockManager
 from .faults import InjectedFault, fault_plan_from_flags
 from .parallel import ModelRunner, parse_mesh
@@ -229,13 +229,6 @@ class Engine:
                 f"max_model_len {self.max_model_len} exceeds the model's "
                 f"max_position_embeddings {config.max_position_embeddings}")
         self.table_width = -(-self.max_model_len // self.page_size)
-        # the paged decode kernel's unit of work, by its own rule (the
-        # latent kernel has its own): the tokens one grid step covers
-        # and the steps a slot's row makes
-        rule = latent_pages_per_block if self.latent else pages_per_block
-        blk = rule(self.page_size, self.table_width)
-        self._block_tokens = blk * self.page_size
-        self._blocks_per_row = -(-self.table_width // blk)
         if num_pages is None:       # full residency: every slot can run
             num_pages = self.max_slots * self.table_width  # at max length
         self.emit_logits = bool(emit_logits)
@@ -387,6 +380,18 @@ class Engine:
             # bind the store to the bank: resident adapters (if any)
             # upload now; later acquires patch single rows in place
             self.lora.attach(self.runner)
+        # the paged decode kernel's unit of work, by its own rule (the
+        # latent kernel has its own; the K/V kernel's reads the bytes of
+        # a page as a device's pool holds it): the tokens one grid step
+        # covers and the steps a slot's row makes
+        if self.latent:
+            blk = latent_pages_per_block(self.page_size, self.table_width)
+        else:
+            blk = pages_per_block(
+                self.table_width,
+                page_bytes(self.runner.kpool) // self.tp)
+        self._block_tokens = blk * self.page_size
+        self._blocks_per_row = -(-self.table_width // blk)
 
         # host-side mirrors of the slot state (bookkeeping + targeted
         # device patches on admit/evict; NEVER re-uploaded per step)

@@ -92,18 +92,50 @@ def compile_kernels(fn, *args) -> int:
         'custom_call_target="tpu_custom_call"')
 
 
-@pytest.mark.parametrize("slots,kvh,ps,width", [
-    (8, KVH, 16, 128),      # a decode step of 8 slots, 2048 tokens each
-    (32, KVH, 16, 64),      # the serving cell: 32 slots, 1024 tokens
-    (32, 2, 16, 64),        # the same inside tp=4: a shard's 2 KV heads
-    (4, KVH, 128, 5),       # the one-shot generate's pool: page 128
-], ids=["slots8", "cell", "tp-local", "one-shot"])
-def test_paged_attention(sds, slots, kvh, ps, width):
+# what a Mosaic call that sets no ``vmem_limit_bytes`` may use on a v5e
+V5E_SCOPED_VMEM = 16 << 20
+
+
+def _pallas_call(fn, *args):
+    """The one ``pallas_call`` equation ``fn`` traces to, inside
+    whatever ``jit`` of its own the kernel's wrapper holds it in."""
+    def walk(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                yield e
+            elif e.primitive.name in ("pjit", "jit"):
+                yield from walk(e.params["jaxpr"].jaxpr)
+    found = list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+    assert len(found) == 1, found
+    return found[0]
+
+
+def _scratch_vmem(eqn) -> int:
+    """Bytes of VMEM scratch the ``pallas_call`` equation declares."""
+    mapping = eqn.params["grid_mapping"]
+    scratch = [v.aval for v in
+               eqn.params["jaxpr"].invars[-mapping.num_scratch_operands:]]
+    return sum(a.size * a.dtype.itemsize for a in scratch
+               if str(a.memory_space) == "vmem")
+
+
+@pytest.mark.parametrize("slots,kvh,rep,ps,width", [
+    (8, KVH, 4, 16, 128),   # a decode step of 8 slots, 2048 tokens each
+    (32, KVH, 4, 16, 64),   # the Mistral cell: 32 slots, 1024 tokens
+    (32, 2, 4, 16, 64),     # the same inside tp=4: a shard's 2 KV heads
+    (4, KVH, 4, 128, 5),    # the one-shot generate's pool: page 128
+    (64, 4, 8, 16, 256),    # the Granite cell: two heads of 64 a row
+    (64, 2, 16, 16, 256),   # the Nemotron cell: 2 KV heads of 128
+], ids=["slots8", "cell", "tp-local", "one-shot", "granite-cell",
+        "nemotron-cell"])
+def test_paged_attention(sds, slots, kvh, rep, ps, width):
     pool = sds((2, slots * width + 1, kvh, ps, HD))     # two layers
-    compiled = jax.jit(
-        lambda q, k, v, t, n: PA.paged_attention(q, k, v, 1, t, n)).lower(
-        sds((slots, 4 * kvh, HD)), pool, pool,
-        sds((slots, width), jnp.int32), sds((slots,), jnp.int32)).compile()
+
+    def call(q, k, v, t, n):
+        return PA.paged_attention(q, k, v, 1, t, n)
+    args = (sds((slots, rep * kvh, HD)), pool, pool,
+            sds((slots, width), jnp.int32), sds((slots,), jnp.int32))
+    compiled = jax.jit(call).lower(*args).compile()
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert len(_paged_events(event_names(compiled))) == 1
@@ -115,6 +147,19 @@ def test_paged_attention(sds, slots, kvh, ps, width):
         r'custom_call_target="tpu_custom_call", '
         rf'operand_layout_constraints=\{{s32\[{slots},{width}\]\S* '
         rf's32\[{slots}\]', text), text[-3000:]
+    # the grid the block rule gives, and its buffers (two a pool, of
+    # whole rounds) beside the rounds' own values inside the VMEM a
+    # call may use unasked: the compile above is what holds it to that
+    eqn = _pallas_call(call, *args)
+    page_bytes = kvh * ps * HD * 2
+    blk = PA.pages_per_block(width, page_bytes)
+    assert eqn.params["grid_mapping"].grid == (slots, -(-width // blk))
+    chunk = PA.round_tokens(blk * ps)
+    rows = -(-blk * ps // chunk) * chunk
+    assert _scratch_vmem(eqn) == (
+        4 * rows * (page_bytes // ps) + kvh * rep * (HD + 2 * 128) * 4)
+    assert dict(eqn.params["compiler_params"]).get("mosaic_tpu") is None
+    assert _scratch_vmem(eqn) <= V5E_SCOPED_VMEM // 2 + (1 << 20)
 
 
 def _metric_events(name):
@@ -138,6 +183,15 @@ def _hlo_lines(text, stem):
     trace names their events: the line from its ``%``."""
     lines = [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()]
     return [ln for ln in lines if ln.startswith("%" + stem)]
+
+
+def _paged_body_and_sites(lowered_text):
+    """(paged kernels the lowered program holds, calls of the function
+    that holds one): the kernel's wrapper is one ``jit`` with the layer
+    as an operand, so a step program traces and lowers the body once
+    and calls it a layer."""
+    return (lowered_text.count('kernel_name = "paged_attention"'),
+            len(re.findall(r"\bcall @_paged_attention\(", lowered_text)))
 
 
 def _cell_runner(monkeypatch, *, spec_k=0, kv_quant=False, layers=2):
@@ -333,7 +387,12 @@ def test_cell_programs_keep_their_kernels_and_scope_order(
             sds((1, bucket), jnp.int32), sds((1,), jnp.int32),
             sds((bucket // run.page_size,), jnp.int32), pool, pool, (), (),
             rope, rope, (), (), (), ())
-    assert lowered.as_text().count("@tpu_custom_call") == layers
+    text = lowered.as_text()
+    if program == "decode_step":    # one body, a call of it a layer
+        assert text.count("@tpu_custom_call") == 1
+        assert _paged_body_and_sites(text) == (1, layers)
+    else:
+        assert text.count("@tpu_custom_call") == layers
     assert _scope_order(lowered, {"embed", "head", *layer_scopes}) == (
         ["embed"] + layer_scopes * layers + ["head"])
 
@@ -352,8 +411,14 @@ def test_cell_programs_keep_their_kernels_and_scope_order(
 # ``mla_paged_attention``, so each layer reshapes it ([64, 256] ->
 # [16384]) and the call's first operand is that; with SSA numbers
 # stripped nothing else differs from the parent's text.
+# The Mistral ``decode_step`` is PR 36's own (its parent's was
+# 8fadd2647ac63de8): each layer's ``@tpu_custom_call`` of the paged
+# kernel became a ``call @_paged_attention`` of one private function
+# that holds the one custom call (and the reshapes of q and the output
+# around it), the layer riding in as ``tensor<1xi32>``.  The Mistral
+# ``prefill`` and both GigaChat programs are untouched.
 PARENT_HLO = {
-    ("mistral", "decode_step"): "8fadd2647ac63de8",
+    ("mistral", "decode_step"): "92543bcc7227c45d",
     ("mistral", "prefill"): "b60cf16e4026bb63",
     ("gigachat", "decode_step"): "b32ad1ec7af330f5",
     ("gigachat", "prefill"): "ea3b1ffe856fee5e"}
@@ -493,7 +558,9 @@ def test_hybrid_cell_programs_keep_their_kernels_and_scope_order(
     if program == "prefill":
         assert calls == len(cfg.attention_layers)       # flash, D = 64
         return
-    assert calls == cfg.num_hidden_layers       # a kernel a layer
+    # a kernel a Mamba layer, and the attention layers' ONE
+    assert calls == len(cfg.mamba_layers) + 1
+    assert _paged_body_and_sites(text) == (1, len(cfg.attention_layers))
     compiled = lowered.compile()
     hlo = compiled.as_text()
     updates = _hlo_lines(hlo, "ssm_state_update")
@@ -526,8 +593,12 @@ def test_hybrid_cell_programs_keep_their_kernels_and_scope_order(
 # H * P]`` and batches its products over the one group, the gated norm
 # reshapes to one group: reshapes and unit batch dimensions around the
 # parent's arithmetic (PERF.md, PR 34, has the cell's numbers beside the
-# parent's).  An edit that reaches the Granite program moves these.
-GRANITE_HLO = {"decode_step": "25da47839de8b841",
+# parent's).  The ``decode_step`` is PR 36's (PR 34's was
+# 25da47839de8b841): the four attention layers call ONE private
+# ``@_paged_attention`` function, as the Mistral program does; the
+# ``prefill`` is PR 34's still.  An edit that reaches the Granite
+# program moves these.
+GRANITE_HLO = {"decode_step": "a4eec2bffdb90ced",
                "prefill": "2bac911596edc873"}
 
 
@@ -600,7 +671,9 @@ def test_hybrid_moe_cell_programs_keep_their_kernels_and_scope_order(
     if program == "prefill":
         assert calls == n_a + 2 * n_e       # flash; up and down products
         return
-    assert calls == n_m + n_a + 2 * n_e
+    # the attention blocks' calls share one body
+    assert calls == n_m + 1 + 2 * n_e
+    assert _paged_body_and_sites(text) == (1, n_a)
     compiled = lowered.compile()
     hlo = compiled.as_text()
     by_stem = {stem: _hlo_lines(hlo, stem) for stem in (
@@ -622,7 +695,7 @@ def test_hybrid_moe_cell_programs_keep_their_kernels_and_scope_order(
             re.search(p, n) for p in _metric_events(metric))]
         assert len(found) == len(by_stem[stem]), metric
         assert all(n.startswith("%" + stem) for n in found), metric
-    assert len(every) == calls
+    assert len(every) == n_m + n_a + 2 * n_e
     mem = compiled.memory_analysis()
     record_property("temp_size_in_bytes", mem.temp_size_in_bytes)
     print(f"nemotron decode_step: temp {mem.temp_size_in_bytes}, "
@@ -635,6 +708,37 @@ def test_hybrid_moe_cell_programs_keep_their_kernels_and_scope_order(
     # the state pools and the K/V pools are all updated in place
     assert mem.alias_size_in_bytes > 1.54e9 + 1.6e9
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) < 0.9 * 2**34
+
+
+@pytest.mark.parametrize("cell,slots,width,calls", [
+    ("mistral", 32, 64, 16), ("granite", 64, 256, 4),
+    ("nemotron", 64, 256, 6)])
+def test_decode_steps_trace_and_lower_the_paged_kernel_once(
+        sds, monkeypatch, cell, slots, width, calls):
+    """What keeps set-up flat whatever the kernel's body holds, tested
+    without a clock: each paged cell's ``decode_step`` at the depth it
+    runs lowers to ONE paged-kernel body and a call of it an attention
+    layer (every process pays tracing and lowering, whether or not the
+    compile cache then serves the executable), and compiles to that many
+    ``%paged_attention`` custom calls whose first operands are still
+    the table ``[slots, width]`` and the lengths ``[slots]``, where the
+    Mistral cell's roofline looks for them."""
+    if cell == "mistral":
+        run, shapes = _cell_runner(monkeypatch, layers=None)
+        lowered = _lower_decode_program(sds, run, shapes)
+    else:
+        run, shapes = (_hybrid_cell_runner if cell == "granite"
+                       else _hybrid_moe_cell_runner)(monkeypatch)
+        lowered = _lower_hybrid_program(sds, run, shapes, "decode_step")
+    assert (run.max_slots, run.table_width) == (slots, width)
+    assert _paged_body_and_sites(lowered.as_text()) == (1, calls)
+    lines = _hlo_lines(lowered.compile().as_text(), "paged_attention")
+    assert len(lines) == calls
+    for ln in lines:
+        assert re.search(
+            r'custom_call_target="tpu_custom_call", '
+            rf'operand_layout_constraints=\{{s32\[{slots},{width}\]\S* '
+            rf's32\[{slots}\]\S* s32\[1\]', ln), ln[:600]
 
 
 def _donated(lowered) -> list[bool]:
@@ -693,8 +797,10 @@ def test_cell_decode_steps_lend_the_ring_and_keep_their_temporaries(
     record_property("temp_size_in_bytes", temp)
     print(f"{cell} decode_step: temp_size_in_bytes {temp}")
     assert low_mb * 1e6 < temp < high_mb * 1e6
-    if kernels is not None:
-        assert lowered.as_text().count("@tpu_custom_call") == kernels
+    if kernels is not None:         # one body, a call of it a layer
+        assert _paged_body_and_sites(lowered.as_text()) == (1, kernels)
+        assert len(_hlo_lines(compiled.as_text(),
+                              "paged_attention")) == kernels
 
 
 def test_slot_patch_is_one_program_at_the_cells_shapes(sds, monkeypatch):
@@ -739,18 +845,6 @@ def test_int8_pages_decode_step_reports_its_temporaries(sds, monkeypatch,
         assert "copy" not in opcode and "copy" not in stem, (stem, opcode)
 
 
-# what a Mosaic call that sets no ``vmem_limit_bytes`` may use on a v5e
-V5E_SCOPED_VMEM = 16 << 20
-
-
-def _pallas_call(fn, *args):
-    """The one ``pallas_call`` equation ``fn`` traces to."""
-    found = [e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
-             if e.primitive.name == "pallas_call"]
-    assert len(found) == 1, found
-    return found[0]
-
-
 def test_mla_paged_attention_at_the_cells_shapes(sds):
     """64 slots, 256 pages a slot, 5 layers in one pool, rows of 576
     values (declared at the 640 lanes they occupy), 64 heads over a
@@ -776,10 +870,7 @@ def test_mla_paged_attention_at_the_cells_shapes(sds):
     assert blk * page == MLA.BLOCK_TOKENS == 4096
     mapping = eqn.params["grid_mapping"]
     assert mapping.grid == (slots, -(-width // blk)) == (64, 1)
-    scratch = [v.aval for v in
-               eqn.params["jaxpr"].invars[-mapping.num_scratch_operands:]]
-    vmem = sum(a.size * a.dtype.itemsize for a in scratch
-               if str(a.memory_space) == "vmem")
+    vmem = _scratch_vmem(eqn)
     # two buffers of a block's rows, the accumulator, two lane-wide sides
     assert vmem == (2 * blk * page * 640 * 2 + heads * 512 * 4
                     + 2 * heads * 128 * 4)

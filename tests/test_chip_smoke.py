@@ -203,7 +203,9 @@ def test_grouped_matmul_phase_checks_both_tiles(monkeypatch):
     (["--ssm-update"], "ssm_update_phase", {"groups": 1}),
     (["--ssm-update", "8"], "ssm_update_phase", {"groups": 8}),
     (["--grouped-matmul", "16,2688,1856"], "grouped_matmul_phase",
-     {"experts": 16, "k": 2688, "n": 1856})])
+     {"experts": 16, "k": 2688, "n": 1856}),
+    (["--paged-decode", "granite"], "paged_decode_phase",
+     C.PAGED_CELLS["granite"])])
 def test_main_hands_the_phase_its_shape(monkeypatch, argv, phase, kw):
     seen = {}
     monkeypatch.setattr(C, phase, lambda **k: seen.update(k) or {"phase": 0})
@@ -240,6 +242,36 @@ def test_mla_decode_phase_checks_every_context_and_block(monkeypatch):
     monkeypatch.setattr(M, "mla_paged_attention", reads_a_row_too_many)
     with pytest.raises(RuntimeError, match="differs from its XLA form"):
         C.mla_decode_phase(blocks=(16,), **toy)
+
+
+def test_paged_decode_phase_checks_every_context_block_and_round(monkeypatch):
+    """The K/V decode kernel's chip check at a toy size, under the
+    Pallas interpreter: every context at every block and round the
+    table holds against the dense gather, the rule put back, and a
+    kernel that reads past a slot's context refused."""
+    from paddle_tpu.ops.pallas import paged_attention as PA
+    monkeypatch.setattr(PA, "_INTERPRET", True)
+    rule = PA.BLOCK_BYTES, PA.ROUND_TOKENS
+    toy = dict(seed=C.SEED, slots=4, kvh=2, rep=4, width=12, layers=2,
+               contexts=(1, 9, 48), page=4, calls=3, dtype="float32",
+               tol=1e-5)
+    out = C.paged_decode_phase(blocks=(8, 16, 32, 64), rounds=(8, 16), **toy)
+    swept = [(8, 8), (16, 8), (16, 16), (32, 8), (32, 16)]  # 64 > the table
+    keys = [f"block{b}.round{r}.ctx{c}" for b, r in swept
+            for c in ("1", "9", "48", "mixed")]
+    assert sorted(out["gap"]) == sorted(keys)
+    assert sorted(out["ms_a_call"]) == sorted(out["gb_a_s"]) == sorted(keys)
+    assert out["gap"]["block8.round8.ctx1"] == 0.0      # one row: itself
+    assert out["page_bytes"] == 2 * 4 * 128 * 4
+    assert (PA.BLOCK_BYTES, PA.ROUND_TOKENS) == rule
+    json.dumps(out)
+    xla = PA.paged_attention_xla
+
+    def reads_a_row_too_many(q, k, v, layer, table, lens):
+        return xla(q, k, v, layer, table, lens + 1)
+    monkeypatch.setattr(PA, "paged_attention", reads_a_row_too_many)
+    with pytest.raises(RuntimeError, match="differs from its XLA form"):
+        C.paged_decode_phase(blocks=(16,), rounds=(8,), **toy)
 
 
 def test_interpret_switches_are_checked(monkeypatch):

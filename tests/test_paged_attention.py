@@ -90,32 +90,19 @@ _GEOMETRY = {
 }
 
 
-@pytest.mark.parametrize("layer", [0, 2])
-@pytest.mark.parametrize("order", ["rising", "falling", "empty-first"])
-@pytest.mark.parametrize("geometry", list(_GEOMETRY))
-def test_paged_kernel_blocks_match_reference(geometry, order, layer):
-    """The block-of-pages kernel against the dense gather, under the TPU
-    interpreter with uninitialised scratch reading NaN: contexts of 1, a
-    block exactly, a block + 1, the whole table and nothing at all;
-    pages scattered over the pool; NaN in the dump page, in every page
-    no row names and in every other layer of the pool.  A stale buffer
-    tail, a copy past the context, a read past the table's row or of
-    another layer would show as NaN or as a difference."""
+def _check_against_reference(ps, kvh, rep, d, width, lens, layer, seed):
+    """The kernel under the TPU interpreter, uninitialised scratch
+    reading NaN, against the dense gather on slots that see ``lens``
+    tokens of a table ``width`` pages wide: pages scattered over the
+    pool; NaN in the dump page, in every page no row names and in every
+    other layer of the pool.  A stale buffer tail, a copy past the
+    context, a read past the table's row or of another layer shows as
+    NaN or as a difference."""
     from jax.experimental.pallas import tpu as pltpu
 
-    ps, kvh, beyond = _GEOMETRY[geometry]
-    rep, d = 4, 128
-    blk = PA.pages_per_block(ps, 1 << 20)
-    width, block = 2 * blk + beyond, blk * ps
-    assert PA.pages_per_block(ps, width) == blk
-    lens = [1, block, block + 1, width * ps, 0]
-    if order == "falling":
-        lens = lens[::-1]
-    elif order == "empty-first":
-        lens = [0, 0] + lens[:4]
-    rng = np.random.RandomState(len(geometry) + len(order))
+    rng = np.random.RandomState(seed)
     b = len(lens)
-    need = [-(-n // ps) for n in lens]
+    need = [min(-(-n // ps), width) for n in lens]
     n_pages = sum(need) + 4
     dump = n_pages - 1
     ids = rng.permutation(n_pages - 1)[:sum(need)]
@@ -155,6 +142,102 @@ def test_paged_kernel_blocks_match_reference(geometry, order, layer):
         else:
             np.testing.assert_allclose(out_k[i], out_x[i], atol=1e-4,
                                        rtol=1e-4)
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("order", ["rising", "falling", "empty-first"])
+@pytest.mark.parametrize("geometry", list(_GEOMETRY))
+def test_paged_kernel_blocks_match_reference(geometry, order, layer):
+    """The block-of-pages kernel against the dense gather
+    (``_check_against_reference``): contexts of 1, a block exactly, a
+    block + 1, the whole table and nothing at all, over a table of two
+    whole blocks and a part of one."""
+    ps, kvh, beyond = _GEOMETRY[geometry]
+    rep, d = 4, 128
+    page_bytes = kvh * ps * d * 4                   # float32 pools
+    blk = PA.pages_per_block(1 << 20, page_bytes)
+    width, block = 2 * blk + beyond, blk * ps
+    assert PA.pages_per_block(width, page_bytes) == blk
+    lens = [1, block, block + 1, width * ps, 0]
+    if order == "falling":
+        lens = lens[::-1]
+    elif order == "empty-first":
+        lens = [0, 0] + lens[:4]
+    _check_against_reference(ps, kvh, rep, d, width, lens, layer,
+                             seed=len(geometry) + len(order))
+
+
+# The decode call's shape in each cell that runs the kernel, inside a
+# tp=4 shard and in the one-shot generate: pool rows a page (KV heads),
+# query heads a row, page size, the table's width in pages.
+_SHAPES = {
+    "mistral": (8, 4, 16, 64),
+    "granite": (4, 8, 16, 256),         # two heads of 64 a 128-lane row
+    "nemotron": (2, 16, 16, 256),
+    "tp-local": (2, 4, 16, 64),
+    "one-shot": (8, 4, 128, 5),
+}
+
+
+def _edge_contexts(edge, ps, width, blk, chunk):
+    """The contexts one case runs, a slot each, empty slots among them."""
+    block, most = blk * ps, width * ps
+    if edge == "page":
+        return [0, 1, 0, ps - 1, ps, 0, ps + 1]
+    if edge == "round":     # a whole round, and one row either side
+        return [chunk - 1, 0, chunk, chunk + 1]
+    if edge == "block":     # a slot's row spills into its next block
+        return [block - 1, block, 0, min(block + 1, most)]
+    if edge == "pages":     # live pages a power of two, and not
+        two = 1 << (blk.bit_length() - 1)
+        return [two * ps, 0, (two - 1) * ps, (two // 2 + 1) * ps + 1]
+    assert edge == "table"  # the row's end, and ``lens`` beyond it
+    return [most, 0, most + 3 * ps + 1, most - 1]
+
+
+@pytest.mark.parametrize("edge", ["page", "round", "block", "pages",
+                                  "table"])
+@pytest.mark.parametrize("shape", list(_SHAPES))
+def test_paged_kernel_edges_match_reference(shape, edge):
+    """At each shape the kernel is called with, float32 pools of the
+    cell's bfloat16 bytes a page (so the block rule gives the cell's
+    block): contexts at every edge its structure has — a page, a round
+    of the softmax, a block, a live page count that is and is not a
+    power of two (the waits), the table's full width and ``lens`` past
+    it — with empty slots between live ones."""
+    kvh, rep, ps, width = _SHAPES[shape]
+    d = 128
+    cell_bytes = kvh * ps * d * 2
+    # float32 pages are twice the cell's: halve what a block may hold
+    blk = PA.pages_per_block(width, cell_bytes)
+    chunk = PA.round_tokens(blk * ps)
+    saved = PA.BLOCK_BYTES
+    PA.BLOCK_BYTES = 2 * saved
+    try:
+        assert PA.pages_per_block(width, 2 * cell_bytes) == blk
+        _check_against_reference(
+            ps, kvh, rep, d, width,
+            _edge_contexts(edge, ps, width, blk, chunk), layer=1,
+            seed=len(shape) + len(edge))
+    finally:
+        PA.BLOCK_BYTES = saved
+
+
+@pytest.mark.parametrize("shape", list(_SHAPES))
+def test_pages_per_block_fits_the_vmem_it_implies(shape):
+    """The block rule at each shape: at least a page, at most the table,
+    and two buffers a pool of whole rounds inside half of the 16 MiB a
+    call may use unasked (the rest is the rounds' own)."""
+    kvh, rep, ps, width = _SHAPES[shape]
+    page_bytes = kvh * ps * 128 * 2
+    blk = PA.pages_per_block(width, page_bytes)
+    assert 1 <= blk <= width
+    assert blk == width or (blk + 1) * page_bytes > PA.BLOCK_BYTES
+    chunk = PA.round_tokens(blk * ps)
+    rows = -(-blk * ps // chunk) * chunk
+    assert 2 * 2 * rows * (page_bytes // ps) <= 8 << 20
+    assert all(s % 16 == 0 and s <= chunk for s in PA.tail_sizes(chunk))
+    assert PA.tail_sizes(chunk)[-1] == chunk
 
 
 def _tiny_model():

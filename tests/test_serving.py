@@ -558,7 +558,9 @@ def test_engine_paged_block_counters(tiny_model, monkeypatch):
     from paddle_tpu.ops.pallas import paged_attention as PA
     obs.tracer().reset()
     # blocks of 32 tokens = 4 pages; a row of 12 pages makes 3 grid steps
-    monkeypatch.setattr(PA, "BLOCK_TOKENS", 32)
+    cfg = tiny_model.config     # float32 pages of 8 rows
+    monkeypatch.setattr(PA, "BLOCK_BYTES", 4 * (
+        cfg.num_key_value_heads * 8 * cfg.head_dim * 4))
     eng = create_engine(tiny_model, max_slots=2, page_size=8,
                         max_model_len=96)
     keys = ("paged_blocks_live", "paged_blocks_grid")
@@ -607,7 +609,9 @@ def test_paged_block_counters_follow_the_familys_kernel(tiny_model,
     from paddle_tpu.ops.pallas import mla_paged_attention as MLA
     from paddle_tpu.ops.pallas import paged_attention as PA
     from paddle_tpu.serving.engine import Engine
-    monkeypatch.setattr(PA, "BLOCK_TOKENS", 16)     # 2 pages, 8 blocks a row
+    cfg = tiny_model.config     # the K/V rule goes by a page's bytes
+    page = cfg.num_key_value_heads * 8 * cfg.head_dim * 4   # float32
+    monkeypatch.setattr(PA, "BLOCK_BYTES", 2 * page)  # 2 pages, 8 blocks a row
     monkeypatch.setattr(MLA, "BLOCK_TOKENS", 64)    # 8 pages, 2 blocks a row
     kw = dict(max_slots=3, page_size=8, max_model_len=128)
     if family == "latent":
