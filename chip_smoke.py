@@ -445,12 +445,12 @@ def train_phase(cfg, devices, *, seed, batch=32, seq=384, max_steps=10,
             raise RuntimeError(f"{name} is on {p.devices()}")
     bufs = {"buffers." + k: b._data for k, b in model.named_buffers()}
     kernels = kernels_in(step._compiled, (
-        params, bufs, o.opt_state(), jax.random.key(0), ids._data,
-        y._data))
+        params, bufs, o.opt_state(), jax.random.key(0),
+        np.float32(o.get_lr()), ids._data, y._data))
     return {"phase": "train", "layers": cfg.num_hidden_layers,
             "hidden": cfg.hidden_size, "batch": batch, "seq": seq,
             "seconds": round(time.perf_counter() - t0, 2),
-            # steps 1 and 2 compile (the optimizer state appears at 1)
+            # step 1 compiles (the optimizer state is made before it)
             "step_s": step_s, "losses": losses, "kernels": kernels,
             "params_on": str(devices[0]),
             "device_bytes": device_bytes(devices)}

@@ -8,6 +8,9 @@ optimizer.py:317) and write back in the param dtype.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
+
 import jax.numpy as jnp
 import numpy as np
 
@@ -16,8 +19,25 @@ from ..autograd import no_grad
 
 __all__ = ["Optimizer"]
 
+# The learning rate a compiled step hands in as a traced scalar: get_lr()
+# inside that trace reads it, so one program follows a scheduler (or
+# set_lr) from call to call instead of keeping its trace's value.
+_TRACED_LR = contextvars.ContextVar("traced_lr", default=None)
+
+
+@contextlib.contextmanager
+def traced_lr(lr):
+    """``get_lr()`` of every optimizer returns ``lr`` inside the block."""
+    token = _TRACED_LR.set(lr)
+    try:
+        yield
+    finally:
+        _TRACED_LR.reset(token)
+
 
 class Optimizer:
+    _creating_state = False     # create_state's pass: slots keep their init
+
     def __init__(self, learning_rate=0.001, parameters=None, weight_decay=None,
                  grad_clip=None, multi_precision=True, name=None):
         from .lr import LRScheduler
@@ -55,6 +75,9 @@ class Optimizer:
 
     # ------------------------------------------------------------ lr
     def get_lr(self):
+        lr = _TRACED_LR.get()
+        if lr is not None:
+            return lr
         if self._lr_scheduler is not None:
             return self._lr_scheduler()
         return float(self._lr)
@@ -84,7 +107,81 @@ class Optimizer:
         return slot[name]
 
     def _set_acc(self, p, name, value):
+        if self._creating_state:
+            return
         self._accumulators[self._param_key(p)][name] = value
+
+    def create_state(self):
+        """Create every accumulator and master weight that the next update
+        of the trainable parameters would create, with the values that
+        update would give them (``_acc``'s init, ``_get_master``'s cast);
+        state already held (loaded, resumed) is kept.
+
+        One jitted pass runs ``_update_param`` on zero gradients with
+        ``_set_acc`` and ``_write_back`` held off, so every slot keeps
+        the value its creation gave it; the update arithmetic is traced,
+        and dropped from the program (nothing reads it).  The pass is
+        traced once an optimizer, and skipped where the state already has
+        the layout it left.  A compiled step whose optimizer state is
+        whole before its first trace is handed the same pytree on every
+        call, and so holds one program."""
+        import jax
+
+        if type(self)._update_param is Optimizer._update_param:
+            return              # wrappers and closure optimizers: no slots
+        datas = {self._param_key(p): p._data for p in self._parameter_list
+                 if not p.stop_gradient}
+        held = (self._accumulators, self._master_weights)
+        whole = self.__dict__.get("_whole_layout")
+        if not datas or (whole is not None
+                         and jax.tree.structure(held) == whole):
+            return
+        create = self.__dict__.get("_create_state_jit")
+        if create is None:
+            create = self._create_state_jit = jax.jit(self._created)
+        accs, masters = create(datas, *held, np.float32(self.get_lr()))
+        for k, slots in accs.items():
+            for name, a in slots.items():
+                self._accumulators.setdefault(k, {}).setdefault(name, a)
+        for k, m in masters.items():
+            self._master_weights.setdefault(k, m)
+        self._whole_layout = jax.tree.structure(held)
+
+    def _created(self, datas, accs, masters, lr):
+        """``create_state``'s pass: the state after one update that
+        changed nothing but what it created."""
+        params = [p for p in self._parameter_list
+                  if self._param_key(p) in datas]
+        with self._replaying(params, [datas[self._param_key(p)]
+                                      for p in params], accs, masters, 0):
+            self._creating_state = True
+            try:
+                for p in params:
+                    self._update_param(
+                        p, jnp.zeros(p._data.shape, jnp.float32), lr)
+            finally:
+                self._creating_state = False
+            return self._accumulators, self._master_weights
+
+    @contextlib.contextmanager
+    def _replaying(self, params, datas, accs, masters, steps):
+        """Swap ``datas`` into ``params`` and the given state into the
+        optimizer for a replay of ``_update_param`` under a trace; put
+        back what was there after."""
+        saved = ([p._data for p in params], self._accumulators,
+                 self._master_weights, self._step_count)
+        try:
+            for p, d in zip(params, datas):
+                p._data = d
+            self._accumulators = {k: dict(v) for k, v in accs.items()}
+            self._master_weights = dict(masters)
+            self._step_count = steps
+            yield
+        finally:
+            (kept, self._accumulators, self._master_weights,
+             self._step_count) = saved
+            for p, d in zip(params, kept):
+                p._data = d
 
     # ------------------------------------------------------------ step
     @no_grad()
@@ -163,15 +260,7 @@ class Optimizer:
             opt = self
 
             def run(pvals, gvals, accs, masters, lr_arr, prev_steps):
-                saved = ([p._data for p in ps], opt._accumulators,
-                         opt._master_weights, opt._step_count)
-                try:
-                    for p, pv in zip(ps, pvals):
-                        p._data = pv
-                    opt._accumulators = {k: dict(v)
-                                         for k, v in accs.items()}
-                    opt._master_weights = dict(masters)
-                    opt._step_count = prev_steps
+                with opt._replaying(ps, pvals, accs, masters, prev_steps):
                     for p, g in zip(ps, gvals):
                         g32 = g.astype(jnp.float32)
                         if opt._l1_coeff:
@@ -184,12 +273,6 @@ class Optimizer:
                     new_masters = {k: opt._master_weights[k] for k in keys
                                    if k in opt._master_weights}
                     return new_p, new_accs, new_masters
-                finally:
-                    (pd, opt._accumulators, opt._master_weights,
-                     opt._step_count) = saved[0], saved[1], saved[2], \
-                        saved[3]
-                    for p, pv in zip(ps, pd):
-                        p._data = pv
 
             fn = jax.jit(run)
         try:
@@ -235,6 +318,8 @@ class Optimizer:
         self._update_param(p, g.to_dense(), lr)
 
     def _write_back(self, p, new_f32):
+        if self._creating_state:
+            return
         key = self._param_key(p)
         if key in self._master_weights:
             self._master_weights[key] = new_f32

@@ -172,7 +172,7 @@ class Rprop(Optimizer):
         x = self._param_f32(p)
         prev = self._acc(p, "prev")
         lrs = self._acc(p, "learning_rate",
-                        jnp.full(p._data.shape, float(lr), jnp.float32))
+                        jnp.full(p._data.shape, lr, jnp.float32))
         sign = g * prev
         eta = jnp.where(sign > 0, self._eta_pos,
                         jnp.where(sign < 0, self._eta_neg, 1.0))
