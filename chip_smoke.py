@@ -4,6 +4,7 @@
     python chip_smoke.py --four-chips  # four chips: the sharded paths only
     python chip_smoke.py --ssm-update [G]  # one chip: the state-update kernel
     python chip_smoke.py --grouped-matmul 16,2688,1856   # the experts' kernel
+    python chip_smoke.py --granite-moe # the Granite 4.0-H Small cell's kernels
     python chip_smoke.py --mla-decode  # one chip: the latent decode kernel
     python chip_smoke.py --paged-decode [CELL]  # the K/V paged decode kernel
 
@@ -46,6 +47,14 @@ dense-gather XLA form at the decode step's and a prefill's row tiles, with
 an expert that no row chose and tiles past the live ones, and times the
 decode call against the bytes of the experts it reads.
 
+``--granite-moe`` runs both kernels at the Granite 4.0-H Small cell's
+shapes: the state-update check at 128 heads (H x P = 8,192, bfloat16),
+the grouped matmul's check over 36 experts' gate | up ``[4096, 1536]``
+and down ``[768, 4096]`` with 320 pairs at the decode tile of 16 (8.9 rows
+an expert: some span two row tiles), and gate | up computed as one call
+1,536 wide against two calls 768 wide over the same buffer
+(``gate_up_phase``), each timed.
+
 ``--mla-decode`` runs ``ops/pallas/mla_paged_attention.py``'s kernel at
 the GigaChat cell's shape (64 slots, 64 heads over latents of 512 + 64,
 pages of 16 rows, 256 table columns, 5 layers in one pool) against its
@@ -54,7 +63,7 @@ at a mix with an empty slot, the largest gap printed; then times it, call
 after call inside one program, at each block size swept.
 
 ``--paged-decode [CELL]`` runs ``ops/pallas/paged_attention.py``'s kernel
-at the three paged cells' shapes (``PAGED_CELLS``; one of them where it is
+at the four paged cells' shapes (``PAGED_CELLS``; one of them where it is
 named) against ``paged_attention_xla`` at each context and at a mix with
 an empty slot, at each block (tokens a grid step) and round (rows a round
 of the softmax) swept, the largest gap printed; then times it, call after
@@ -709,6 +718,81 @@ def grouped_matmul_phase(*, seed, experts=16, k=2688, n=1856,
     return out
 
 
+def gate_up_phase(*, seed, experts=36, k=4096, f=768, pairs=320, tile=16,
+                  reps=50, dtype="bfloat16") -> dict:
+    """The gated experts' first product two ways over one sorted buffer:
+    one ``grouped_matmul`` over the published stack ``[experts, k, 2 f]``
+    (gate | up) and two over ``[experts, k, f]`` stacks; both against
+    the dense-gather form, then each timed.  Reports the live row tiles
+    beside the experts touched: an expert whose rows fill two tiles has
+    its weights read twice."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import grouped_ffn as GF
+    t0 = time.perf_counter()
+    rng = np.random.default_rng(seed)
+    w = jnp.asarray(rng.normal(size=(experts, k, 2 * f)) / np.sqrt(k),
+                    jnp.float32).astype(dtype)
+    halves = (w[:, :, :f], w[:, :, f:])
+    rows = -(-pairs // tile) * tile + experts * tile
+    share = rng.multinomial(pairs, [1.0 / experts] * experts)
+    emap = np.concatenate([np.full(-(-c // tile), e) for e, c
+                           in enumerate(share)]).astype(np.int32)
+    live = len(emap)
+    emap = np.pad(emap, (0, rows // tile - live), constant_values=0)
+    x = jnp.asarray(rng.normal(size=(rows, k)), jnp.float32).astype(dtype)
+    args = (x, jnp.asarray(emap), jnp.int32(live))
+    one = jax.jit(lambda x, w, e, n: GF.grouped_matmul(x, w, e, n,
+                                                       tile_m=tile))
+    two = jax.jit(lambda x, g, u, e, n: jnp.concatenate([
+        GF.grouped_matmul(x, g, e, n, tile_m=tile),
+        GF.grouped_matmul(x, u, e, n, tile_m=tile)], axis=-1))
+    twin = jax.jit(lambda x, w, e, n: GF.grouped_matmul_xla(x, w, e, n,
+                                                            tile_m=tile))
+    want = np.asarray(twin(x, w, *args[1:]).astype(jnp.float32))[
+        :live * tile]
+    touched = int((share > 0).sum())
+    out = {"phase": "gate_up", "shape": [experts, k, 2 * f],
+           "blocks": {"one_call": list(GF.expert_blocks(
+               k, 2 * f, w.dtype.itemsize)), "two_calls": list(
+               GF.expert_blocks(k, f, w.dtype.itemsize))},
+           "rows": rows, "pairs": pairs, "live_tiles": live,
+           "experts_touched": touched,
+           "tiles_per_touched_expert": round(live / touched, 4),
+           "rows_max": int(share.max())}
+    for name, fn, wargs in (("one_call", one, (w,)),
+                            ("two_calls", two, halves)):
+        got = np.asarray(fn(x, *wargs, *args[1:]).astype(jnp.float32))[
+            :live * tile]
+        gap = float(np.max(np.abs(got - want) / (1.0 + np.abs(want))))
+        if not gap <= 2.0 ** -7:
+            raise RuntimeError(f"gate | up as {name} differs from its XLA "
+                               f"form: {gap}")
+        jax.block_until_ready(fn(x, *wargs, *args[1:]))
+        t1 = time.perf_counter()
+        for _ in range(reps):
+            y = fn(x, *wargs, *args[1:])
+        jax.block_until_ready(y)
+        ms = (time.perf_counter() - t1) * 1e3 / reps
+        out[name] = {"gap": gap, "ms": round(ms, 4), "weights_gb_per_s":
+                     round(touched * k * 2 * f * w.dtype.itemsize
+                           / ms / 1e6, 1)}
+    out["seconds"] = round(time.perf_counter() - t0, 2)
+    return out
+
+
+def granite_moe_phases(*, seed) -> list:
+    """The kernels at the Granite 4.0-H Small cell's shapes."""
+    tiles = ((16, 320), (128, 2560))
+    return [ssm_update_phase(seed=seed, hp=8192, dtypes=("bfloat16",),
+                             lane_blocks=(2048, 4096, 8192)),
+            grouped_matmul_phase(seed=seed, experts=36, k=4096, n=1536,
+                                 tiles=tiles),
+            grouped_matmul_phase(seed=seed, experts=36, k=768, n=4096,
+                                 tiles=tiles),
+            gate_up_phase(seed=seed)]
+
+
 # ------------------------------------------------- the decode kernels' cases
 def decode_cases(rng, *, slots, width, page, contexts) -> tuple:
     """What both paged decode kernels' checks run over: every slot at
@@ -838,6 +922,8 @@ PAGED_CELLS = {
                     contexts=(1, 300, 1100, 4032)),
     "nemotron": dict(slots=64, kvh=2, rep=16, width=256, layers=6,
                      contexts=(1, 300, 1100, 4032)),
+    "granite_small": dict(slots=64, kvh=8, rep=4, width=256, layers=1,
+                          contexts=(1, 300, 1100, 4032)),
 }
 
 # As MLA_TOL: both forms round the softmax's weights to bfloat16 before
@@ -944,6 +1030,10 @@ def main(argv=None) -> int:
     ap.add_argument("--grouped-matmul", metavar="E,K,N",
                     help="run only the experts' grouped matmul's check, "
                          "over E matrices [K, N]")
+    ap.add_argument("--granite-moe", action="store_true",
+                    help="run only the state-update and the experts' "
+                         "kernels' checks at the Granite 4.0-H Small "
+                         "cell's shapes")
     ap.add_argument("--mla-decode", action="store_true",
                     help="run only the latent decode kernel's check")
     ap.add_argument("--paged-decode", nargs="?", const="all",
@@ -966,6 +1056,10 @@ def main(argv=None) -> int:
     if args.grouped_matmul:
         e, k, n = (int(x) for x in args.grouped_matmul.split(","))
         say(**grouped_matmul_phase(seed=SEED, experts=e, k=k, n=n))
+        return _ok(devices)
+    if args.granite_moe:
+        for found in granite_moe_phases(seed=SEED):
+            say(**found)
         return _ok(devices)
     if args.mla_decode:
         say(**mla_decode_phase(seed=SEED))

@@ -50,6 +50,10 @@ HI = jax.lax.Precision.HIGHEST
 # rows a decode step and some tens of a prefill
 DECODE_TILE, PREFILL_TILE = 16, 128
 MOE_COUNTERS = ("moe_routed_pairs", "moe_local_pairs", "moe_experts_live")
+# the live row tiles of the sorted buffer, counted where a description
+# names it among its ``counters``: a touched expert's weights are fetched
+# once a row tile, so this over ``moe_experts_live`` is how many times
+MOE_ROW_TILES = "moe_row_tiles_live"
 
 
 def held_range(local_experts, n_routed: int) -> tuple:
@@ -255,14 +259,22 @@ def _rope(x, cos, sin):
 
 
 # ------------------------------------------------------------------- router
-def route(cfg: DeepseekV3Config, x, w_gate, bias):
+def route(cfg, x, w_gate, bias):
     """x [T, H] -> (experts [T, K] int32, weights [T, K] float32), over
-    all ``n_routed_experts``, in float32.  The bias moves the choice,
-    never the weight."""
+    all ``n_routed_experts``, in float32, by the description's
+    ``scoring_func``: "sigmoid" (where it does not say; DeepSeek-V3's
+    bias-corrected sigmoid in groups: the bias moves the choice, never
+    the weight) or "softmax_topk" (Granite's: the top ``num_experts_per_tok``
+    logits, a softmax over those alone, no bias, no groups, no scale)."""
+    logits = jnp.dot(x.astype(jnp.float32), w_gate.astype(jnp.float32),
+                     precision=HI)
+    if getattr(cfg, "scoring_func", "sigmoid") == "softmax_topk":
+        top, idx = jax.lax.top_k(logits, cfg.num_experts_per_tok)
+        gates = jax.nn.softmax(top, axis=-1).astype(x.dtype)
+        return idx.astype(jnp.int32), gates.astype(jnp.float32)
     t = x.shape[0]
     e, g = cfg.n_routed_experts, cfg.n_group
-    sc = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
-                                w_gate.astype(jnp.float32), precision=HI))
+    sc = jax.nn.sigmoid(logits)
     ch = sc + bias.astype(jnp.float32)[None, :]
     grouped = ch.reshape(t, g, e // g)
     group_score = jax.lax.top_k(grouped, 2)[0].sum(axis=-1)     # [T, g]
@@ -284,8 +296,8 @@ def _sorted_rows(cfg: DeepseekV3Config, idx, valid, tile: int):
 
     Returns (row_pair [R]: the pair a buffer row holds, or T*K for a
     padding row; pair_row [T*K]: the buffer row of a pair, or R where
-    the pair is not computed here; emap [R/tile]; n_live; sizes
-    [held]: rows per held expert)."""
+    the pair is not computed here; emap [R/tile]; n_live: the live row
+    tiles; sizes [held]: rows per held expert)."""
     first, held = cfg.local_experts
     t, k = idx.shape
     n = t * k
@@ -334,21 +346,29 @@ def routed_experts(cfg, w: dict, x, valid, tile: int):
     """Σ over the chosen experts this chip holds of ``w_e . expert_e(x)``
     for x [T, H], an expert being ``down_e(silu(gate_e x) . up_e x)`` or
     ``down_e(relu(up_e x)^2)`` as the description says (``_gated``);
-    tokens where ``valid`` is false choose nothing.  Dropless: every
+    gate and up are two stacks, or one published stack ``e_gateup``
+    [held, H, 2F] (gate | up) computed by one grouped product 2F wide.
+    Tokens where ``valid`` is false choose nothing.  Dropless: every
     pair of a held expert is computed.  Returns (y [T, H] float32,
-    counts [3]: ``MOE_COUNTERS``)."""
+    counts: ``MOE_COUNTERS``, and ``MOE_ROW_TILES`` after them where the
+    description's ``counters`` name it)."""
     from ..ops.pallas.grouped_ffn import select_grouped_matmul
     t = x.shape[0]
     k = cfg.num_experts_per_tok
     with jax.named_scope("moe.route"):
-        idx, wts = route(cfg, x, w["router"], w["router_bias"])
+        idx, wts = route(cfg, x, w["router"], w.get("router_bias"))
         row_pair, pair_row, emap, n_live, sizes = _sorted_rows(
             cfg, idx, valid, tile)
         x_buf = jnp.take(x, row_pair // k, axis=0, mode="fill",
                          fill_value=0)
     with jax.named_scope("moe.experts"):
         gmm = select_grouped_matmul()
-        if _gated(cfg):
+        if "e_gateup" in w:
+            gu = gmm(x_buf, w["e_gateup"], emap, n_live, tile_m=tile)
+            f = gu.shape[-1] // 2
+            act = (jax.nn.silu(gu[:, :f].astype(jnp.float32))
+                   * gu[:, f:].astype(jnp.float32)).astype(x.dtype)
+        elif _gated(cfg):
             g = gmm(x_buf, w["e_gate"], emap, n_live, tile_m=tile)
             u = gmm(x_buf, w["e_up"], emap, n_live, tile_m=tile)
             act = (jax.nn.silu(g.astype(jnp.float32))
@@ -362,10 +382,11 @@ def routed_experts(cfg, w: dict, x, valid, tile: int):
         y = jnp.take(y_buf, pair_row, axis=0, mode="fill", fill_value=0)
         y = jnp.sum(y.reshape(t, k, -1).astype(jnp.float32)
                     * wts[:, :, None], axis=1)
-    counts = jnp.stack([jnp.sum(valid.astype(jnp.int32)) * k,
-                        jnp.sum(sizes), jnp.sum((sizes > 0).astype(
-                            jnp.int32))]).astype(jnp.int32)
-    return y, counts
+    counts = [jnp.sum(valid.astype(jnp.int32)) * k, jnp.sum(sizes),
+              jnp.sum((sizes > 0).astype(jnp.int32))]
+    if MOE_ROW_TILES in getattr(cfg, "counters", ()):
+        counts.append(n_live)
+    return y, jnp.stack(counts).astype(jnp.int32)
 
 
 def _swiglu(x, gate, up, down):
@@ -375,7 +396,7 @@ def _swiglu(x, gate, up, down):
 def expert_ffn(cfg, w: dict, x, valid, tile: int):
     """The expert layer's feed-forward part on x [T, H] (normed): the
     held routed experts' terms and the shared expert, whole.  Returns
-    (y [T, H], MoE counts [3])."""
+    (y [T, H], MoE counts)."""
     routed, counts = routed_experts(cfg, w, x, valid, tile)
     with jax.named_scope("moe.shared"):
         if _gated(cfg):

@@ -1,7 +1,7 @@
 """The ``granitemoehybrid`` decoder family for the serving path: Mamba-2
 (state-space) layers beside GQA attention layers, a shared SwiGLU MLP in
-every layer, Granite's four multipliers, no position encoding, a tied
-head.
+every layer and, where the model has them, routed SwiGLU experts beside
+it, Granite's four multipliers, no position encoding, a tied head.
 
 Reference: the published ``modeling_granitemoehybrid`` (IBM Granite 4.0
 "H" models); the mixer is Mamba-2 (Dao & Gu, arXiv:2405.21060).  Every
@@ -11,6 +11,16 @@ layer, with ``rm = residual_multiplier``::
     h      = h + rm * Mixer(RMSNorm(h))        # Mamba-2 or attention
     h      = h + rm * MLP(RMSNorm(h))          # down(silu(gate) * up)
     logits = (RMSNorm(h) @ embed^T) / logits_scaling
+
+or, in a model with routed experts (``num_local_experts`` > 0: H-small,
+72 experts, 10 a token), with ``u = RMSNorm(h)``::
+
+    h      = h + rm * (Experts(u) + MLP(u))
+    Experts(u) = sum_{e in top_k(l)} softmax(l[top_k])_e * down_e(silu(gate_e u) * up_e u)
+
+the router's logits ``l = u @ W_router`` in float32 with no bias, the
+softmax over the ``num_experts_per_tok`` chosen logits alone, the gates
+in the served dtype (the published ``GraniteMoeHybridTopKGating``).
 
 What the serving runner needs of a family is here: the description
 (:class:`GraniteHybridConfig`), the names and shapes of the weights
@@ -45,26 +55,38 @@ dim, whatever ``mamba_expand`` says of the hidden size.
 A description says what its blocks are made of (``blocks``: for each
 block the parts it runs in order, each with its own norm and residual
 add).  Here every block is a mixer and then the shared MLP:
-``("mamba", "mlp")`` or ``("attention", "mlp")``.
+``("mamba", "mlp")`` or ``("attention", "mlp")``; with routed experts the
+second part is ``"moe"``, the experts and the shared MLP under one norm
+and one residual add (``expert_block``).  The expert part routes over all
+``num_local_experts`` and computes the terms of the experts this chip
+holds (``local_experts``: first, count), dropless; the others belong to
+other chips and are left out (``deepseek_v3.routed_experts``).  The held
+experts are the published stacks, ``[in, out]``:
+``block_sparse_moe.input_linear.weight`` ``[held, hidden, 2 F]`` (gate |
+up) and ``output_linear.weight`` ``[held, F, hidden]``.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import InitVar, dataclass, field
 
 import jax
 import jax.numpy as jnp
 
 from ..ops.pallas.ssm_update import select_ssm_state_update
-from .generation import _mm, residual_add
+from .deepseek_v3 import (MOE_COUNTERS, MOE_ROW_TILES, held_range,
+                          routed_experts)
+from .generation import _ffn, _mm, residual_add
 from .llama_hybrid import _rms
 
 __all__ = ["GraniteHybridConfig", "weight_shapes", "layer_weights",
            "mamba_prefill", "mamba_decode", "mamba_prefill_block",
-           "mamba_decode_block", "state_shapes", "COUNTERS"]
+           "mamba_decode_block", "expert_block", "state_shapes",
+           "COUNTERS"]
 
 HI = jax.lax.Precision.HIGHEST
 EMBED = "model.embed_tokens.weight"
 COUNTERS = ("ssm_rows_live",)
+MOE = "model.layers.{}.block_sparse_moe."
 
 
 class RecurrentDescription:
@@ -98,9 +120,17 @@ class RecurrentDescription:
 
 @dataclass
 class GraniteHybridConfig(RecurrentDescription):
+    """The published keys under the names the bodies read
+    (:meth:`from_published` maps them).  ``intermediate_size`` is taken
+    as a constructor keyword for the shared MLP's width, the name older
+    calls gave it (the Granite-micro cell's driver; the keyword can go
+    once that call names ``shared_intermediate_size``), and is no
+    attribute: the published ``intermediate_size`` is one routed expert's
+    width (``expert_intermediate_size``)."""
     vocab_size: int = 100352
     hidden_size: int = 2048
-    intermediate_size: int = 8192       # shared_intermediate_size
+    # the shared MLP's width; None: 8192, Granite-micro's
+    shared_intermediate_size: int | None = None
     num_hidden_layers: int = 40
     # "mamba" or "attention" a layer; None: attention at 5, 15, 25, ...
     layer_types: tuple | None = None
@@ -123,10 +153,28 @@ class GraniteHybridConfig(RecurrentDescription):
     rms_norm_eps: float = 1e-5
     max_position_embeddings: int = 131072
     tie_word_embeddings: bool = True
+    # routed experts: how many the router scores (0: H-micro's dense
+    # layer), how many a token takes, one expert's width
+    num_local_experts: int = 0
+    num_experts_per_tok: int = 0
+    expert_intermediate_size: int = 0
+    # (first, count): the routed experts this chip holds; None = all
+    local_experts: tuple | None = None
     dtype: str = "bfloat16"
     family: str = field(default="granitemoehybrid", init=False)
+    # the router's rule (``deepseek_v3.route``)
+    scoring_func: str = field(default="softmax_topk", init=False)
+    intermediate_size: InitVar[int | None] = None
 
-    def __post_init__(self):
+    def __post_init__(self, intermediate_size):
+        if intermediate_size is not None:
+            if self.shared_intermediate_size is not None:
+                raise ValueError(
+                    "the shared MLP's width is given twice: name it "
+                    "shared_intermediate_size alone")
+            self.shared_intermediate_size = intermediate_size
+        elif self.shared_intermediate_size is None:
+            self.shared_intermediate_size = 8192
         if self.layer_types is None:
             self.layer_types = tuple(
                 "attention" if i % 10 == 5 else "mamba"
@@ -146,6 +194,47 @@ class GraniteHybridConfig(RecurrentDescription):
                     f"for the granitemoehybrid family (only {want!r})")
         if self.mamba_n_heads % self.mamba_n_groups:
             raise ValueError("mamba_n_groups must divide mamba_n_heads")
+        if self.num_local_experts:
+            if not (0 < self.num_experts_per_tok <= self.num_local_experts
+                    and self.expert_intermediate_size > 0):
+                raise ValueError(
+                    "routed experts need 0 < num_experts_per_tok <= "
+                    "num_local_experts and an expert_intermediate_size")
+            self.local_experts = held_range(self.local_experts,
+                                            self.num_local_experts)
+        elif self.local_experts is not None:
+            raise ValueError("local_experts names experts of a model "
+                             "without routed experts")
+
+    @classmethod
+    def from_published(cls, m: dict, **kw):
+        """The description of a published ``config.json`` (``m``);
+        ``kw``: what the source does not say (``local_experts``,
+        ``dtype``)."""
+        for name, want in (("model_type", "granitemoehybrid"),
+                           ("hidden_act", "silu"),
+                           ("normalization_function", "rmsnorm"),
+                           ("attention_bias", False)):
+            if m[name] != want:
+                raise ValueError(
+                    f"{name}={m[name]!r} is not implemented for the "
+                    f"granitemoehybrid family (only {want!r})")
+        experts = int(m["num_local_experts"])
+        return cls(
+            shared_intermediate_size=m["shared_intermediate_size"],
+            expert_intermediate_size=m["intermediate_size"] if experts else 0,
+            layer_types=tuple(m["layer_types"]),
+            **{k: m[k] for k in (
+                "vocab_size", "hidden_size", "num_hidden_layers",
+                "num_attention_heads", "num_key_value_heads",
+                "mamba_n_heads", "mamba_d_head", "mamba_d_state",
+                "mamba_n_groups", "mamba_d_conv", "mamba_expand",
+                "mamba_chunk_size", "mamba_conv_bias", "mamba_proj_bias",
+                "embedding_multiplier", "attention_multiplier",
+                "residual_multiplier", "logits_scaling",
+                "position_embedding_type", "rms_norm_eps",
+                "max_position_embeddings", "tie_word_embeddings",
+                "num_local_experts", "num_experts_per_tok")}, **kw)
 
     @property
     def head_dim(self) -> int:
@@ -153,10 +242,23 @@ class GraniteHybridConfig(RecurrentDescription):
 
     @property
     def blocks(self) -> tuple:
-        """The parts each block runs, in order: a mixer, then the MLP."""
-        return tuple((kind, "mlp") for kind in self.layer_types)
+        """The parts each block runs, in order: a mixer, then the MLP, or
+        the experts and the MLP where the model has routed experts."""
+        second = "moe" if self.num_local_experts else "mlp"
+        return tuple((kind, second) for kind in self.layer_types)
+
+    @property
+    def counters(self) -> tuple:
+        """What the decode step counts on the device."""
+        if not self.num_local_experts:
+            return COUNTERS
+        return COUNTERS + MOE_COUNTERS + (MOE_ROW_TILES,)
 
 
+# the width's older name is a constructor keyword alone: without this the
+# InitVar's default would answer ``cfg.intermediate_size`` with None (so
+# ``dataclasses.replace`` wants ``intermediate_size=None`` named)
+del GraniteHybridConfig.intermediate_size
 
 def kv_pack(cfg) -> int:
     """KV heads that share one row of the K/V pools: a head dim under
@@ -182,7 +284,7 @@ def state_shapes(cfg, slots: int) -> dict:
 def weight_shapes(cfg: GraniteHybridConfig) -> dict:
     """{name: shape} of every leaf the serving state holds: one
     embedding matrix (the head is its transpose) and no ``lm_head``."""
-    h, i = cfg.hidden_size, cfg.intermediate_size
+    h, i = cfg.hidden_size, cfg.shared_intermediate_size
     q = cfg.num_attention_heads * cfg.head_dim
     kv = cfg.num_key_value_heads * cfg.head_dim
     out = {EMBED: (cfg.vocab_size, h), "model.norm.weight": (h,)}
@@ -193,6 +295,13 @@ def weight_shapes(cfg: GraniteHybridConfig) -> dict:
             p + "post_attention_layernorm.weight": (h,),
             p + "shared_mlp.input_linear.weight": (h, 2 * i),
             p + "shared_mlp.output_linear.weight": (i, h)})
+        if cfg.num_local_experts:
+            e, f = MOE.format(n), cfg.expert_intermediate_size
+            held = cfg.local_experts[1]
+            out.update({
+                e + "router.layer.weight": (h, cfg.num_local_experts),
+                e + "input_linear.weight": (held, h, 2 * f),
+                e + "output_linear.weight": (held, f, h)})
         if kind == "attention":
             a = p + "self_attn."
             out.update({a + "q_proj.weight": (h, q),
@@ -223,6 +332,11 @@ def layer_weights(state: dict, cfg: GraniteHybridConfig, i: int) -> dict:
          "ln2": state[p + "post_attention_layernorm.weight"],
          "gateup": state[p + "shared_mlp.input_linear.weight"],
          "down": state[p + "shared_mlp.output_linear.weight"]}
+    if cfg.num_local_experts:
+        e = MOE.format(i)
+        w.update(router=state[e + "router.layer.weight"],
+                 e_gateup=state[e + "input_linear.weight"],
+                 e_down=state[e + "output_linear.weight"])
     if cfg.layer_types[i] == "attention":
         a = p + "self_attn."
         w.update({k: state[a + k + "_proj.weight"] for k in "qkvo"})
@@ -416,3 +530,15 @@ def mamba_decode_block(cfg, w, lm, x, ssm, conv, active):
     h = _rms(x[:, None], w["ln1"], cfg.rms_norm_eps)[:, 0]
     out, ssm, conv = mamba_decode(cfg, w, h, ssm, conv, lm, active)
     return residual_add(x, out, cfg), ssm, conv
+
+
+def expert_block(cfg, w, x, valid, tile: int):
+    """x [T, hidden] -> (x + rm * (Experts(u) + MLP(u)), MoE counts):
+    ``u = RMSNorm_2(x)``; the held experts' terms and the shared MLP,
+    whole, under the block's second norm and one residual add; tokens
+    where ``valid`` [T] is false choose no expert."""
+    h = _rms(x[:, None], w["ln2"], cfg.rms_norm_eps)[:, 0]
+    routed, counts = routed_experts(cfg, w, h, valid, tile)
+    with jax.named_scope("moe.shared"):
+        y = (_ffn(w, h).astype(jnp.float32) + routed).astype(h.dtype)
+    return residual_add(x, y, cfg), counts

@@ -174,6 +174,11 @@ class NemotronHConfig(RecurrentDescription):
         """The parts each block runs, in order: its one part."""
         return tuple((kind,) for kind in self.layer_types)
 
+    @property
+    def counters(self) -> tuple:
+        """What the decode step counts on the device."""
+        return COUNTERS
+
 
 # ------------------------------------------------------------------ weights
 def weight_shapes(cfg: NemotronHConfig) -> dict:

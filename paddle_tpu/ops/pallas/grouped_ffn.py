@@ -24,9 +24,14 @@ whole ``[D, 2F]`` block a grid step, which at expert widths of
 runs each of an expert's three matrix products as one grouped matmul
 tiled over the contraction and the output columns (``expert_blocks``),
 over the same tile-aligned sorted rows.  The row tile is small at decode
-(``tile_m`` 16: an expert sees 0-8 rows a step) and the kernel is told
-how many row tiles are live: a tile past them computes nothing and asks
-for no new block, so an expert that no row chose is never read.
+(``tile_m`` 16: an expert sees a few rows a step, 2 to 9 on average in
+the benchmark's cells) and the kernel is told how many row tiles are
+live: a tile past them computes nothing and asks for no new block, so an
+expert that no row chose is never read.  An expert whose rows fill more
+than one tile has its weights fetched once a tile; the serving step
+counts the live tiles (``moe_row_tiles_live``), and that over the
+touched experts (``moe_experts_live``) is how many times a touched
+expert's weights are read.
 """
 from __future__ import annotations
 

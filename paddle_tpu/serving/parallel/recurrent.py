@@ -10,8 +10,9 @@ same two builders, which take the blocks from the description
 (``config.blocks``: for each block the parts it runs, each with its own
 norm and residual add) and the parts from where every family takes
 them: ``granite_hybrid.mamba_*_block``, ``generation.*_attention`` and
-``mlp_block``, ``nemotron_h.expert_block`` over ``deepseek_v3``'s router
-and grouped experts.  The same seam and the same argument positions as
+``mlp_block``, and the family's own ``expert_block`` (its norm, its
+shared MLP or expert) over ``deepseek_v3``'s router and grouped
+experts.  The same seam and the same argument positions as
 the key/value programs, so the engine, the ring and ``push_slot`` do not
 know the difference, and two kinds of state in one program:
 
@@ -33,10 +34,10 @@ argument that is the empty tuple for the other families (no leaves: their
 programs are unchanged).  Nothing is sliced out of a pool that feeds a
 Pallas call or stacked again.  What a recurrent state cannot do yet is
 refused by name when the engine is built (``check_options``).  The decode
-step counts on the device what the family's description names
-(``COUNTERS``: the slots its Mamba blocks updated, and for a family with
-expert blocks the ``moe_*`` counters of ``deepseek_v3``), which
-``engine.stats()`` fetches on demand.
+step counts on the device what the model's description names
+(``config.counters``: the slots its Mamba blocks updated, and for a
+model with expert blocks the ``moe_*`` counters of ``deepseek_v3``),
+which ``engine.stats()`` fetches on demand.
 """
 from __future__ import annotations
 
@@ -53,7 +54,8 @@ from ...models.generation import (decode_attention, mlp_block,
 from ...models.llama_hybrid import _rms
 from ...ops.pallas.paged_attention import PagedKV
 
-# family -> the module that describes it: EMBED, COUNTERS, layer_weights
+# family -> the module that describes it: EMBED, layer_weights,
+# expert_block
 FAMILIES = {"granitemoehybrid": gh, "nemotron_h": nh}
 WHY_NOT = {
     "mesh": "its programs are single-chip (the state pools are not "
@@ -111,21 +113,20 @@ def state_bytes(cfg, slots: int) -> int:
 
 
 def counters0(cfg):
-    return jnp.zeros((len(FAMILIES[cfg.family].COUNTERS),), jnp.int32)
+    return jnp.zeros((len(cfg.counters),), jnp.int32)
 
 
 def counters_by_name(cfg, counters) -> dict:
     """The device's counters as {name: int}: a device fetch."""
-    return dict(zip(FAMILIES[cfg.family].COUNTERS,
-                    (int(v) for v in counters)))
+    return dict(zip(cfg.counters, (int(v) for v in counters)))
 
 
 def _count(cfg, counters, name: str, values):
     """``counters`` with ``values`` [k] added from ``name``'s place in
-    the family's ``COUNTERS`` on.  (A sum of int32 is int64 under x64:
-    the counters' dtype must come back as it went in, or the step
+    the description's ``counters`` on.  (A sum of int32 is int64 under
+    x64: the counters' dtype must come back as it went in, or the step
     traces twice.)"""
-    names = FAMILIES[cfg.family].COUNTERS
+    names = cfg.counters
     at = names.index(name)
     return counters + jnp.pad(values.astype(jnp.int32),
                               (at, len(names) - at - values.shape[0]))
@@ -156,6 +157,7 @@ def _head(cfg, state, h):
 def build_step(runner, count_trace):
     cfg = runner.config
     weights_of = FAMILIES[cfg.family].layer_weights
+    expert_block = FAMILIES[cfg.family].expert_block
     emit_logits = runner.emit_logits
     rope_len = runner._rope_len
 
@@ -182,7 +184,7 @@ def build_step(runner, count_trace):
                     counters = _count(cfg, counters, "ssm_rows_live",
                                       jnp.sum(active).reshape(1))
                 elif part == "moe":
-                    h, counts = nh.expert_block(
+                    h, counts = expert_block(
                         cfg, w, h, active.astype(bool), DECODE_TILE)
                     counters = _count(cfg, counters, MOE_COUNTERS[0],
                                       counts)
@@ -205,6 +207,7 @@ def build_step(runner, count_trace):
 def build_prefill(runner, bucket: int, count_trace):
     cfg = runner.config
     weights_of = FAMILIES[cfg.family].layer_weights
+    expert_block = FAMILIES[cfg.family].expert_block
     pack = gh.kv_pack(cfg)
 
     def prefill(state, ids, length, table_row, kpool, vpool, kscale,
@@ -229,8 +232,8 @@ def build_prefill(runner, bucket: int, count_trace):
                 elif part == "moe":
                     # (the prefill's expert counts are not kept: the
                     # counters are the decode step's)
-                    x = nh.expert_block(cfg, w, x[0], pmask[0],
-                                        PREFILL_TILE)[0][None]
+                    x = expert_block(cfg, w, x[0], pmask[0],
+                                     PREFILL_TILE)[0][None]
                 else:
                     x = mlp_block(w, x, cfg, li=i)
             # the block's state is written after its last part

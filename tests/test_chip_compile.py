@@ -715,6 +715,148 @@ def test_hybrid_moe_cell_programs_keep_their_kernels_and_scope_order(
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) < 0.9 * 2**34
 
 
+def _granite_moe_cell_runner(monkeypatch):
+    """The Granite 4.0-H Small cell's runner: the configuration as the
+    benchmark's driver reads it, its 10 layers, shapes only."""
+    import json
+    import sys
+    from paddle_tpu.models.granite_hybrid import GraniteHybridConfig
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.drivers.engine_closed_loop_granite_moe import routed_model
+    from benchmarks.lib import granite_moe_state
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "granite-4.0-h-small-ep2-l10.json")) as f:
+        conf = json.load(f)
+    m = routed_model(conf)
+    run = _bare_runner(monkeypatch, GraniteHybridConfig.from_published(
+        {k: v for k, v in m.items() if k != "local_experts"},
+        local_experts=tuple(m["local_experts"]),
+        dtype=conf["assumed"]["torch_dtype"]), conf["engine"],
+        recurrent=True)
+    return run, {k: shape for k, (shape, _)
+                 in granite_moe_state.shapes(conf).items()}
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill"])
+def test_granite_moe_cell_programs_keep_their_kernels_and_scope_order(
+        sds, monkeypatch, record_property, program):
+    """The Granite 4.0-H Small cell's programs at the published widths,
+    its 10 layers, each a mixer and then the expert part: one
+    ``ssm_state_update`` call a Mamba layer at 128 heads (H x P = 8,192),
+    one ``paged_attention`` call at 8 KV heads of 128 (decode), the
+    flash kernel (prefill), two ``grouped_matmul`` calls a layer (gate |
+    up 1,536 wide as one, down), the scopes in layer order and no
+    ``mlp`` scope (the shared MLP is the expert part's).  The compiled
+    ``decode_step`` updates both kinds of state where they lie and fits
+    the chip."""
+    from paddle_tpu.serving.parallel import recurrent
+    run, shapes = _granite_moe_cell_runner(monkeypatch)
+    cfg = run.config
+    decode = program == "decode_step"
+    mixers = {"mamba": MAMBA_DECODE if decode else MAMBA_PREFILL,
+              "attention": ATTN_DECODE if decode else ATTN_PREFILL}
+    want = ["embed"]
+    for kind in cfg.layer_types:
+        mixer = [s for s in mixers[kind] if s != "mlp"]
+        # the layer's state is written after its last part
+        write = [s for s in mixer if s in ("ssm.write", "kv.write")
+                 and not decode]
+        want += [s for s in mixer if s not in write] + MOE_PART + write
+    slots = run.max_slots
+    if decode:
+        lowered = run._make_step_fn().lower(
+            {name: sds(shape) for name, shape in shapes.items()},
+            *(sds(recurrent.kv_pool_shape(cfg, run.num_pages,
+                                          run.page_size)),) * 2, (), (),
+            sds((slots, run.table_width), jnp.int32),
+            *(sds((slots,), jnp.int32),) * 3, sds((1, slots), jnp.int32),
+            sds((), jnp.int32), sds((0,), jnp.float32),
+            sds((0,), jnp.float32), (), (), sds((5,), jnp.int32),
+            recurrent.state_pools(cfg, slots, zeros=sds))
+    else:
+        lowered = _lower_hybrid_program(sds, run, shapes, program)
+    assert _scope_order(lowered, set(want) | {"head", "mlp"}) == (
+        want + ["head"])
+    text = lowered.as_text()
+    calls = text.count("@tpu_custom_call")
+    n_m, n_a = len(cfg.mamba_layers), len(cfg.attention_layers)
+    assert (n_m, n_a, cfg.num_hidden_layers) == (9, 1, 10)
+    if not decode:
+        assert calls == n_a + 2 * 10        # flash; gate|up and down
+        return
+    assert calls == n_m + 1 + 2 * 10
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    by_stem = {stem: _hlo_lines(hlo, stem) for stem in (
+        "ssm_state_update", "grouped_matmul", "paged_attention")}
+    assert [len(by_stem[k]) for k in (
+        "ssm_state_update", "grouped_matmul", "paged_attention")] == [
+        n_m, 2 * 10, n_a]
+    # each roofline's pattern finds its own kernel's events and no other
+    # (the experts' by the decode step's 1,216 sorted rows)
+    names = [n for n in event_names(compiled)
+             if n.startswith(tuple("%" + s for s in by_stem))]
+    for stem, metric in (
+            ("ssm_state_update", "ssm_update_roofline.serve.granite_moe"),
+            ("grouped_matmul", "moe_experts_roofline.serve.granite_moe"),
+            ("paged_attention",
+             "paged_attention_roofline.serve.granite_moe")):
+        found = [n for n in names if any(
+            re.search(p, n) for p in _metric_events(metric))]
+        assert len(found) == len(by_stem[stem]), metric
+        assert all(n.startswith("%" + stem) for n in found), metric
+    mem = compiled.memory_analysis()
+    record_property("temp_size_in_bytes", mem.temp_size_in_bytes)
+    print(f"granite-moe decode_step: temp {mem.temp_size_in_bytes}, "
+          f"arguments {mem.argument_size_in_bytes}, "
+          f"aliased {mem.alias_size_in_bytes}")
+    assert mem.temp_size_in_bytes < 400e6
+    # weights 9.51 + ssm 1.21 (bfloat16) + conv 0.03 + K/V 1.07 GB
+    assert 11.8e9 < mem.argument_size_in_bytes < 11.9e9
+    # the state pools and the K/V pools are all updated in place
+    assert mem.alias_size_in_bytes > 1.20e9 + 1.07e9
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) < 0.9 * 2**34
+
+
+@pytest.mark.parametrize("rows,k,n,tile,timed", [
+    (1216, 4096, 1536, 16, True),   # a decode step's gate | up: 640 + 36 x 16
+    (1216, 768, 4096, 16, True),    # ... and its down
+    (7168, 4096, 1536, 128, False),  # a prefill of 256 tokens
+], ids=["decode-gateup", "decode-down", "prefill-gateup"])
+def test_grouped_matmul_at_the_granite_moe_cells_shapes(sds, rows, k, n,
+                                                        tile, timed):
+    """36 held experts 768 wide, gate | up as one call 1,536 wide: whole
+    lane tiles, no padding; the experts' roofline times the decode
+    step's calls alone."""
+    text = compiled_text(
+        lambda x, w, e, live: GF.grouped_matmul(x, w, e, live, tile_m=tile),
+        sds((rows, k)), sds((36, k, n)), sds((rows // tile,), jnp.int32),
+        sds((), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    lines = _hlo_lines(text, "grouped_matmul")
+    assert len(lines) == 1
+    assert timed == any(re.search(p, lines[0]) for p in _metric_events(
+        "moe_experts_roofline.serve.granite_moe"))
+
+
+def test_ssm_state_update_at_128_heads(sds):
+    """The state-update kernel at the Granite 4.0-H Small cell's pool,
+    ``[9, 64, 128, 8192]`` float32 (4 MiB a slot a layer), one group:
+    one call, the pool updated where it lies."""
+    from paddle_tpu.ops.pallas import ssm_update as U
+    compiled = jax.jit(
+        lambda pool, d, x, b, c, a: U.ssm_state_update(pool, 3, d, x, b, c,
+                                                       a),
+        donate_argnums=0).lower(
+        sds((9, 64, 128, 8192), jnp.float32), sds((64, 8192), jnp.float32),
+        sds((64, 8192), jnp.float32), sds((64, 1, 128), jnp.float32),
+        sds((64, 1, 128), jnp.float32), sds((64,), jnp.int32)).compile()
+    assert len(_hlo_lines(compiled.as_text(), "ssm_state_update")) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < (8 << 20)
+
+
 @pytest.mark.parametrize("cell,slots,width,calls", [
     ("mistral", 32, 64, 16), ("granite", 64, 256, 4),
     ("nemotron", 64, 256, 6)])

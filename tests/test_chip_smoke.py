@@ -199,6 +199,49 @@ def test_grouped_matmul_phase_checks_both_tiles(monkeypatch):
                                tiles=((16, 40),), reps=1, dtype="float32")
 
 
+def test_gate_up_phase_times_one_call_against_two(monkeypatch):
+    """Gate | up as one grouped product 2F wide and as two F wide, at a
+    toy size under the Pallas interpreter: both agree with the dense
+    gather, experts that spill into a second row tile are counted, and a
+    kernel that reads the wrong expert is refused."""
+    from paddle_tpu.ops.pallas import grouped_ffn as GF
+    monkeypatch.setattr(GF, "_INTERPRET", True)
+    out = C.gate_up_phase(seed=C.SEED, experts=4, k=32, f=16, pairs=40,
+                          tile=8, reps=1, dtype="float32")
+    assert out["shape"] == [4, 32, 32] and out["rows"] == 40 + 32
+    assert out["live_tiles"] > out["experts_touched"]      # 10 rows, tile 8
+    for name in ("one_call", "two_calls"):
+        assert out[name]["gap"] < 1e-5 and out[name]["ms"] > 0
+    json.dumps(out)
+    real = GF.grouped_matmul
+
+    def wrong_expert(x, w, emap, live, *, tile_m):
+        return real(x, w, (emap + 1) % 4, live, tile_m=tile_m)
+    monkeypatch.setattr(GF, "grouped_matmul", wrong_expert)
+    with pytest.raises(RuntimeError, match="differs from its XLA form"):
+        C.gate_up_phase(seed=C.SEED, experts=4, k=32, f=16, pairs=40,
+                        tile=8, reps=1, dtype="float32")
+
+
+def test_granite_moe_runs_its_cells_shapes(monkeypatch):
+    seen = []
+    for phase in ("ssm_update_phase", "grouped_matmul_phase",
+                  "gate_up_phase"):
+        monkeypatch.setattr(C, phase, lambda p=phase, **k: seen.append(
+            (p, k)) or {"phase": p})
+    monkeypatch.setattr(C, "require_tpu", lambda n: jax.devices()[:1])
+    monkeypatch.setattr(C, "interpret_is_off", lambda: None)
+    monkeypatch.setattr(C, "_ok", lambda devices: 0)
+    assert C.main(["--granite-moe"]) == 0
+    assert [(p, k.get("hp"), k.get("experts"), k.get("k"), k.get("n"))
+            for p, k in seen] == [
+        ("ssm_update_phase", 8192, None, None, None),
+        ("grouped_matmul_phase", None, 36, 4096, 1536),
+        ("grouped_matmul_phase", None, 36, 768, 4096),
+        ("gate_up_phase", None, None, None, None)]
+    assert seen[0][1]["dtypes"] == ("bfloat16",)      # the cell's state
+
+
 @pytest.mark.parametrize("argv,phase,kw", [
     (["--ssm-update"], "ssm_update_phase", {"groups": 1}),
     (["--ssm-update", "8"], "ssm_update_phase", {"groups": 8}),
